@@ -36,7 +36,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-__all__ = ["HW", "TPU_V5E", "RooflineTerms", "roofline_terms", "energy_joules",
+__all__ = ["HW", "TPU_V5E", "HW_BY_DEVICE_KIND", "hw_for_device",
+           "RooflineTerms", "roofline_terms", "energy_joules",
            "clamp_f_scale", "F_SCALE_MAX"]
 
 # highest supported DVFS point (modest turbo headroom above nominal);
@@ -67,6 +68,25 @@ class HW:
 
 
 TPU_V5E = HW()
+
+# the modeled chip for each TPU ``device_kind`` JAX reports
+HW_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hw_for_device(device) -> HW:
+    """The modeled chip of a JAX ``device``.  A TPU whose
+    ``device_kind`` has no row in :data:`HW_BY_DEVICE_KIND` raises:
+    modeling it as a v5e would misreport every time and joule.  Any
+    other platform (the CPU of the tests) models the v5e the kernels
+    are written for."""
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return HW_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no modeled chip for TPU device_kind {device.device_kind!r}; "
+            f"known kinds: {sorted(HW_BY_DEVICE_KIND)}") from None
 
 
 def clamp_f_scale(hw: HW, f_scale: float) -> float:

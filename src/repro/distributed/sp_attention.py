@@ -21,7 +21,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["sp_decode_attention"]
@@ -117,7 +116,7 @@ def sp_decode_attention(q, k_cache, v_cache, kv_pos, k_new, v_new,
         if dp_axes else None
     cspec = P(dpn, sq, None, None)
     rep = P(dpn, None, None, None)
-    out, kc, vc, kp = shard_map(
+    out, kc, vc, kp = jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep, cspec, cspec, P(sq), rep, rep),
         out_specs=(rep, cspec, cspec, P(sq)),

@@ -20,10 +20,12 @@ The kernel reads every shape it tiles by -- query heads, kv-heads, head
 dim -- from its *local* operands, never from a model config, so a
 kv-head-sharded pool (``repro.distributed.sharding
 .paged_decode_state_specs``, DESIGN.md §15) needs no kernel changes:
-each shard launches over its own ``n_kv_heads / model`` head slice with
-the full block table (replicated control metadata), and the
-scalar-prefetch pipeline above runs per shard exactly as it does on one
-chip.
+under a mesh the model runs the kernel in a ``shard_map``
+(``repro.models.attention.paged_decode_attention``; the SPMD
+partitioner cannot split a Pallas call), each shard over its own
+``n_kv_heads / model`` head slice with the full block table (replicated
+control metadata), and the scalar-prefetch pipeline above runs per
+shard exactly as it does on one chip.
 
 ``paged_decode_attention`` is the dispatching entry point: the Pallas
 kernel on TPU (or under ``interpret=True``), otherwise the pure-XLA
@@ -42,9 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.kernels.ref import paged_decode_attention_ref
-from repro.runtime.chaos import fire as _chaos_fire
+from repro.runtime.chaos import InjectedFault, fire as _chaos_fire
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_pallas",
            "FALLBACK_EVENTS", "fallback_key", "mark_fallback",
@@ -149,7 +150,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, phys_tables,
             max_pages=max_pages, scale=scale, out_dtype=out_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(phys_tables.astype(jnp.int32),
@@ -158,13 +159,12 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, phys_tables,
       q, k_pages, v_pages)
 
 
-# Graceful degradation (DESIGN.md §14): shapes whose Pallas build has
-# faulted fall back to the XLA reference *stickily* -- the fault is paid
-# once per shape, every later trace of that shape dispatches straight to
-# ref.  Metered: every engagement is recorded on FALLBACK_EVENTS so the
-# serve loop (serve.degraded) and tests can see exactly what degraded
-# and why.  Keyed per shape because a lowering fault is a property of
-# the (batch, heads, head-dim, page geometry) tuple, not of the process.
+# Graceful degradation under injected kernel faults (DESIGN.md §14):
+# a degraded shape falls back to the XLA reference *stickily* -- every
+# later trace of that shape dispatches straight to ref.  Metered: every
+# engagement is recorded on FALLBACK_EVENTS so the serve loop
+# (serve.degraded) and tests can see exactly what degraded and why.
+# Keyed per shape: the (batch, heads, head-dim, page geometry) tuple.
 _FALLBACK: set[tuple] = set()
 FALLBACK_EVENTS: list[dict] = []
 
@@ -196,13 +196,11 @@ def paged_decode_attention(q, k_pages, v_pages, phys_tables, cur_pos, *,
     (or ``interpret=True``), the XLA gather reference otherwise -- both
     produce the same f32 math, so callers never branch on backend.
 
-    A Pallas build fault (or an injected ``kernel`` chaos event) marks
-    this shape's sticky fallback and degrades to the reference instead
-    of propagating: wrong-but-up is never on the menu -- ref computes
-    identical math -- but slow-and-correct beats down.  Runtime launch
-    faults surface inside jit where this host-side dispatch cannot
-    catch them; the serve loop catches those, calls
-    :func:`mark_fallback` and retraces (DESIGN.md §14)."""
+    An injected ``kernel`` chaos event marks this shape's sticky
+    fallback and degrades to the reference (ref computes identical
+    math), so chaos runs exercise the degraded path (DESIGN.md §14).  A
+    real Pallas build fault propagates: on the chip a silent switch to
+    the reference would hide that the kernel never ran."""
     key = fallback_key(q.shape[0], q.shape[1], q.shape[2],
                        k_pages.shape[1], phys_tables.shape[1])
     want_pallas = bool(force_pallas or interpret
@@ -210,10 +208,11 @@ def paged_decode_attention(q, k_pages, v_pages, phys_tables, cur_pos, *,
     if want_pallas and not fallback_active(key):
         try:
             _chaos_fire("kernel")
+        except InjectedFault as e:
+            mark_fallback(key, reason=repr(e))
+        else:
             return paged_decode_attention_pallas(
                 q, k_pages, v_pages, phys_tables, cur_pos,
                 interpret=bool(interpret))
-        except Exception as e:  # noqa: BLE001 -- degrade, metered
-            mark_fallback(key, reason=repr(e))
     return paged_decode_attention_ref(
         q, k_pages, v_pages, phys_tables, cur_pos)

@@ -38,7 +38,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.curves import hilbert_decode, morton_decode
 from repro.core.schedule import grid_schedule, is_pow2, \
     schedule_extra_kwargs
@@ -108,6 +107,16 @@ def _mm_kernel(a_ref, b_ref, *rest, kt: int, out_dtype,
     def _flush():
         o_ref[...] = _fused_flush(acc_ref[...], bias_ref, res_ref,
                                   activation, out_dtype, batched=False)
+
+
+def _flat_schedule(schedule: str, mt: int, nt: int, g: int):
+    """The scalar-prefetch table, flat: tile step t visits
+    (table[2t], table[2t+1]).  SMEM pads the minor dim of a 2-D table to
+    128 words, so a (steps, 2) table takes 64x its size there and
+    overflows SMEM's 1 MiB at the vocab head of a 2048-row prefill."""
+    return jnp.asarray(
+        grid_schedule(schedule, mt, nt, **schedule_extra_kwargs(schedule, g)),
+        dtype=jnp.int32).reshape(-1)
 
 
 def _mm_kernel_prefetch(sched_ref, *args, **kwargs):
@@ -184,7 +193,7 @@ def sfc_matmul_pallas(
                    has_residual=residual is not None)
     out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-    semantics = tpu_compiler_params(
+    semantics = pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"),
     )
 
@@ -223,21 +232,19 @@ def sfc_matmul_pallas(
         )(a, b, *ep_ops)
 
     # --- scalar-prefetch variant: host-precomputed schedule table ---------
-    sched = jnp.asarray(
-        grid_schedule(schedule, mt, nt, **schedule_extra_kwargs(schedule, g)),
-        dtype=jnp.int32)
+    sched = _flat_schedule(schedule, mt, nt, g)
 
     def a_map(t, kk, sched_ref):
-        return sched_ref[t, 0], kk
+        return sched_ref[2 * t], kk
 
     def b_map(t, kk, sched_ref):
-        return kk, sched_ref[t, 1]
+        return kk, sched_ref[2 * t + 1]
 
     def o_map(t, kk, sched_ref):
-        return sched_ref[t, 0], sched_ref[t, 1]
+        return sched_ref[2 * t], sched_ref[2 * t + 1]
 
     def bias_map(t, kk, sched_ref):
-        return 0, sched_ref[t, 1]
+        return 0, sched_ref[2 * t + 1]
 
     ep_specs, ep_ops = _epilogue_operands(
         bias, residual, (1, n),
@@ -342,7 +349,7 @@ def sfc_matmul_batched_pallas(
                    has_residual=residual is not None)
     out_shape = jax.ShapeDtypeStruct((bsz, m, n), out_dtype)
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-    semantics = tpu_compiler_params(
+    semantics = pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
     )
 
@@ -382,21 +389,19 @@ def sfc_matmul_batched_pallas(
             interpret=interpret,
         )(a, b, *ep_ops)
 
-    sched = jnp.asarray(
-        grid_schedule(schedule, mt, nt, **schedule_extra_kwargs(schedule, g)),
-        dtype=jnp.int32)
+    sched = _flat_schedule(schedule, mt, nt, g)
 
     def a_map(bb_, t, kk, sched_ref):
-        return bb_, sched_ref[t, 0], kk
+        return bb_, sched_ref[2 * t], kk
 
     def b_map(bb_, t, kk, sched_ref):
-        return bb_, kk, sched_ref[t, 1]
+        return bb_, kk, sched_ref[2 * t + 1]
 
     def o_map(bb_, t, kk, sched_ref):
-        return bb_, sched_ref[t, 0], sched_ref[t, 1]
+        return bb_, sched_ref[2 * t], sched_ref[2 * t + 1]
 
     def bias_map(bb_, t, kk, sched_ref):
-        return 0, 0, sched_ref[t, 1]
+        return 0, 0, sched_ref[2 * t + 1]
 
     ep_specs, ep_ops = _epilogue_operands(
         bias, residual, (1, 1, n),

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.schedule import grid_schedule
 
 __all__ = ["sfc_matmul_cached"]
@@ -137,7 +136,7 @@ def sfc_matmul_cached(a, b, *, schedule: str = "morton", bm: int = 128,
             jax.ShapeDtypeStruct((m, n), out_dtype),
             jax.ShapeDtypeStruct((1, 2), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(sched, a, b)

@@ -1,4 +1,8 @@
 import os
+# compile-only over 512 placeholder host devices: pinned to the CPU
+# backend (and so are the --sweep children, which inherit this env), so
+# no process of the sweep ever opens a TPU that the host may have
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

@@ -176,6 +176,16 @@ def link_distance(mesh, *, device_order: str | None = None,
     return out
 
 
+def _make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis *Auto*: the sharding rules in
+    :mod:`repro.distributed.sharding` are GSPMD annotations, and jax's
+    default Explicit axes would make every unannotated gather a type
+    error instead of a propagated sharding."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False,
                          device_order: str = "rowmajor"):
     if device_order not in DEVICE_ORDERS:
@@ -185,7 +195,7 @@ def make_production_mesh(*, multi_pod: bool = False,
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     if device_order == "rowmajor":
-        return _record_device_order(jax.make_mesh(shape, axes),
+        return _record_device_order(_make_mesh(shape, axes),
                                     device_order)
     devs = jax.devices()
     n = int(np.prod(shape))
@@ -198,18 +208,20 @@ def make_production_mesh(*, multi_pod: bool = False,
         ordered += device_permutation(
             device_order, rows, cols, devs[p * per_pod:(p + 1) * per_pod])
     return _record_device_order(
-        jax.make_mesh(shape, axes, devices=ordered), device_order)
+        _make_mesh(shape, axes, ordered), device_order)
 
 
 def make_smoke_mesh(shape=(2, 2, 2), axes=("pod", "data", "model"), *,
                     device_order: str = "rowmajor"):
-    """Small mesh for CPU multi-device tests (8 host devices).
+    """Small mesh over ``jax.devices()``: the host devices of the CPU
+    multi-device tests (8 for the default shape), or the chips of one
+    TPU host (``chip_smoke.py --four-chips`` builds (2, 2) over four).
 
     ``device_order`` embeds the non-pod axes on the
     :func:`default_torus` of their chip count, same validation and
     permutation path as production."""
     if device_order == "rowmajor":
-        return _record_device_order(jax.make_mesh(shape, axes),
+        return _record_device_order(_make_mesh(shape, axes),
                                     device_order)
     pods = shape[axes.index("pod")] if "pod" in axes else 1
     per_pod = int(np.prod(shape)) // pods
@@ -220,7 +232,7 @@ def make_smoke_mesh(shape=(2, 2, 2), axes=("pod", "data", "model"), *,
         ordered += device_permutation(
             device_order, rows, cols, devs[p * per_pod:(p + 1) * per_pod])
     return _record_device_order(
-        jax.make_mesh(shape, axes, devices=ordered), device_order)
+        _make_mesh(shape, axes, ordered), device_order)
 
 
 def mesh_chips(mesh) -> int:

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_apply", "split_stages"]
@@ -77,10 +76,8 @@ def pipeline_apply(stage_fn, layer_params, x_mb, mesh, axis: str = "pod"):
     lspec = jax.tree.map(
         lambda x: P(*( (axis,) + (None,) * (x.ndim - 1) )), stages)
     # fully manual (all mesh axes): non-pipeline axes see replicated
-    # inputs + deterministic compute, so results stay replicated; the
-    # partial-manual spelling (axis_names={axis}) lowers axis_index to a
-    # PartitionId op the pinned jax cannot SPMD-partition on CPU.
-    return shard_map(
+    # inputs + deterministic compute, so results stay replicated
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(lspec, P()),
         out_specs=P(),

@@ -42,28 +42,44 @@ def _train_setup(arch, mesh, **kw):
     return cfg, fn, (p_shd, o_shd, b_shd), params, opt, batch
 
 
-def check_dp_tp_matches_single(arch="qwen3_1_7b"):
-    """Sharded step == single-device step (same loss, ~same params)."""
-    mesh = make_smoke_mesh((2, 2, 2))
-    cfg, fn, (p_shd, o_shd, b_shd), params, opt, batch = _train_setup(
-        arch, mesh)
-    p1 = jax.device_put(params, p_shd)
-    o1 = jax.device_put(opt, o_shd)
-    b1 = jax.device_put(batch, b_shd)
-    pd, od, md = fn(p1, o1, b1)
+def _init_train_state(cfg, moe_pad, p_shd, o_shd):
+    """(params, opt state) made in place under the given shardings, so
+    a full-width state never passes through one device on its way to
+    its shards."""
+    params = jax.jit(
+        lambda: init_model(cfg, jax.random.PRNGKey(0), moe_pad=moe_pad),
+        out_shardings=p_shd)()
+    return params, jax.jit(init_opt_state, out_shardings=o_shd)(params)
 
-    ref_step = jax.jit(make_train_step(
-        cfg, None, AdamWConfig(peak_lr=1e-2, warmup=0)))
-    # re-init (donated buffers)
-    params = init_model(cfg, jax.random.PRNGKey(0),
-                        moe_pad=mesh.shape["model"])
-    opt = init_opt_state(params)
-    pr, orr, mr = ref_step(params, opt, batch)
-    lm, lr_ = float(md["loss"]), float(mr["loss"])
+
+def check_dp_tp_matches_single(arch="qwen3_1_7b", mesh=None, cfg=None):
+    """Sharded step == single-device step (same loss, ~same params).
+
+    ``mesh`` and ``cfg`` default to the (2,2,2) host-device mesh and the
+    smoke config.  The sharded result is copied to the host before the
+    reference step starts, and the reference donates its state: at the
+    published widths one chip holds only one train state."""
+    mesh = mesh or make_smoke_mesh((2, 2, 2))
+    cfg = cfg or get_smoke_config(arch)
+    opt_cfg = AdamWConfig(peak_lr=1e-2, warmup=0)
+    fn, (p_shd, o_shd, b_shd), _ = build_train_step(
+        cfg, mesh, SHAPE.name, opt_cfg=opt_cfg)
+    moe_pad = mesh.shape["model"]
+    batch = make_batch(cfg, SHAPE, seed=1)
+    params, opt = _init_train_state(cfg, moe_pad, p_shd, o_shd)
+    pd, od, md = fn(params, opt, jax.device_put(batch, b_shd))
+    lm = float(md["loss"])
+    pd = jax.device_get(pd)
+    del od
+
+    one = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    ref_step = jax.jit(make_train_step(cfg, None, opt_cfg),
+                       donate_argnums=(0, 1))
+    params, opt = _init_train_state(cfg, moe_pad, one, one)
+    pr, _, mr = ref_step(params, opt, batch)
+    lr_ = float(mr["loss"])
     assert abs(lm - lr_) / max(abs(lr_), 1e-6) < 5e-3, (lm, lr_)
-    flat_d = jax.tree.leaves(pd)
-    flat_r = jax.tree.leaves(pr)
-    for a, b in zip(flat_d, flat_r):
+    for a, b in zip(jax.tree.leaves(pd), jax.tree.leaves(pr)):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             rtol=5e-2, atol=5e-2)
@@ -188,11 +204,30 @@ def check_train_cli_with_failure():
     print("OK train_cli_with_failure")
 
 
-def check_paged_sharded_matches_replicated(arch="qwen3_1_7b"):
+def _assert_logits_close(got, want, cfg):
+    """f32 configs (the smoke ones): within 3e-3.  bf16 (the published
+    widths on the chip): two correct programs round partial sums at
+    different points, about nine roundings of u = 2**-8 a layer, and n
+    independent roundings grow like sqrt(n) u; so within sqrt(9 L) u of
+    the largest |logit| (0.023 of it at 4 layers)."""
+    got = np.asarray(got, np.float32)[..., :cfg.vocab]
+    want = np.asarray(want, np.float32)[..., :cfg.vocab]
+    if cfg.act_jdtype() == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=3e-3, atol=3e-3)
+    else:
+        rel = np.sqrt(9 * cfg.n_layers) * 2.0 ** -8
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=rel * np.abs(want).max())
+
+
+def check_paged_sharded_matches_replicated(arch="qwen3_1_7b", mesh=None,
+                                           cfg=None):
     """kv-head-sharded paged pool == replicated pool == single device
     (DESIGN.md §15): identical logits under a ragged slot-isolated
     prefill + lockstep greedy decode, with the pool sharding pinned via
     jit in/out shardings so GSPMD cannot quietly replicate it back.
+    ``mesh`` and ``cfg`` default to the hilbert-placed (2,2,2)
+    host-device mesh and the smoke config.
 
     ``REPRO_PARITY_SPEC`` (JSON: {"prompts": [[...], ...], "steps": N})
     overrides the deterministic schedule -- the hook the hypothesis
@@ -216,8 +251,8 @@ def check_paged_sharded_matches_replicated(arch="qwen3_1_7b"):
 
     # hilbert placement: the parity claim must hold under the curve
     # embedding production would use, not just the identity one
-    mesh = make_smoke_mesh((2, 2, 2), device_order="hilbert")
-    cfg = dataclasses.replace(get_smoke_config(arch), remat=False)
+    mesh = mesh or make_smoke_mesh((2, 2, 2), device_order="hilbert")
+    cfg = dataclasses.replace(cfg or get_smoke_config(arch), remat=False)
     m = mesh.shape["model"]
     assert cfg.n_kv_heads % m == 0, (cfg.n_kv_heads, m)
     sspec = shd.paged_decode_state_specs(cfg, mesh)
@@ -251,8 +286,7 @@ def check_paged_sharded_matches_replicated(arch="qwen3_1_7b"):
                          jnp.asarray(pos, jnp.int32), mask)
         ll, state_l = local(params, state_l, toks,
                             jnp.asarray(pos, jnp.int32), mask)
-        np.testing.assert_allclose(np.asarray(ld), np.asarray(ll),
-                                   rtol=3e-3, atol=3e-3)
+        _assert_logits_close(ld, ll, cfg)
         return ll
 
     for s, pr in enumerate(prompts):      # ragged slot-isolated prefill

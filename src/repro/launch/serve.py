@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import _engine_for
 from repro.models import DotEngine, decode_step, \
     fused_epilogue_savings_bytes, init_decode_state, init_model
@@ -287,9 +288,10 @@ class ServeLoop:
         self._build_jits()
 
     def _build_jits(self) -> None:
-        """(Re)build the jitted step wrappers.  Called again after a
-        kernel-fault degradation: the fresh wrappers retrace, and the
-        retrace dispatches through the now-sticky XLA fallback."""
+        """(Re)build the jitted step wrappers.  Called again after an
+        injected kernel-fault degradation: the fresh wrappers retrace,
+        and the retrace dispatches through the now-sticky XLA
+        fallback."""
         cfg = self.cfg
         self._step = jax.jit(
             lambda p, s, t, pos, mask: decode_step(
@@ -749,15 +751,9 @@ class ServeLoop:
                 for i, tok in enumerate(prompt):
                     toks = np.zeros((self.slots, 1), np.int32)
                     toks[slot, 0] = tok
-                    try:
-                        logits, self.state = self._step(
-                            self.params, self.state, jnp.asarray(toks),
-                            jnp.asarray(i, jnp.int32),
-                            jnp.asarray(mask))
-                    except TransientFault:
-                        raise
-                    except Exception as e:  # noqa: BLE001
-                        raise self._launch_fault(e) from e
+                    logits, self.state = self._step(
+                        self.params, self.state, jnp.asarray(toks),
+                        jnp.asarray(i, jnp.int32), jnp.asarray(mask))
             self.request_joules[req_id] = \
                 self.request_joules.get(req_id, 0.0) + em.reading.joules
             self.pos[slot] = len(prompt)
@@ -915,14 +911,9 @@ class ServeLoop:
                              hbm_bytes=self._gemm_bytes_step,
                              gemm_bytes=self._gemm_bytes_step,
                              f_scale=self.f_scale)) as em:
-            try:
-                self.state = self._chunk(
-                    self.params, self.state, jnp.asarray(toks),
-                    jnp.asarray(sl), jnp.asarray(st), jnp.asarray(ln))
-            except TransientFault:
-                raise
-            except Exception as e:  # noqa: BLE001
-                raise self._launch_fault(e) from e
+            self.state = self._chunk(
+                self.params, self.state, jnp.asarray(toks),
+                jnp.asarray(sl), jnp.asarray(st), jnp.asarray(ln))
         # per-request attribution weighted by the prompt tokens each row
         # actually processed this chunk -- a gang sharing one reading
         # must not bill a 1-token tail row like a budget-filling row
@@ -1012,15 +1003,10 @@ class ServeLoop:
                              attn_bytes=attn_bytes,
                              gemm_bytes=self._gemm_bytes_step,
                              f_scale=self.f_scale)) as em:
-            try:
-                logits, self.state = self._step(
-                    self.params, self.state, jnp.asarray(toks), pos_arg,
-                    jnp.asarray(self.active))
-                logits = np.asarray(logits[:, 0], np.float32)
-            except TransientFault:
-                raise
-            except Exception as e:  # noqa: BLE001 -- classified below
-                raise self._launch_fault(e) from e
+            logits, self.state = self._step(
+                self.params, self.state, jnp.asarray(toks), pos_arg,
+                jnp.asarray(self.active))
+            logits = np.asarray(logits[:, 0], np.float32)
         # token-weighted attribution degenerates to an even split here:
         # every active slot processed exactly one token this step
         # (prefill readings are weighted by their real token counts)
@@ -1075,24 +1061,13 @@ class ServeLoop:
                     self._sync_tables()
 
     # --------------------------------------------- fault-tolerant loop ----
-    def _launch_fault(self, e: Exception) -> Exception:
-        """Classify a failure of a jitted step call: under fault guards
-        on a paged loop that has not yet degraded, treat it as a kernel
-        launch fault -- the retry path engages the sticky XLA fallback
-        and retraces.  Anything else (or a second failure *after*
-        degrading) is a genuine bug and propagates unchanged."""
-        if self.guards and self.paged and not self._kernel_degraded:
-            f = TransientFault(f"kernel launch fault: {e!r}")
-            f.point = "kernel"
-            return f
-        return e
-
     def _engage_kernel_fallback(self, reason: str) -> None:
-        """Graceful degradation (DESIGN.md §14): mark this loop's
-        paged-attention shape for the sticky XLA reference fallback,
-        then rebuild the jitted wrappers so the retrace dispatches
-        through it.  One-way for the loop's lifetime; metered on
-        ``serve.degraded``."""
+        """Graceful degradation under an injected kernel fault
+        (DESIGN.md §14): mark this loop's paged-attention shape for the
+        sticky XLA reference fallback, then rebuild the jitted wrappers
+        so the retrace dispatches through it.  One-way for the loop's
+        lifetime; metered on ``serve.degraded``.  A real failure of a
+        jitted step is never retried: it propagates."""
         if self._kernel_degraded:
             return
         self._kernel_degraded = True
@@ -1152,8 +1127,8 @@ class ServeLoop:
 
     def _recover(self, e: TransientFault, attempt: int) -> None:
         """Retry path after a transient fault: engage the kernel
-        fallback when the fault was a launch fault, rewind to the last
-        snapshot (restore-and-replay), back off exponentially."""
+        fallback when the fault was an injected kernel fault, rewind to
+        the last snapshot (restore-and-replay), back off exponentially."""
         if getattr(e, "point", None) == "kernel":
             self._engage_kernel_fallback(repr(e))
         if self.snapshotter is not None:
@@ -1215,7 +1190,14 @@ class ServeLoop:
         return self.out
 
 
-def main(argv=None):
+def main(argv=None, prompts=None):
+    """The serve CLI; returns the drained :class:`ServeLoop`.
+
+    ``prompts`` (token lists) replaces the ``--requests`` random prompts
+    of ``--prompt-len`` tokens, for callers that need a particular mix
+    of requests, such as prompts that share a prefix.  Exits non-zero
+    when the loop degraded to the reference kernel without an injected
+    kernel fault."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -1305,6 +1287,7 @@ def main(argv=None):
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if not cfg.has_decode:
         raise SystemExit(f"{cfg.name} is encoder-only: no serving loop")
+    enable_compile_cache()
     layout = args.layout or ("paged" if args.paged else "contiguous")
     serve_cfg = ServeConfig(
         slots=args.slots, cache_len=args.cache_len,
@@ -1333,16 +1316,18 @@ def main(argv=None):
     loop = ServeLoop(cfg, params, serve_cfg,
                      power_backend=detect_backend(args.power_backend),
                      tracer=tracer)
-    rng = np.random.default_rng(args.seed)
-    for r in range(args.requests):
-        prompt = rng.integers(2, cfg.vocab, size=args.prompt_len).tolist()
+    if prompts is None:
+        rng = np.random.default_rng(args.seed)
+        prompts = [rng.integers(2, cfg.vocab, size=args.prompt_len).tolist()
+                   for _ in range(args.requests)]
+    for r, prompt in enumerate(prompts):
         loop.submit(r, prompt)
     t0 = time.time()
     out = loop.run(max_new=args.max_new)
     dt = time.time() - t0
-    total_new = sum(len(v) - args.prompt_len for v in out.values())
+    total_new = sum(len(v) - len(prompts[r]) for r, v in out.items())
     totals = loop.energy.totals()
-    print(f"[serve] {args.requests} requests ({serve_cfg.mode}), "
+    print(f"[serve] {len(prompts)} requests ({serve_cfg.mode}), "
           f"{total_new} tokens in "
           f"{dt:.2f}s ({total_new / max(dt, 1e-9):.1f} tok/s)")
     n_steps = max(len(loop.energy.readings), 1)
@@ -1372,14 +1357,14 @@ def main(argv=None):
     print(f"[serve] fused epilogues (DESIGN.md §9): "
           f"~{loop.ep_saved_step / 1e6:.2f} MB/step HBM traffic "
           f"eliminated across {loop.slots} slots (modeled)")
+    if loop._kernel_degraded:
+        print("[serve] kernel degraded to XLA fallback")
     if args.chaos or loop.errors or loop.snapshotter is not None:
         snaps = loop.snapshotter.snapshots if loop.snapshotter else 0
         rests = loop.snapshotter.restores if loop.snapshotter else 0
         print(f"[serve] fault tolerance (DESIGN.md §14): "
               f"{snaps} snapshots, {rests} restores, "
-              f"{len(loop.errors)} failed requests"
-              + (", kernel degraded to XLA fallback"
-                 if loop._kernel_degraded else ""))
+              f"{len(loop.errors)} failed requests")
         for r, reason in sorted(loop.errors.items()):
             print(f"  req {r}: failed ({reason})")
         if loop.chaos is not None:
@@ -1387,8 +1372,8 @@ def main(argv=None):
                   f"faults {loop.chaos.fired}, schedule "
                   f"{'exhausted' if loop.chaos.exhausted() else 'open'}")
     for r, toks in sorted(out.items()):
-        print(f"  req {r}: {toks[:args.prompt_len]} -> "
-              f"{toks[args.prompt_len:][:8]}... "
+        n = len(prompts[r])
+        print(f"  req {r}: {toks[:n]} -> {toks[n:][:8]}... "
               f"({loop.request_joules.get(r, 0.0):.2f} J)")
     lat = loop.energy.meta.get("latency") or {}
     ttft, tpot = lat.get("ttft_ms", {}), lat.get("tpot_ms", {})
@@ -1416,7 +1401,12 @@ def main(argv=None):
         print(f"[serve] wrote {len(tracer.events)} trace events to "
               f"{args.trace} (python -m repro.obs.trace {args.trace} "
               f"-o trace.json for Perfetto)")
-    return out
+    injected = loop.chaos is not None and any(
+        point == "kernel" for point, *_ in loop.chaos.fired)
+    if loop._kernel_degraded and not injected:
+        raise SystemExit("[serve] the paged-attention kernel degraded "
+                         "without an injected kernel fault")
+    return loop
 
 
 if __name__ == "__main__":

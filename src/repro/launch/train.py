@@ -18,8 +18,9 @@ import jax
 
 from repro.checkpoint import AsyncCheckpointer, latest_step, load_checkpoint
 from repro.configs import get_config, get_smoke_config
-from repro.core.energy import TPU_V5E
+from repro.core.energy import hw_for_device
 from repro.data import PackedSyntheticData, PrefetchLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import build_train_step
 from repro.models import fused_epilogue_savings_bytes, init_model
 from repro.models.config import ShapeSpec
@@ -73,6 +74,7 @@ def main(argv=None):
                          "adjudicated on this metric (DESIGN.md §8); "
                          "default keeps the XLA engine")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # observability (DESIGN.md §12): per-step spans (energy attributed
     # to them by the meter) + a step-latency histogram in the process
@@ -161,7 +163,8 @@ def main(argv=None):
     # per-step energy telemetry (DESIGN.md §8): counters where the host
     # has them, the analytic model (static power x measured step time +
     # 6*N*tokens FLOPs) in counter-less containers
-    power = detect_backend(args.power_backend)
+    hw = hw_for_device(jax.devices()[0])
+    power = detect_backend(args.power_backend, hw=hw)
     n_params = sum(int(p.size) for p in jax.tree.leaves(params))
     step_flops = 6.0 * n_params * args.batch * args.seq
     # fused epilogues (DESIGN.md §9): HBM passes the forward no longer
@@ -269,7 +272,7 @@ def main(argv=None):
           f"{totals['joules'] / max(totals['seconds'], 1e-9):.1f} W avg")
     print(f"[train] fused epilogues (DESIGN.md §9): "
           f"~{ep_saved / 1e6:.1f} MB/fwd HBM traffic eliminated "
-          f"(~{ep_saved * TPU_V5E.e_hbm:.3f} J/fwd at modeled e_hbm)")
+          f"(~{ep_saved * hw.e_hbm:.3f} J/fwd at modeled e_hbm)")
     if args.energy_report:
         energy.write(args.energy_report)
         print(f"[train] wrote energy report to {args.energy_report}")

@@ -14,10 +14,12 @@ step against a KV cache (the distributed sequence-parallel decode lives in
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .layers import DotEngine, apply_rope, init_linear, init_rms, rms_norm
 
@@ -150,6 +152,7 @@ def paged_decode_attention(x, p, cfg, engine: DotEngine, k_pages, v_pages,
     scattered into each slot's page at (cur_pos // page_size,
     cur_pos % page_size).
     """
+    from repro.distributed.ctx import current
     from repro.kernels.paged_attention import \
         paged_decode_attention as paged_core
 
@@ -175,8 +178,24 @@ def paged_decode_attention(x, p, cfg, engine: DotEngine, k_pages, v_pages,
     v_pages = v_pages.at[rows, offset].set(
         jnp.where(sel, v_new[:, 0], v_pages[rows, offset]))
 
-    out = paged_core(q[:, 0], k_pages, v_pages, phys_tables, pos,
-                     interpret=interpret)
+    core = functools.partial(paged_core, interpret=interpret)
+    ctx = current()
+    if ctx is not None:
+        # a Pallas kernel is one program per device, which the SPMD
+        # partitioner cannot split: under a mesh it runs in a shard_map,
+        # each model shard over the kv-head slice of the pool that
+        # paged_decode_state_specs gives it (and the query heads of
+        # those kv heads), everything else replicated
+        ax = ctx.model_axis
+        m = ctx.mesh.shape[ax]
+        split = m > 1 and cfg.n_kv_heads % m == 0
+        q_spec = P(None, ax if split else None, None)
+        kv_spec = P(None, None, ax if split else None, None)
+        core = jax.shard_map(
+            core, mesh=ctx.mesh,
+            in_specs=(q_spec, kv_spec, kv_spec, P(), P()),
+            out_specs=q_spec, check_vma=False)
+    out = core(q[:, 0], k_pages, v_pages, phys_tables, pos)
     out = engine.dot(out.reshape(b, 1, -1), p["wo"], residual=residual)
     return out, k_pages, v_pages
 

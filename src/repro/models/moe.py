@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from .layers import DotEngine, init_linear
 
 __all__ = ["init_moe", "moe_dense", "moe_capacity", "moe_ep", "moe_ffn"]
@@ -193,7 +192,7 @@ def moe_ep(x, params, cfg, mesh, engine: DotEngine,
         return y.reshape(bl, sl, dl), aux[None]
 
     espec = P(model_axis, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(), espec, espec, espec),
         out_specs=(x_spec, P(dpt)),

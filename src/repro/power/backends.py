@@ -19,7 +19,9 @@ backends behind one protocol:
   non-degenerate readings.
 
 :func:`detect_backend` auto-selects (rapl > nvml > model) with graceful
-fallback; ``REPRO_POWER_BACKEND`` pins a choice.
+fallback; ``REPRO_POWER_BACKEND`` pins a choice.  On a TPU it always
+selects the model: RAPL and NVML read host CPU and GPU counters, which
+never see the chip.
 """
 from __future__ import annotations
 
@@ -27,7 +29,7 @@ import os
 from dataclasses import dataclass
 from typing import Any, Protocol, runtime_checkable
 
-from repro.core.energy import HW, TPU_V5E, energy_joules
+from repro.core.energy import HW, TPU_V5E, energy_joules, hw_for_device
 
 __all__ = ["WorkloadHints", "PowerBackend", "RaplBackend", "NvmlBackend",
            "ModelBackend", "detect_backend", "RAPL_SYSFS_ROOT"]
@@ -284,20 +286,37 @@ class ModelBackend:
 # ---------------------------------------------------------------- detection
 def detect_backend(prefer: str | None = None, *,
                    rapl_root: str | None = None,
-                   hw: HW = TPU_V5E) -> PowerBackend:
+                   hw: HW | None = None) -> PowerBackend:
     """Pick the best available backend.
 
     Order: explicit ``prefer`` (or ``$REPRO_POWER_BACKEND``), then RAPL,
     then NVML, then the analytic model.  An unavailable preference falls
     back down the same chain rather than raising: telemetry must never
     take down the workload it observes.
+
+    On a TPU the model is the only backend: its readings are modeled
+    joules of the chip, where a host counter would label the CPU
+    package's energy as the run's.  Asking for a counter there raises.
+    ``hw`` defaults to the modeled chip of the first JAX device
+    (:func:`repro.core.energy.hw_for_device`, which raises on an
+    unknown TPU kind).
     """
+    import jax
+
+    device = jax.devices()[0]
+    hw = hw or hw_for_device(device)
     prefer = prefer or os.environ.get(_ENV_BACKEND) or None
     order = ["rapl", "nvml", "model"]
-    if prefer is not None:
-        if prefer not in order:
+    if prefer is not None and prefer not in order:
+        raise ValueError(
+            f"unknown power backend {prefer!r}; choose from {order}")
+    if device.platform == "tpu":
+        if prefer not in (None, "model"):
             raise ValueError(
-                f"unknown power backend {prefer!r}; choose from {order}")
+                f"power backend {prefer!r} reads host counters, not the "
+                f"TPU; only 'model' is available on a TPU")
+        return ModelBackend(hw=hw)
+    if prefer is not None:
         order = [prefer] + [b for b in order if b != prefer]
     for name in order:
         # construct once and keep the instance: probing availability via

@@ -203,7 +203,23 @@ def measure_config(
     the bias/activation/residual the caller will run: Pallas candidates
     execute it fused in the flush, the ``xla`` candidate pays the real
     dot-then-elementwise composition -- the measurement adjudicates the
-    same pipeline the model scored."""
+    same pipeline the model scored.
+
+    Runs eagerly even when called while a jitted caller is being traced
+    (``schedule="auto"`` resolves at trace time): under the caller's
+    trace the timed calls would be staged into it, and the clock would
+    time tracing, not the device.  (``jax.ensure_compile_time_eval``
+    is not enough: it folds the kernel's index maps into constants.)"""
+    import jax
+
+    with jax.core.eval_context():
+        return _measure(cfg, m, n, k, dtype, interpret=interpret,
+                        reps=reps, warmup=warmup, seed=seed,
+                        batched=batched, epilogue=epilogue)
+
+
+def _measure(cfg, m, n, k, dtype, *, interpret, reps, warmup, seed,
+             batched, epilogue) -> float:
     import jax.numpy as jnp
 
     from repro.kernels.ops import sfc_matmul, sfc_matmul_batched

@@ -242,6 +242,30 @@ def test_auto_schedule_matches_ref_interpret(tune_cache):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("schedule", ["xla", "morton"])
+def test_measure_config_inside_a_trace(schedule):
+    """schedule="auto" resolves -- and on a TPU measures -- while the
+    caller is being traced.  The measurement must run the candidate
+    eagerly (not stage it into the caller's trace, where the clock would
+    time tracing) and must not fold the kernel's index maps into
+    constants."""
+    import jax
+
+    from repro.tune import measure_config
+
+    seen = []
+
+    def traced(x):
+        seen.append(measure_config(
+            TuneConfig(schedule, 128, 128, 128), 128, 256, 128,
+            interpret=True, reps=1, warmup=1))
+        return x
+
+    staged = jax.make_jaxpr(traced)(1.0)
+    assert not staged.eqns       # nothing of the measurement was staged
+    assert isinstance(seen[0], float) and seen[0] > 0
+
+
 def test_auto_batched(tune_cache):
     a = _rand((3, 48, 40), jnp.float32, 4)
     b = _rand((3, 40, 56), jnp.float32, 5)

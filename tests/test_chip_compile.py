@@ -1,0 +1,157 @@
+"""The main-path Pallas kernels compile for a TPU v5e chip.
+
+Interpret mode runs a kernel's logic but not the TPU compiler, so a
+block the compiler refuses (alignment, VMEM, partitioning) would first
+show on the chip.  These tests compile for one chip of a *described*
+``v5e:2x2`` topology -- no chip needed -- at the qwen3-1.7B widths:
+
+* ``sfc_matmul_pallas`` in bf16 at every projection and vocab-head
+  shape of the model, under every config the tuner proposes there
+  (schedules x the blocks of ``tune/autotune._BLOCK_CANDIDATES``), and
+  under each epilogue;
+* ``paged_decode_attention_pallas`` with pages of 8 and 16.
+
+The topology is described inside a fixture, never at import, so that
+only the test worker that runs this file loads the TPU library.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.configs import get_config
+from repro.kernels.paged_attention import paged_decode_attention_pallas
+from repro.kernels.sfc_matmul import sfc_matmul_pallas
+from repro.serve.paged_kv import default_pool_pages, default_slot_pages
+from repro.tune import candidate_configs
+
+CFG = get_config("qwen3_1_7b")
+D, DFF, DH = CFG.d_model, CFG.d_ff, CFG.d_head
+# (N, K) of each GEMM a decode or prefill step runs, by role
+GEMMS = {
+    "q_o": (CFG.n_heads * DH, D),
+    "kv": (CFG.n_kv_heads * DH, D),
+    "gate_up": (DFF, D),
+    "down": (D, DFF),
+    "head": (CFG.vocab, D),
+}
+# M: 128 rows is the decode step (4 slots padded to one 128-row block)
+# and the prefill chunk (4 slots x 32 tokens); 2048 is a long prefill,
+# where every block of the tuner's candidate list fits
+ROWS = (128, 2048)
+EPILOGUES = {
+    "none": {},
+    "bias": {"bias": True},
+    "relu": {"activation": "relu"},
+    "gelu": {"activation": "gelu"},
+    "silu": {"activation": "silu"},
+    "residual": {"residual": True},
+    "bias_gelu_residual": {"bias": True, "activation": "gelu",
+                           "residual": True},
+    "f32_out": {"out_dtype": jnp.float32},
+}
+CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2, with JAX's persistent cache off
+    (a compile for a described chip is written to it but cannot be read
+    back without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+    compilation_cache.reset_cache()
+    if log_dir is None:
+        os.environ.pop("TPU_LOG_DIR", None)
+    else:
+        os.environ["TPU_LOG_DIR"] = log_dir
+
+
+def _compile_gemm(sharding, cfg, m, n, k, *, bias=False, residual=False,
+                  activation="none", out_dtype=None) -> str:
+    """Compiled HLO of one bf16 kernel call at the padded (m, n, k)."""
+    def pad(x, b):
+        return -(-x // b) * b
+
+    mp, np_, kp = pad(m, cfg.bm), pad(n, cfg.bn), pad(k, cfg.bk)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+
+    def call(a, b, bias_, res):
+        return sfc_matmul_pallas(
+            a, b, schedule=cfg.schedule, bm=cfg.bm, bn=cfg.bn, bk=cfg.bk,
+            use_prefetch=cfg.use_prefetch, g=cfg.g, bias=bias_,
+            residual=res, activation=activation, out_dtype=out_dtype)
+
+    args = (spec(mp, kp), spec(kp, np_), spec(np_) if bias else None,
+            spec(mp, np_) if residual else None)
+    return jax.jit(call).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("rows", ROWS)
+@pytest.mark.parametrize("role", sorted(GEMMS))
+def test_sfc_matmul_tuner_candidates_compile(one_chip, role, rows):
+    n, k = GEMMS[role]
+    cands = [c for c in candidate_configs(rows, n, k, dtype_bytes=2)
+             if c.schedule != "xla"]
+    assert {c.schedule for c in cands} >= {"rowmajor", "morton",
+                                           "hilbert", "supertile"}
+    for c in cands:
+        assert CUSTOM_CALL in _compile_gemm(one_chip, c, rows, n, k), c
+
+
+def test_every_candidate_block_is_compiled():
+    """The shapes above reach every block the tuner can propose."""
+    from repro.tune.autotune import _BLOCK_CANDIDATES
+
+    seen = {(c.bm, c.bn, c.bk) for role in GEMMS for rows in ROWS
+            for c in candidate_configs(rows, *GEMMS[role], dtype_bytes=2)
+            if c.schedule != "xla"}
+    assert seen == set(_BLOCK_CANDIDATES)
+
+
+@pytest.mark.parametrize("epilogue", sorted(EPILOGUES))
+def test_sfc_matmul_epilogues_compile(one_chip, epilogue):
+    """Each epilogue, under each schedule the tuner proposes, at the
+    MLP up-projection of a long prefill."""
+    n, k = GEMMS["gate_up"]
+    for c in candidate_configs(2048, n, k, dtype_bytes=2):
+        if c.schedule == "xla" or (c.bm, c.bn, c.bk) != (256, 256, 128):
+            continue
+        hlo = _compile_gemm(one_chip, c, 2048, n, k, **EPILOGUES[epilogue])
+        assert CUSTOM_CALL in hlo, c
+
+
+@pytest.mark.parametrize("page_size", [8, 16])
+def test_paged_attention_compiles(one_chip, page_size):
+    """The serve pool of 4 slots x 256 tokens at the qwen3 widths."""
+    slots, cache_len = 4, 256
+    pages = default_pool_pages(slots, cache_len, page_size)
+    rows = CFG.n_layers * pages + 1          # + the reserved zero row
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = spec((rows, page_size, CFG.n_kv_heads, DH))
+    hlo = jax.jit(paged_decode_attention_pallas).lower(
+        spec((slots, CFG.n_heads, DH)), pool, pool,
+        spec((slots, default_slot_pages(pages, cache_len, page_size)),
+             jnp.int32),
+        spec((slots,), jnp.int32)).compile().as_text()
+    assert CUSTOM_CALL in hlo

@@ -267,6 +267,53 @@ def test_kernel_dispatch_degrades_sticky_to_ref():
         pa.reset_fallback()
 
 
+def _paged_inputs():
+    rng = np.random.default_rng(0)
+    B, H, hkv, dh, ps, maxp = 2, 4, 2, 8, 4, 3
+    q = jnp.asarray(rng.standard_normal((B, H, dh)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((7, ps, hkv, dh)), jnp.float32)
+    tab = jnp.asarray(rng.integers(0, 6, size=(B, maxp)), jnp.int32)
+    return q, kp, kp, tab
+
+
+def test_kernel_build_fault_propagates(monkeypatch):
+    """A Pallas fault nobody injected is a real fault: it propagates
+    and marks no fallback (a silent switch to the reference would hide
+    that the kernel never ran on the chip)."""
+    from repro.kernels import paged_attention as pa
+    pa.reset_fallback()
+
+    def broken(*a, **k):
+        raise RuntimeError("mosaic refused the kernel")
+
+    monkeypatch.setattr(pa, "paged_decode_attention_pallas", broken)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        pa.paged_decode_attention(*_paged_inputs(), jnp.int32(5),
+                                  interpret=True)
+    assert pa.FALLBACK_EVENTS == []
+
+
+def test_serve_step_fault_propagates(cfg, params):
+    """A failing jitted step on a guarded paged loop raises out of
+    run(): no retry, no restore, no degradation."""
+    sc = ServeConfig(slots=2, cache_len=64, layout="paged",
+                     mode="continuous", prefill_budget=16)
+    m = MetricsRegistry()
+    loop = ServeLoop(cfg, params, sc, metrics=m)
+    assert loop.guards
+
+    def broken(*a):
+        raise RuntimeError("device lost")
+
+    loop._step = broken
+    loop.submit(0, [5, 6, 7, 8])
+    with pytest.raises(RuntimeError, match="device lost"):
+        loop.run(max_new=4)
+    assert not loop._kernel_degraded
+    assert m.counter("serve.degraded").value == 0
+    assert m.counter("serve.retries").value == 0
+
+
 # ------------------------------------------------- power degradation -----
 def test_power_chaos_degrades_to_zero_joules():
     from repro.obs import default_registry

@@ -130,6 +130,46 @@ def test_detect_explicit_preference_and_fallback(rapl_root, tmp_path,
         detect_backend("wattmeter")
 
 
+class _Device:
+    """Stands in for a JAX device: only what detection reads."""
+
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def _on_device(monkeypatch, platform, kind):
+    import jax
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: [_Device(platform, kind)])
+
+
+def test_detect_on_tpu_is_the_model_even_with_host_counters(
+        rapl_root, monkeypatch):
+    """RAPL reads the host CPU package: on a TPU the model is the only
+    backend, whatever counters the host has."""
+    _on_device(monkeypatch, "tpu", "TPU v5 lite")
+    b = detect_backend(rapl_root=rapl_root)
+    assert b.name == "model" and b.hw is TPU_V5E
+    for counter in ("rapl", "nvml"):
+        with pytest.raises(ValueError, match="only 'model'"):
+            detect_backend(counter, rapl_root=rapl_root)
+    monkeypatch.setenv("REPRO_POWER_BACKEND", "rapl")
+    with pytest.raises(ValueError, match="only 'model'"):
+        detect_backend(rapl_root=rapl_root)
+
+
+def test_unknown_tpu_kind_raises(monkeypatch):
+    from repro.core.energy import hw_for_device
+    with pytest.raises(ValueError, match="TPU v9 ultra"):
+        hw_for_device(_Device("tpu", "TPU v9 ultra"))
+    assert hw_for_device(_Device("tpu", "TPU v5 lite")) is TPU_V5E
+    assert hw_for_device(_Device("cpu", "cpu")) is TPU_V5E
+    _on_device(monkeypatch, "tpu", "TPU v9 ultra")
+    with pytest.raises(ValueError, match="no modeled chip"):
+        detect_backend()
+
+
 # ------------------------------------------------------------ meter + model
 def test_model_backend_reading_is_non_degenerate():
     """Acceptance: in a container with no counters the ModelBackend must

@@ -116,6 +116,61 @@ def test_serve_with_energy_objective(isolated_tune_cache):
     assert loop.energy.meta["objective"] == "energy"
 
 
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and nothing
+    else is set; otherwise every call yields the same path inside the
+    checkout."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, \
+        enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+        assert enable_compile_cache() == "/elsewhere/jax"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = enable_compile_cache()
+        assert first == enable_compile_cache() == CHECKOUT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == first
+        checkout = Path(__file__).resolve().parents[1]
+        assert Path(first) == checkout / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_serve_cli_fails_on_uninjected_degradation(monkeypatch):
+    """The serve CLI exits non-zero when its loop degraded to the
+    reference kernel without an injected kernel fault; an injected one
+    is the chaos path and serves to the end."""
+    from repro.kernels import paged_attention as pa
+    from repro.launch import serve
+
+    argv = ["--arch", "qwen3_1_7b", "--smoke", "--layout", "paged",
+            "--mode", "continuous", "--max-new", "3"]
+    prompts = [[5, 6, 7, 8], [9, 10, 11]]
+    try:
+        loop = serve.main(argv + ["--chaos", "kernel@step=1"],
+                          prompts=prompts)
+        assert loop._kernel_degraded and not loop.errors
+        assert all(len(loop.out[r]) == len(p) + 3
+                   for r, p in enumerate(prompts))
+        pa.reset_fallback()
+
+        run = serve.ServeLoop.run
+
+        def degrading_run(self, max_new=32):
+            self._engage_kernel_fallback("planted")
+            return run(self, max_new)
+
+        monkeypatch.setattr(serve.ServeLoop, "run", degrading_run)
+        with pytest.raises(SystemExit, match="without an injected"):
+            serve.main(argv, prompts=prompts)
+    finally:
+        pa.reset_fallback()
+
+
 def test_benchmark_driver_runs():
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "bench_locality"],
