@@ -45,15 +45,19 @@ def init_attention(key, cfg, dtype=jnp.float32):
 def _project_qkv(x, p, cfg, engine: DotEngine, cos, sin):
     b, s, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    q = engine.dot(x, p["wq"]).reshape(b, s, h, dh)
-    k = engine.dot(x, p["wk"]).reshape(b, s, hkv, dh)
-    v = engine.dot(x, p["wv"]).reshape(b, s, hkv, dh)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
-    if cfg.rope:
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+    with jax.named_scope("q"):
+        q = engine.dot(x, p["wq"]).reshape(b, s, h, dh)
+    with jax.named_scope("k"):
+        k = engine.dot(x, p["wk"]).reshape(b, s, hkv, dh)
+    with jax.named_scope("v"):
+        v = engine.dot(x, p["wv"]).reshape(b, s, hkv, dh)
+    with jax.named_scope("core"):
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"])
+            k = rms_norm(k, p["k_norm"])
+        if cfg.rope:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
     return q, k, v
 
 
@@ -88,21 +92,28 @@ def attention(x, p, cfg, engine: DotEngine, cos, sin, *,
 
     b, s, _ = x.shape
     q, k, v = _project_qkv(x, p, cfg, engine, cos, sin)
-    # SP attention core: queries sequence-sharded over "model" (head-count
-    # agnostic, always divisible); k/v replicated across it (DESIGN.md §5)
-    q = constrain(q, "dp", "model", None, None)
-    k = constrain(k, "dp", None, None, None)
-    v = constrain(v, "dp", None, None, None)
+    with jax.named_scope("core"):
+        # SP attention core: queries sequence-sharded over "model"
+        # (head-count agnostic, always divisible); k/v replicated across
+        # it (DESIGN.md §5)
+        q = constrain(q, "dp", "model", None, None)
+        k = constrain(k, "dp", None, None, None)
+        v = constrain(v, "dp", None, None, None)
+        out = _full_core(q, k, v, cfg, q_chunk)
+        out = constrain(out, "dp", "model", None, None)
+    with jax.named_scope("o"):
+        out = engine.dot(out.reshape(b, s, -1), p["wo"], residual=residual)
+    return (out, k, v) if return_kv else out
+
+
+def _full_core(q, k, v, cfg, q_chunk: int):
+    """softmax(q k^T) v over the whole sequence: bidirectional, or causal
+    in statically unrolled q chunks (within the SWA window, if any)."""
+    s = q.shape[1]
     scale = 1.0 / math.sqrt(cfg.d_head)
     window = cfg.swa_window
-
     if not cfg.causal:
-        out = _sdpa(q, k, v, None, scale)
-        out = constrain(out, "dp", "model", None, None)
-        out = engine.dot(out.reshape(b, s, -1), p["wo"],
-                         residual=residual)
-        return (out, k, v) if return_kv else out
-
+        return _sdpa(q, k, v, None, scale)
     c = min(q_chunk, s)
     assert s % c == 0, (s, c)
     outs = []
@@ -121,10 +132,7 @@ def attention(x, p, cfg, engine: DotEngine, cos, sin, *,
         if window is not None:
             mask &= kpos > qpos - window
         outs.append(_sdpa(q_i, k_i, v_i, mask[None, None, None], scale))
-    out = jnp.concatenate(outs, axis=1)
-    out = constrain(out, "dp", "model", None, None)
-    out = engine.dot(out.reshape(b, s, -1), p["wo"], residual=residual)
-    return (out, k, v) if return_kv else out
+    return jnp.concatenate(outs, axis=1)
 
 
 def prefill_kv(x, p, cfg, engine: DotEngine, cos, sin):
@@ -195,8 +203,10 @@ def paged_decode_attention(x, p, cfg, engine: DotEngine, k_pages, v_pages,
             core, mesh=ctx.mesh,
             in_specs=(q_spec, kv_spec, kv_spec, P(), P()),
             out_specs=q_spec, check_vma=False)
-    out = core(q[:, 0], k_pages, v_pages, phys_tables, pos)
-    out = engine.dot(out.reshape(b, 1, -1), p["wo"], residual=residual)
+    with jax.named_scope("core"):
+        out = core(q[:, 0], k_pages, v_pages, phys_tables, pos)
+    with jax.named_scope("o"):
+        out = engine.dot(out.reshape(b, 1, -1), p["wo"], residual=residual)
     return out, k_pages, v_pages
 
 
@@ -232,24 +242,35 @@ def decode_attention(x, p, cfg, engine: DotEngine, k_cache, v_cache,
         raise NotImplementedError(
             "per-slot position vectors are single-device only; the "
             "sequence-parallel decode path takes a scalar position")
-    if c is not None:
-        # sequence-parallel decode: KV cache sharded along S, online-softmax
-        # combine across shards (repro.distributed.sp_attention).
-        from repro.distributed.sp_attention import sp_decode_attention
-        seq_axes = getattr(c, "seq_axes", None) or (c.model_axis,)
-        out, k_cache, v_cache, _ = sp_decode_attention(
-            q, k_cache, v_cache, cache_positions, k_new, v_new,
-            write_slot, cur_pos, mesh=c.mesh, window=cfg.swa_window,
-            seq_axes=seq_axes,
-            dp_axes=tuple(a for a in c.dp if a not in seq_axes),
-            row_mask=row_mask)
-        out = engine.dot(out.reshape(b, 1, -1), p["wo"],
-                         residual=residual)
-        return out, k_cache, v_cache
+    with jax.named_scope("core"):
+        if c is not None:
+            # sequence-parallel decode: KV cache sharded along S,
+            # online-softmax combine across shards
+            # (repro.distributed.sp_attention).
+            from repro.distributed.sp_attention import sp_decode_attention
+            seq_axes = getattr(c, "seq_axes", None) or (c.model_axis,)
+            out, k_cache, v_cache, _ = sp_decode_attention(
+                q, k_cache, v_cache, cache_positions, k_new, v_new,
+                write_slot, cur_pos, mesh=c.mesh, window=cfg.swa_window,
+                seq_axes=seq_axes,
+                dp_axes=tuple(a for a in c.dp if a not in seq_axes),
+                row_mask=row_mask)
+        else:
+            out, k_cache, v_cache = _cached_core(
+                q, k_new, v_new, k_cache, v_cache, cache_positions,
+                write_slot, cur_pos, cfg, row_mask)
+    with jax.named_scope("o"):
+        out = engine.dot(out.reshape(b, 1, -1), p["wo"], residual=residual)
+    return out, k_cache, v_cache
 
+
+def _cached_core(q, k_new, v_new, k_cache, v_cache, cache_positions,
+                 write_slot, cur_pos, cfg, row_mask):
+    """Write the new token's K/V into one device's cache and attend over
+    it: ``decode_attention`` without a mesh."""
     slots = jnp.arange(k_cache.shape[1])
     scale = 1.0 / math.sqrt(cfg.d_head)
-    if vector_pos:
+    if jnp.ndim(cur_pos) > 0:
         # per-row write slot + per-row dense validity (no kv_pos): row b
         # attends exactly to its own positions [0, cur_pos[b]]
         sel = (slots[None, :] == write_slot[:, None])[:, :, None, None]
@@ -260,7 +281,6 @@ def decode_attention(x, p, cfg, engine: DotEngine, k_cache, v_cache,
         valid = slots[None, :] <= cur_pos[:, None]           # (B, S)
         out = _sdpa(q, k_cache, v_cache,
                     valid[:, None, None, None, :], scale)
-        out = engine.dot(out.reshape(b, 1, -1), p["wo"], residual=residual)
         return out, k_cache, v_cache
     sel = (slots == write_slot)[None, :, None, None]
     if row_mask is not None:  # slot-isolated writes (continuous batching)
@@ -272,5 +292,4 @@ def decode_attention(x, p, cfg, engine: DotEngine, k_cache, v_cache,
     if cfg.swa_window is not None:
         valid &= pos > cur_pos - cfg.swa_window
     out = _sdpa(q, k_cache, v_cache, valid[None, None, None, None, :], scale)
-    out = engine.dot(out.reshape(b, 1, -1), p["wo"], residual=residual)
     return out, k_cache, v_cache

@@ -175,9 +175,12 @@ def swiglu_mlp(x, params, engine: DotEngine, residual=None):
     f32 accumulator in-kernel on the Pallas path) and ``residual`` rides
     the down-projection's -- the layer's post-matmul elementwise HBM
     passes collapse into the GEMM flushes (DESIGN.md §9)."""
-    g = engine.dot(x, params["w1"], activation="silu")
-    u = engine.dot(x, params["w3"])
-    return engine.dot(g * u, params["w2"], residual=residual)
+    with jax.named_scope("gate"):
+        g = engine.dot(x, params["w1"], activation="silu")
+    with jax.named_scope("up"):
+        u = engine.dot(x, params["w3"])
+    with jax.named_scope("down"):
+        return engine.dot(g * u, params["w2"], residual=residual)
 
 
 def init_swiglu(key, d: int, d_ff: int, dtype=jnp.float32):

@@ -113,15 +113,18 @@ def _layer_fwd(x, lp, cfg: ArchConfig, engine: DotEngine, cos, sin, mesh):
     # residual adds ride the out-projection / down-projection GEMMs'
     # fused epilogues instead of separate elementwise passes (DESIGN.md §9)
     if cfg.family in ("dense", "encoder", "vlm"):
-        x = attn_mod.attention(rms_norm(x, lp["norm1"]), lp["attn"], cfg,
-                               engine, cos, sin,
-                               q_chunk=cfg.attn_q_chunk, residual=x)
-        x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
-                       residual=x)
+        with jax.named_scope("attn"):
+            x = attn_mod.attention(rms_norm(x, lp["norm1"]), lp["attn"],
+                                   cfg, engine, cos, sin,
+                                   q_chunk=cfg.attn_q_chunk, residual=x)
+        with jax.named_scope("mlp"):
+            x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
+                           residual=x)
     elif cfg.family == "moe":
-        x = attn_mod.attention(rms_norm(x, lp["norm1"]), lp["attn"], cfg,
-                               engine, cos, sin,
-                               q_chunk=cfg.attn_q_chunk, residual=x)
+        with jax.named_scope("attn"):
+            x = attn_mod.attention(rms_norm(x, lp["norm1"]), lp["attn"],
+                                   cfg, engine, cos, sin,
+                                   q_chunk=cfg.attn_q_chunk, residual=x)
         y, aux = moe_mod.moe_ffn(
             rms_norm(x, lp["norm2"]), lp["moe"], cfg, engine, mesh=mesh,
             data_axes=(c.dp if c is not None else ("data",)))
@@ -170,13 +173,14 @@ def forward(params, cfg: ArchConfig, batch, engine: DotEngine | None = None,
     """Full-sequence forward -> (logits (B,S,V) f32, aux_loss)."""
     from repro.distributed.ctx import constrain
     engine = engine or DotEngine()
-    x = embed_inputs(params, cfg, batch, engine)
-    x = constrain(x, "dp", None, None)
-    b, s, _ = x.shape
-    if cfg.has_attention and cfg.rope:
-        cos, sin = rope(jnp.arange(s), cfg.d_head, cfg.rope_theta)
-    else:
-        cos = sin = None
+    with jax.named_scope("embed"):
+        x = embed_inputs(params, cfg, batch, engine)
+        x = constrain(x, "dp", None, None)
+        b, s, _ = x.shape
+        if cfg.has_attention and cfg.rope:
+            cos, sin = rope(jnp.arange(s), cfg.d_head, cfg.rope_theta)
+        else:
+            cos = sin = None
 
     def body(x, lp):
         return _layer_fwd(x, lp, cfg, engine, cos, sin, mesh)
@@ -189,16 +193,24 @@ def forward(params, cfg: ArchConfig, batch, engine: DotEngine | None = None,
             body = jax.checkpoint(body, policy=policy)
         else:
             body = jax.checkpoint(body)
-    x, auxs = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"])
-    from repro.distributed.ctx import constrain
-    # vocab head: the f32 cast is fused into the GEMM's single output
-    # write instead of a separate full-logits cast pass
-    logits = engine.dot(x, params["lm_head"], out_dtype=jnp.float32) \
-        if cfg.vocab else x
-    logits = _mask_padded_vocab(logits, cfg)
-    logits = constrain(logits, "dp", None, "model")
-    return logits, auxs.mean()
+    with jax.named_scope("layers"):
+        x, auxs = jax.lax.scan(body, x, params["layers"])
+    logits = _head(params, cfg, x, engine)
+    return constrain(logits, "dp", None, "model"), auxs.mean()
+
+
+def _head(params, cfg: ArchConfig, x, engine: DotEngine):
+    """Final norm and vocab head: (..., d) -> (..., V) f32 logits,
+    padded columns masked (the normed activations where the family has
+    no vocab).  The f32 cast is fused into the GEMM's single output
+    write instead of a separate full-logits cast pass."""
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"])
+    if not cfg.vocab:
+        return x
+    with jax.named_scope("head"):
+        logits = engine.dot(x, params["lm_head"], out_dtype=jnp.float32)
+        return _mask_padded_vocab(logits, cfg)
 
 
 def _mask_padded_vocab(logits, cfg: ArchConfig):
@@ -313,7 +325,8 @@ def prefill_kv(params, cfg: ArchConfig, state, tokens, slot: int = 0,
             f"{cfg.family!r}")
     toks = jnp.asarray(tokens, jnp.int32).reshape(1, -1)
     seq = toks.shape[1]
-    x = jnp.take(params["embed"], toks, axis=0).astype(cfg.act_jdtype())
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], toks, axis=0).astype(cfg.act_jdtype())
     if cfg.rope:
         cos, sin = rope(jnp.arange(seq), cfg.d_head, cfg.rope_theta)
     else:
@@ -335,7 +348,8 @@ def prefill_kv(params, cfg: ArchConfig, state, tokens, slot: int = 0,
             x = x + y
         return x, (k, v)
 
-    x, (k, v) = jax.lax.scan(body, x, params["layers"])
+    with jax.named_scope("layers"):
+        x, (k, v) = jax.lax.scan(body, x, params["layers"])
     k, v = k[:, 0], v[:, 0]          # (L_layers, seq, hkv, dh)
     from repro.serve.state import copy_state
     new_state = copy_state(state)
@@ -365,9 +379,7 @@ def prefill_kv(params, cfg: ArchConfig, state, tokens, slot: int = 0,
         new_state["v"] = state["v"].at[:, slot, :seq].set(v)
         new_state["kv_pos"] = state["kv_pos"].at[:seq].set(
             jnp.arange(seq, dtype=jnp.int32))
-    x = rms_norm(x, params["final_norm"])
-    logits = engine.dot(x, params["lm_head"], out_dtype=jnp.float32)
-    return _mask_padded_vocab(logits, cfg), new_state
+    return _head(params, cfg, x, engine), new_state
 
 
 def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
@@ -408,7 +420,8 @@ def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
     lens_v = jnp.asarray(lengths, jnp.int32).reshape(-1)
     pos2d = starts_v[:, None] + jnp.arange(chunk, dtype=jnp.int32)  # (G, L)
     valid = jnp.arange(chunk)[None, :] < lens_v[:, None]            # (G, L)
-    x = jnp.take(params["embed"], toks, axis=0).astype(cfg.act_jdtype())
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], toks, axis=0).astype(cfg.act_jdtype())
     if cfg.rope:
         cos, sin = rope(pos2d, cfg.d_head, cfg.rope_theta)  # (G, L, dh/2)
     else:
@@ -470,9 +483,11 @@ def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
         kpos = jnp.arange(sk, dtype=jnp.int32)[None, None, :]
         mask = kpos <= jnp.minimum(
             pos2d, (starts_v + lens_v - 1)[:, None])[:, :, None]
-        o = attn_mod._sdpa(q, kf, vf, mask[:, None, None], scale)
-        x = engine.dot(o.reshape(g, chunk, -1), lp["attn"]["wo"],
-                       residual=x)
+        with jax.named_scope("core"):
+            o = attn_mod._sdpa(q, kf, vf, mask[:, None, None], scale)
+        with jax.named_scope("o"):
+            x = engine.dot(o.reshape(g, chunk, -1), lp["attn"]["wo"],
+                           residual=x)
         if cfg.family in ("dense", "vlm"):
             x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
                            residual=x)
@@ -490,9 +505,10 @@ def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
             x, kp, vp = _chunk_layer(x, layer["p"], kp, vp, phys)
             return (x, kp, vp), None
 
-        (x, kp, vp), _ = jax.lax.scan(
-            body, (x, state["k_pages"], state["v_pages"]),
-            {"p": params["layers"], "perm": state["page_perm"]})
+        with jax.named_scope("layers"):
+            (x, kp, vp), _ = jax.lax.scan(
+                body, (x, state["k_pages"], state["v_pages"]),
+                {"p": params["layers"], "perm": state["page_perm"]})
         new_state["k_pages"] = kp
         new_state["v_pages"] = vp
     else:
@@ -501,9 +517,10 @@ def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
                                      layer["v"], None)
             return x, (kc, vc)
 
-        x, (kc, vc) = jax.lax.scan(
-            body, x, {"p": params["layers"], "k": state["k"],
-                      "v": state["v"]})
+        with jax.named_scope("layers"):
+            x, (kc, vc) = jax.lax.scan(
+                body, x, {"p": params["layers"], "k": state["k"],
+                          "v": state["v"]})
         new_state["k"] = kc
         new_state["v"] = vc
         # dense discipline: slot p holds position p (the vector decode
@@ -525,7 +542,9 @@ def _decode_step_paged(params, cfg: ArchConfig, state, tokens, pos,
     from repro.serve.paged_kv import physical_rows, zero_row_index
     from repro.serve.state import copy_state
 
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.act_jdtype())
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(
+            cfg.act_jdtype())
     cos, sin = _decode_rope(cfg, pos) if cfg.rope else (None, None)
     zero_row = zero_row_index(state["k_pages"])
     bt = state["block_tables"]
@@ -551,15 +570,14 @@ def _decode_step_paged(params, cfg: ArchConfig, state, tokens, pos,
             x = x + y
         return (x, kp, vp), None
 
-    (x, kp, vp), _ = jax.lax.scan(
-        body, (x, state["k_pages"], state["v_pages"]),
-        {"p": params["layers"], "perm": state["page_perm"]})
+    with jax.named_scope("layers"):
+        (x, kp, vp), _ = jax.lax.scan(
+            body, (x, state["k_pages"], state["v_pages"]),
+            {"p": params["layers"], "perm": state["page_perm"]})
     new_state = copy_state(state)
     new_state["k_pages"] = kp
     new_state["v_pages"] = vp
-    x = rms_norm(x, params["final_norm"])
-    logits = engine.dot(x, params["lm_head"], out_dtype=jnp.float32)
-    return _mask_padded_vocab(logits, cfg), new_state
+    return _head(params, cfg, x, engine), new_state
 
 
 def decode_step(params, cfg: ArchConfig, state, tokens, pos,
@@ -582,7 +600,9 @@ def decode_step(params, cfg: ArchConfig, state, tokens, pos,
         return _decode_step_paged(params, cfg, state, tokens, pos,
                                   engine, row_mask)
     from repro.serve.state import copy_state
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.act_jdtype())
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(
+            cfg.act_jdtype())
     if cfg.has_attention and cfg.rope:
         cos, sin = _decode_rope(cfg, pos)  # (1|B, 1, dh/2)
     else:
@@ -642,7 +662,8 @@ def decode_step(params, cfg: ArchConfig, state, tokens, pos,
     for key in ("k", "v", "ssm_h", "ssm_conv"):
         if key in state:
             xs[key] = state[key]
-    x, upd = jax.lax.scan(body, x, xs)
+    with jax.named_scope("layers"):
+        x, upd = jax.lax.scan(body, x, xs)
     new_state = copy_state(state)
     for key in ("ssm_h", "ssm_conv"):
         if key in upd:
@@ -651,7 +672,4 @@ def decode_step(params, cfg: ArchConfig, state, tokens, pos,
         new_state["k"] = upd["k"]
         new_state["v"] = upd["v"]
         new_state["kv_pos"] = state["kv_pos"].at[slot].set(pos)
-    x = rms_norm(x, params["final_norm"])
-    logits = engine.dot(x, params["lm_head"], out_dtype=jnp.float32)
-    logits = _mask_padded_vocab(logits, cfg)
-    return logits, new_state
+    return _head(params, cfg, x, engine), new_state

@@ -17,8 +17,12 @@ every tracer, which is what lets :func:`attribute_energy` (called by
 innermost enclosing span without the meter ever holding a tracer
 reference -- the trace answers "which phase burned the joules".
 
-A disabled tracer's ``span()`` returns a shared no-op context manager
-and records nothing (near-zero cost, benchmarked).
+An enabled tracer's sync span also opens a
+``jax.profiler.TraceAnnotation`` of the same name for its lifetime, so
+that under a running ``jax.profiler`` trace every span lands in the
+profiler's host plane, on the clock of the device's operations.  A
+disabled tracer's ``span()`` returns a shared no-op context manager and
+records nothing (near-zero cost, benchmarked).
 
 CLI (JSONL -> Chrome trace JSON, schema-validated)::
 
@@ -35,6 +39,8 @@ import os
 import threading
 import time
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "trace_span", "default_tracer", "set_default_tracer",
            "attribute_energy", "validate_trace"]
@@ -81,9 +87,10 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     """One open sync span: pushes its event dict on the thread-local
-    stack at enter, stamps ``dur`` and appends to the tracer at exit."""
+    stack and opens its profiler annotation at enter, stamps ``dur``,
+    closes the annotation and appends to the tracer at exit."""
 
-    __slots__ = ("_tracer", "_ev", "_t0")
+    __slots__ = ("_tracer", "_ev", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -97,10 +104,13 @@ class _Span:
         self._t0 = time.monotonic_ns()
         self._ev["ts"] = self._t0 / 1e3
         st.append(self._ev)
+        self._ann = TraceAnnotation(self._ev["name"])
+        self._ann.__enter__()
         return self._ev["args"]
 
     def __exit__(self, exc_type, exc, tb):
         self._ev["dur"] = (time.monotonic_ns() - self._t0) / 1e3
+        self._ann.__exit__(exc_type, exc, tb)
         st = _open_stack()
         if st and st[-1] is self._ev:
             st.pop()
@@ -177,10 +187,6 @@ class Tracer:
         with open(path, "w") as f:
             for ev in self.events:
                 f.write(json.dumps(ev, sort_keys=True) + "\n")
-
-    def write_chrome(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.to_chrome(), f, indent=1, sort_keys=True)
 
 
 # ------------------------------------------------------- default tracer ---

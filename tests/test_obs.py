@@ -222,6 +222,46 @@ def test_disabled_tracer_records_nothing():
     assert tr.events == []
 
 
+def _host_events(profile_dir) -> dict:
+    """{name: [(line, start_ns, end_ns)]} of a profile's host planes."""
+    from jax.profiler import ProfileData
+
+    out: dict = {}
+    for path in Path(profile_dir).rglob("*.xplane.pb"):
+        for plane in ProfileData.from_file(str(path)).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        s = float(ev.start_ns)
+                        out.setdefault(ev.name, []).append(
+                            (line.name, s, s + float(ev.duration_ns)))
+    return out
+
+
+def test_spans_land_in_the_profilers_host_plane(tmp_path):
+    """An enabled tracer's span is a host event of a running profiler
+    trace, nested in the annotation it was opened under, on the same
+    thread; a disabled tracer's span adds nothing."""
+    on, off = Tracer(enabled=True), Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.outer"):
+            with on.span("test.span", step=1):
+                time.sleep(0.001)
+            with off.span("test.disabled_span"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    host = _host_events(tmp_path)
+    (outer,), (span,) = host["test.outer"], host["test.span"]
+    assert span[0] == outer[0]
+    assert outer[1] <= span[1] < span[2] <= outer[2]
+    assert span[2] - span[1] >= 1e6
+    assert "test.disabled_span" not in host
+    assert [ev["name"] for ev in on.events] == ["test.span"]
+    assert off.events == []
+
+
 def test_energy_attribution_lands_on_innermost_span():
     """Top-level meter readings attach joules to the enclosing span;
     nested readings ride inside their parent (no double count), so span
