@@ -101,20 +101,22 @@ class ContractReport:
 
 
 def gemm_vmem_bytes(cfg: TuneConfig, dtype_bytes: int = 4,
-                    epilogue: EpilogueSpec | None = None) -> int:
-    """Resident VMEM working set of one ``sfc_matmul`` grid step.
+                    epilogue: EpilogueSpec | None = None,
+                    k: int | None = None) -> int:
+    """Scoped VMEM of one ``sfc_matmul`` grid step: the kernel's own
+    count (:func:`repro.kernels.sfc_matmul.sfc_vmem_bytes`, double
+    buffers included), operands and output in ``dtype_bytes``.  ``k``,
+    the GEMM's K, says whether the f32 accumulator and a zeroed last K
+    block are held; None counts the accumulator, as where K takes more
+    than one block."""
+    from repro.kernels.sfc_matmul import sfc_vmem_bytes
 
-    A (bm, bk) + B (bk, bn) + staged C block (bm, bn) in the operand
-    dtype, the (bm, bn) f32 accumulator scratch, and -- when an epilogue
-    is fused -- its (1, bn) bias tile and (bm, bn) residual block."""
-    bm, bn, bk = cfg.bm, cfg.bn, cfg.bk
-    need = (bm * bk + bk * bn + bm * bn) * dtype_bytes + bm * bn * 4
-    if epilogue is not None and not epilogue.is_noop:
-        if epilogue.bias:
-            need += bn * dtype_bytes
-        if epilogue.residual:
-            need += bm * bn * dtype_bytes
-    return need
+    ep = None if (epilogue is None or epilogue.is_noop) else epilogue
+    kt, k_tail = (2, 0) if k is None else (-(-k // cfg.bk), k % cfg.bk)
+    return sfc_vmem_bytes(cfg.bm, cfg.bn, cfg.bk, dtype_bytes, dtype_bytes,
+                          bias=bool(ep and ep.bias),
+                          residual=bool(ep and ep.residual), kt=kt,
+                          k_tail=k_tail)
 
 
 def _closed_form_ok(schedule: str, mt: int, nt: int) -> bool:
@@ -142,9 +144,9 @@ def check_gemm_contract(
     ``level="fast"`` runs the O(1) arithmetic checks (structure, VMEM,
     closed-form existence) -- what the tuner applies per candidate.
     ``level="full"`` additionally replays the schedule permutation over
-    the whole (padded) grid and applies every kernel index map to it.
-    The padded grid mirrors ``repro.kernels.ops._pad_to``: operands are
-    padded up to block multiples, so the grid is the ceil-divided one.
+    the whole grid and applies every kernel index map to it.  The grid
+    is the ceil-divided one: the kernel's last block may overhang M, N
+    or K (``padded_shape`` is the extent the blocks cover).
     """
     rep = ContractReport(
         subject=f"gemm {m}x{n}x{k} {cfg.schedule} "
@@ -168,7 +170,7 @@ def check_gemm_contract(
 
     mt, nt, kt = -(-m // cfg.bm), -(-n // cfg.bn), -(-k // cfg.bk)
     ep = None if (epilogue is None or epilogue.is_noop) else epilogue
-    need = gemm_vmem_bytes(cfg, dtype_bytes, ep)
+    need = gemm_vmem_bytes(cfg, dtype_bytes, ep, k)
     budget = int(hw.vmem_per_chip * vmem_frac)
     rep.stats.update(
         grid=(mt, nt, kt), tiles=mt * nt,

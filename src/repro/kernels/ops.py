@@ -29,6 +29,18 @@ kernel with ``via_vmap=True``).
 wrappers, engine): the scalar-prefetch schedule table works on any grid
 and amortises index cost to zero.  ``False`` (the paper-faithful
 in-``index_map`` decode) is an explicit opt-in everywhere.
+
+Blocks: a block the caller names (``bm``/``bn``/``bk``, all three, or
+the tuner's under ``schedule="auto"``) is run as named; a block left
+``None`` is derived from the GEMM's shape, dtypes and epilogue
+(:func:`repro.kernels.sfc_matmul.sfc_blocks`).  Either way the operands
+go to the kernel as they are: a last block may overhang M, N or K, and
+the kernel crops or masks it, so no operand is padded or copied.  Every
+GEMM traced onto the kernel adds its grid steps and the steps that keep
+a resident A or B block to the ``sfc.grid_steps`` /
+``sfc.copies_elided`` counters of ``repro.obs``'s default registry
+(host arithmetic while the jitted wrapper traces, so once per trace and
+never per call).
 """
 from __future__ import annotations
 
@@ -37,8 +49,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.obs.metrics import default_registry
+
 from .ref import matmul_batched_fused_ref, matmul_fused_ref
-from .sfc_matmul import sfc_matmul_batched_pallas, sfc_matmul_pallas
+from .sfc_matmul import grid_step_counts, sfc_blocks, \
+    sfc_matmul_batched_pallas, sfc_matmul_pallas
 
 __all__ = ["sfc_matmul", "sfc_matmul_batched", "default_backend_is_tpu"]
 
@@ -47,22 +62,36 @@ def default_backend_is_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _pad_to(x, mult0: int, mult1: int):
-    """Pad the trailing two dims of ``x`` up to (mult0, mult1) multiples."""
-    p0 = (-x.shape[-2]) % mult0
-    p1 = (-x.shape[-1]) % mult1
-    if p0 or p1:
-        pad = [(0, 0)] * (x.ndim - 2) + [(0, p0), (0, p1)]
-        x = jnp.pad(x, pad)
-    return x
+def _uses_kernel(schedule: str, interpret, force_pallas: bool) -> bool:
+    """Whether the call runs the Pallas kernel: not the XLA baseline,
+    and on a TPU, in interpret mode, or forced."""
+    return schedule != "xla" and (
+        force_pallas or bool(interpret) or default_backend_is_tpu())
 
 
-def _pad_last(x, mult: int):
-    """Pad the last dim of ``x`` up to a ``mult`` multiple (bias vectors)."""
-    p = (-x.shape[-1]) % mult
-    if p:
-        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, p)])
-    return x
+def _resolve_blocks(m, n, k, a_dtype, out_dtype, bm, bn, bk, *,
+                    has_bias: bool, has_residual: bool):
+    """(bm, bn, bk): the named block, or the one derived from the shape
+    where all three are ``None``."""
+    if (bm, bn, bk) == (None, None, None):
+        return sfc_blocks(m, n, k, jnp.dtype(a_dtype).itemsize,
+                          jnp.dtype(out_dtype or a_dtype).itemsize,
+                          bias=has_bias, residual=has_residual)
+    if None in (bm, bn, bk):
+        raise ValueError(f"name all of bm, bn, bk or none: {(bm, bn, bk)}")
+    return bm, bn, bk
+
+
+def _count_grid(schedule: str, m, n, k, bm, bn, bk, g, batch=1) -> None:
+    """Add one traced kernel GEMM to the counters (called from the
+    jitted wrappers' bodies, which run only while tracing)."""
+    reg = default_registry()
+    if not reg.enabled:
+        return
+    steps, elided = grid_step_counts(schedule, -(-m // bm), -(-n // bn),
+                                     -(-k // bk), g, batch)
+    reg.counter("sfc.grid_steps").inc(steps)
+    reg.counter("sfc.copies_elided").inc(elided)
 
 
 def _resolve_auto(m: int, n: int, k: int, dtype, batched: bool = False,
@@ -119,27 +148,21 @@ def _sfc_matmul(
     residual=None,
 ):
     out_dtype = out_dtype or a.dtype
-    if schedule == "xla" or (
-            not force_pallas and not default_backend_is_tpu()
-            and not interpret):
+    if not _uses_kernel(schedule, interpret, force_pallas):
         # CPU/GPU fallback for real execution paths; kernels are still
         # exercised on CPU via interpret=True in tests/benchmarks.  The
         # fused math is reproduced exactly (f32 epilogue, single cast).
         return matmul_fused_ref(a, b, bias=bias, activation=activation,
                                 residual=residual, out_dtype=out_dtype)
 
-    m, n = a.shape[0], b.shape[1]
-    ap = _pad_to(a, bm, bk)
-    bp = _pad_to(b, bk, bn)
-    biasp = _pad_last(bias, bn) if bias is not None else None
-    resp = _pad_to(residual, bm, bn) if residual is not None else None
-    out = sfc_matmul_pallas(
-        ap, bp, schedule=schedule, bm=bm, bn=bn, bk=bk,
+    _count_grid(schedule, a.shape[0], b.shape[1], a.shape[1], bm, bn, bk,
+                g)
+    return sfc_matmul_pallas(
+        a, b, schedule=schedule, bm=bm, bn=bn, bk=bk,
         out_dtype=out_dtype, use_prefetch=use_prefetch,
         interpret=bool(interpret), g=g,
-        bias=biasp, activation=activation, residual=resp,
+        bias=bias, activation=activation, residual=residual,
     )
-    return out[:m, :n]
 
 
 def sfc_matmul(
@@ -147,9 +170,9 @@ def sfc_matmul(
     b,
     *,
     schedule: str = "morton",
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
+    bm: int | None = None,
+    bn: int | None = None,
+    bk: int | None = None,
     out_dtype=None,
     use_prefetch: bool = True,
     interpret: bool | None = None,
@@ -163,8 +186,10 @@ def sfc_matmul(
 ):
     """C = act(A @ B + bias) + residual, tiles visited in ``schedule`` order.
 
-    * pads (M, N, K) up to block multiples and crops the result (bias and
-      residual are zero-padded alongside);
+    * ``bm``/``bn``/``bk``: a named block (all three) runs as named; a
+      block left ``None`` is derived from the shape
+      (:func:`repro.kernels.sfc_matmul.sfc_blocks`); neither pads an
+      operand (the kernel crops or masks a block that overhangs);
     * ``bias`` (N,), ``activation`` in {none, relu, gelu, silu} and
       ``residual`` (M, N) form the fused epilogue: applied to the f32
       accumulator in the kernel's flush step, they cost zero extra HBM
@@ -187,9 +212,12 @@ def sfc_matmul(
             objective=objective, has_bias=bias is not None,
             activation=activation, has_residual=residual is not None,
             comm=comm)
+    bm, bn, bk = _resolve_blocks(
+        a.shape[0], b.shape[1], a.shape[1], a.dtype, out_dtype, bm, bn, bk,
+        has_bias=bias is not None, has_residual=residual is not None)
     return _sfc_matmul(
-        a, b, schedule=schedule, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
-        use_prefetch=use_prefetch, interpret=interpret,
+        a, b, schedule=schedule, bm=bm, bn=bn, bk=bk,
+        out_dtype=out_dtype, use_prefetch=use_prefetch, interpret=interpret,
         force_pallas=force_pallas, g=g,
         bias=bias, activation=activation, residual=residual)
 
@@ -220,9 +248,7 @@ def _sfc_matmul_batched(
 ):
     out_dtype = out_dtype or a.dtype
 
-    if schedule == "xla" or (
-            not force_pallas and not default_backend_is_tpu()
-            and not interpret):
+    if not _uses_kernel(schedule, interpret, force_pallas):
         return matmul_batched_fused_ref(
             a, b, bias=bias, activation=activation, residual=residual,
             out_dtype=out_dtype)
@@ -235,28 +261,24 @@ def _sfc_matmul_batched(
     a3 = a.reshape((-1, m, k))
     b3 = b.reshape((-1, k, n))
     res3 = residual.reshape((-1, m, n)) if residual is not None else None
+    _count_grid(schedule, m, n, k, bm, bn, bk, g, batch=a3.shape[0])
 
-    ap = _pad_to(a3, bm, bk)
-    bp = _pad_to(b3, bk, bn)
-    biasp = _pad_last(bias, bn) if bias is not None else None
-    resp = _pad_to(res3, bm, bn) if res3 is not None else None
     if via_vmap:
-        bias2 = biasp
         out = jax.vmap(
             lambda x, y, r: sfc_matmul_pallas(
                 x, y, schedule=schedule, bm=bm, bn=bn, bk=bk,
                 out_dtype=out_dtype, use_prefetch=use_prefetch,
                 interpret=bool(interpret), g=g,
-                bias=bias2, activation=activation, residual=r),
-            in_axes=(0, 0, 0 if resp is not None else None),
-        )(ap, bp, resp)
+                bias=bias, activation=activation, residual=r),
+            in_axes=(0, 0, 0 if res3 is not None else None),
+        )(a3, b3, res3)
     else:
         out = sfc_matmul_batched_pallas(
-            ap, bp, schedule=schedule, bm=bm, bn=bn, bk=bk,
+            a3, b3, schedule=schedule, bm=bm, bn=bn, bk=bk,
             out_dtype=out_dtype, use_prefetch=use_prefetch,
             interpret=bool(interpret), g=g,
-            bias=biasp, activation=activation, residual=resp)
-    return out[:, :m, :n].reshape(lead + (m, n))
+            bias=bias, activation=activation, residual=res3)
+    return out.reshape(lead + (m, n))
 
 
 def sfc_matmul_batched(
@@ -264,9 +286,9 @@ def sfc_matmul_batched(
     b,
     *,
     schedule: str = "morton",
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
+    bm: int | None = None,
+    bn: int | None = None,
+    bk: int | None = None,
     out_dtype=None,
     use_prefetch: bool = True,
     interpret: bool | None = None,
@@ -285,7 +307,9 @@ def sfc_matmul_batched(
     dims; leading dims are flattened into one batch axis for the 3-D-grid
     kernel and restored on return.  ``bias`` (N,) is shared across batch
     elements; ``residual`` matches the (..., M, N) output -- both fused
-    into the kernel flush (DESIGN.md §9).  ``schedule="auto"`` consults
+    into the kernel flush (DESIGN.md §9).  Blocks as in
+    :func:`sfc_matmul`, derived from the per-element GEMM's shape where
+    left ``None``.  ``schedule="auto"`` consults
     the autotuner (keyed on the per-element GEMM shape + epilogue,
     adjudicated under ``objective``).  ``via_vmap=True`` runs the 2-D
     kernel under ``jax.vmap`` instead of the 3-D grid -- the two must
@@ -303,8 +327,11 @@ def sfc_matmul_batched(
             objective=objective, has_bias=bias is not None,
             activation=activation, has_residual=residual is not None,
             comm=comm)
+    bm, bn, bk = _resolve_blocks(
+        a.shape[-2], b.shape[-1], a.shape[-1], a.dtype, out_dtype, bm, bn,
+        bk, has_bias=bias is not None, has_residual=residual is not None)
     return _sfc_matmul_batched(
-        a, b, schedule=schedule, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
-        use_prefetch=use_prefetch, interpret=interpret,
+        a, b, schedule=schedule, bm=bm, bn=bn, bk=bk,
+        out_dtype=out_dtype, use_prefetch=use_prefetch, interpret=interpret,
         force_pallas=force_pallas, via_vmap=via_vmap, g=g,
         bias=bias, activation=activation, residual=residual)

@@ -2,9 +2,17 @@
 
 The paper's technique lifted to the TPU memory hierarchy (DESIGN.md §2):
 the *output-tile grid* is visited in row-major / Morton / Hilbert order.
-Consecutive grid steps that map to the same A- or B-block elide the
-HBM->VMEM DMA (Pallas pipeline revisiting), so traversal order directly
-controls HBM traffic -- the TPU analogue of the paper's cache-hit effect.
+Pallas skips a block's HBM->VMEM copy when the block index of a grid
+step equals the previous step's, so traversal order controls HBM
+traffic -- the TPU analogue of the paper's cache-hit effect.  With k the
+innermost grid dim that happens only where K takes one step (kt = 1):
+then consecutive output tiles in one row of tiles keep their A block,
+and those in one column keep their B block.  With kt > 1 every step
+changes the k index of both blocks and no copy is skipped, whatever the
+order (:func:`grid_step_counts` counts both cases).  At 2,048 rows the
+blocks :func:`sfc_blocks` derives take K whole wherever the working set
+fits, and the Morton walk then skips a copy on some half of the steps
+(PERF.md §5 gives the share by GEMM role).
 
 Two index strategies, mirroring the paper's cost/locality trade-off:
 
@@ -20,13 +28,22 @@ Two index strategies, mirroring the paper's cost/locality trade-off:
   as in the paper (but per tile, not per element).
 
 The kernel accumulates in an f32 VMEM scratch across the innermost k dim
-and writes the output tile once on the last k step.  That flush is also
-the **fused epilogue** (DESIGN.md §9): an optional bias add, activation
-(``gelu``/``silu``/``relu``), and residual add are applied to the f32
-accumulator *before* the single cast-and-write, so a full projection
-layer (dot + bias + act + residual + dtype cast) costs exactly one HBM
-write of C and zero re-reads -- the post-matmul elementwise passes that
-would otherwise each stream the whole output array through HBM are gone.
+and writes the output tile once on the last k step (where K takes one
+step the product goes straight to the flush, with no scratch).  That
+flush is also the **fused epilogue** (DESIGN.md §9): an optional bias
+add, activation (``gelu``/``silu``/``relu``), and residual add are
+applied to the f32 accumulator *before* the single cast-and-write, so a
+full projection layer (dot + bias + act + residual + dtype cast) costs
+exactly one HBM write of C and zero re-reads -- the post-matmul
+elementwise passes that would otherwise each stream the whole output
+array through HBM are gone.
+
+Blocks need not divide the operands.  A last block that overhangs M or N
+reads undefined rows or columns and Pallas drops the part of the output
+block that lies outside C; a last block that overhangs K is zeroed past
+K inside the kernel before its product, so the overhang adds nothing.
+Each call raises the compiler's scoped VMEM limit to its double-buffered
+working set (:func:`sfc_vmem_bytes`) where that exceeds the default.
 """
 from __future__ import annotations
 
@@ -35,15 +52,38 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.curves import hilbert_decode, morton_decode
+from repro.core.energy import TPU_V5E
 from repro.core.schedule import grid_schedule, is_pow2, \
     schedule_extra_kwargs
 from repro.kernels.ref import ACTIVATIONS, apply_activation
 
-__all__ = ["sfc_matmul_pallas", "sfc_matmul_batched_pallas", "decode_step"]
+__all__ = ["sfc_matmul_pallas", "sfc_matmul_batched_pallas", "decode_step",
+           "sfc_blocks", "sfc_vmem_bytes", "grid_step_counts"]
+
+# the compiler's default scoped VMEM limit on a v5e; a kernel whose
+# working set is larger sets ``vmem_limit_bytes`` itself
+SCOPED_VMEM_BYTES = 16 * 2**20
+# what Mosaic keeps in VMEM besides the blocks (internal scratch, the
+# dot's staging), added to the working set when the limit is raised
+VMEM_HEADROOM_BYTES = 4 * 2**20
+# the block rule's cap on the double-buffered working set: a v5e core
+# has 128 MiB of VMEM
+BLOCK_VMEM_BUDGET = 48 * 2**20
+# the block rule, from a sweep of blocks on a v5e at the qwen3-1.7B and
+# GLM-4-9B GEMMs (PERF.md §6): rows of at most 512, so that 2,048 rows
+# leave the curve 4 rows of tiles; K whole up to 4,096, in equal blocks
+# beyond; 1,024 or 2,048 columns, the fewer where a step's operands and
+# output stream in within its compute time with a fifth to spare (the
+# v5e's ridge: its peak FLOP/s over its HBM bandwidth)
+BM_MAX, BK_MAX = 512, 4096
+BN_STEPS = (1024, 2048)
+STEP_INTENSITY = 1.2 * TPU_V5E.peak_flops / TPU_V5E.hbm_bw
+LANES = 128
 
 
 def decode_step(t, schedule: str, mt: int, nt: int):
@@ -68,45 +108,167 @@ def decode_step(t, schedule: str, mt: int, nt: int):
     raise ValueError(f"no closed-form decode for schedule {schedule!r}")
 
 
-def _fused_flush(acc, bias_ref, res_ref, activation: str, out_dtype,
-                 batched: bool):
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def sfc_vmem_bytes(bm: int, bn: int, bk: int, in_bytes: int,
+                   out_bytes: int, *, bias: bool = False,
+                   residual: bool = False, kt: int = 1,
+                   k_tail: int = 0) -> int:
+    """VMEM one grid step of the kernel holds: two buffers of each
+    pipelined block -- A (bm, bk), B (bk, bn), the output (bm, bn) and,
+    where the epilogue has them, the residual (bm, bn, in the output's
+    dtype) and the bias row (counted at a full 8 x 128 f32 tile per 128
+    columns) -- plus the dot's (bm, bn) f32 product, the f32 accumulator
+    where K takes more than one step (``kt``), and the zeroed copies of
+    A's and B's blocks where the last one overhangs K (``k_tail``)."""
+    block = (bm * bk + bk * bn) * in_bytes + bm * bn * out_bytes
+    if residual:
+        block += bm * bn * out_bytes
+    if bias:
+        block += 8 * bn * 4
+    need = 2 * block + bm * bn * 4 * (2 if kt > 1 else 1)
+    if k_tail:
+        need += (bm * bk + bk * bn) * in_bytes
+    return need
+
+
+def _vmem_limit(need: int):
+    """``vmem_limit_bytes`` for a kernel of working set ``need``: None
+    (the compiler's default) where the default holds it."""
+    need += VMEM_HEADROOM_BYTES
+    return None if need <= SCOPED_VMEM_BYTES else need
+
+
+def sfc_blocks(m: int, n: int, k: int, in_bytes: int, out_bytes: int, *,
+               bias: bool = False, residual: bool = False
+               ) -> tuple[int, int, int]:
+    """The (bm, bn, bk) of an M x N x K GEMM when the caller names none.
+
+    * bm: at most ``BM_MAX`` rows, the M blocks balanced so that the last
+      one wastes fewest rows, rounded up to the operand's sublane tile
+      (16 rows in bf16): a decode step of a few slots gets one small
+      block rather than 128 padded rows.
+    * bk: K whole (one k step, so that the curve's consecutive tiles keep
+      a resident block) up to ``BK_MAX``; beyond it K in equal blocks of
+      a multiple of 128, the last one zeroed past K by the kernel.
+    * bn: N whole, rounded up to 128 lanes, where that is narrower than
+      the first of ``BN_STEPS`` whose step does ``STEP_INTENSITY`` FLOP
+      per byte it moves (the larger, where none does); a ragged last
+      block, never a padded weight.
+    * k is split further while the double-buffered working set
+      (:func:`sfc_vmem_bytes`) exceeds ``BLOCK_VMEM_BUDGET``.
+    """
+    sub = 8 * max(1, 4 // in_bytes)
+    m_blocks = max(1, -(-m // BM_MAX))
+    bm = _round_up(max(1, -(-m // m_blocks)), sub)
+    kt = -(-k // BK_MAX)
+    while True:
+        bk = _round_up(-(-k // kt), LANES)
+        for bn in BN_STEPS:
+            bn = min(bn, _round_up(n, LANES))
+            moved = (bm * bk + bk * bn) * in_bytes \
+                + bm * bn * out_bytes * (2 if residual else 1) / kt
+            if 2 * bm * bn * bk >= STEP_INTENSITY * moved:
+                break
+        need = sfc_vmem_bytes(bm, bn, bk, in_bytes, out_bytes, bias=bias,
+                              residual=residual, kt=kt, k_tail=k % bk)
+        if need <= BLOCK_VMEM_BUDGET or bk == LANES:
+            return bm, bn, bk
+        kt += 1
+
+
+def grid_step_counts(schedule: str, mt: int, nt: int, kt: int, g: int = 0,
+                     batch: int = 1) -> tuple[int, int]:
+    """(grid steps, steps that skip a copy) of one kernel call.
+
+    A step skips a copy where its A or its B block index equals the
+    previous step's (Pallas then keeps the resident block).  With k
+    innermost that needs kt = 1; a new batch element changes both."""
+    steps = batch * mt * nt * kt
+    if kt > 1:
+        return steps, 0
+    order = grid_schedule(schedule, mt, nt,
+                          **schedule_extra_kwargs(schedule, g))
+    same = (order[1:] == order[:-1]).any(axis=1)
+    return steps, batch * int(same.sum())
+
+
+def _fused_flush(acc, bias_ref, res_ref, activation: str, out_dtype, ld):
     """The epilogue applied to the f32 accumulator at the last k step:
     out = act(acc + bias) + residual, then a single cast.  Bias blocks
     are (1, bn) VMEM tiles broadcast over the (bm, bn) accumulator."""
     if bias_ref is not None:
-        b = bias_ref[0] if batched else bias_ref[...]
-        acc = acc + b.astype(jnp.float32)
+        acc = acc + ld(bias_ref).astype(jnp.float32)
     acc = apply_activation(acc, activation)
     if res_ref is not None:
-        r = res_ref[0] if batched else res_ref[...]
-        acc = acc + r.astype(jnp.float32)
+        acc = acc + ld(res_ref).astype(jnp.float32)
     return acc.astype(out_dtype)
 
 
-def _mm_kernel(a_ref, b_ref, *rest, kt: int, out_dtype,
-               activation: str = "none", has_bias: bool = False,
-               has_residual: bool = False):
-    # rest: [bias_ref], [residual_ref], o_ref, acc_ref (inputs before
+def _mm_kernel(a_ref, b_ref, *rest, kt: int, k_tail: int, out_dtype,
+               batched: bool, activation: str = "none",
+               has_bias: bool = False, has_residual: bool = False):
+    """One grid step.  ``batched`` kernels see (1, ...) blocks and the k
+    index on grid dim 2; ``k_tail`` is the width of the last k block
+    that lies inside K (0: it lies inside whole)."""
+    # rest: [bias_ref], [residual_ref], o_ref, [acc_ref] (inputs before
     # outputs before scratch -- pallas_call calling convention)
     rest = list(rest)
-    acc_ref = rest.pop()
+    acc_ref = rest.pop() if kt > 1 else None
     o_ref = rest.pop()
     bias_ref = rest[0] if has_bias else None
     res_ref = rest[-1] if has_residual else None
-    k = pl.program_id(1)
+
+    def ld(ref):
+        return ref[0] if batched else ref[...]
+
+    def flush(acc):
+        out = _fused_flush(acc, bias_ref, res_ref, activation, out_dtype, ld)
+        if batched:
+            o_ref[0] = out
+        else:
+            o_ref[...] = out
+
+    def product(masked: bool):
+        a, b = ld(a_ref), ld(b_ref)
+        if masked:
+            # the block overhangs K: what lies past it is undefined
+            a = jnp.where(lax.broadcasted_iota(jnp.int32, a.shape, 1)
+                          < k_tail, a, 0)
+            b = jnp.where(lax.broadcasted_iota(jnp.int32, b.shape, 0)
+                          < k_tail, b, 0)
+        return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+    if kt == 1:
+        flush(product(bool(k_tail)))
+        return
+    k = pl.program_id(2 if batched else 1)
 
     @pl.when(k == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-    )
+    if k_tail:
+        @pl.when(k < kt - 1)
+        def _body():
+            acc_ref[...] += product(False)
+
+        @pl.when(k == kt - 1)
+        def _last():
+            acc_ref[...] += product(True)
+    else:
+        acc_ref[...] += product(False)
 
     @pl.when(k == kt - 1)
     def _flush():
-        o_ref[...] = _fused_flush(acc_ref[...], bias_ref, res_ref,
-                                  activation, out_dtype, batched=False)
+        flush(acc_ref[...])
+
+
+def _mm_kernel_prefetch(sched_ref, *args, **kwargs):
+    # identical body; the schedule ref is consumed by the index_maps only
+    _mm_kernel(*args, **kwargs)
 
 
 def _flat_schedule(schedule: str, mt: int, nt: int, g: int):
@@ -117,11 +279,6 @@ def _flat_schedule(schedule: str, mt: int, nt: int, g: int):
     return jnp.asarray(
         grid_schedule(schedule, mt, nt, **schedule_extra_kwargs(schedule, g)),
         dtype=jnp.int32).reshape(-1)
-
-
-def _mm_kernel_prefetch(sched_ref, *args, **kwargs):
-    # identical body; the schedule ref is consumed by the index_maps only
-    _mm_kernel(*args, **kwargs)
 
 
 def _check_epilogue(bias, residual, activation, n, out_shape):
@@ -137,8 +294,9 @@ def _check_epilogue(bias, residual, activation, n, out_shape):
 def _epilogue_operands(bias, residual, bias_shape, bias_spec, res_spec):
     """The (in_specs, operands) tail for the optional epilogue inputs.
 
-    Shared by all four kernel variants; the (bias, residual) order here
-    must match the kernels' positional ``rest`` parsing."""
+    Shared by both kernels and both index strategies; the (bias,
+    residual) order here must match the kernels' positional ``rest``
+    parsing."""
     specs, ops = [], []
     if bias is not None:
         specs.append(bias_spec)
@@ -149,11 +307,92 @@ def _epilogue_operands(bias, residual, bias_shape, bias_spec, res_spec):
     return specs, ops
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("schedule", "bm", "bn", "bk", "out_dtype",
-                     "use_prefetch", "interpret", "g", "activation"),
-)
+def _sfc_call(a, b, bias, residual, *, batched: bool, schedule: str,
+              bm: int, bn: int, bk: int, out_dtype, use_prefetch: bool,
+              interpret: bool, g: int, activation: str):
+    """The pallas_call of both kernels: grid (T, kt), or (batch, T, kt)
+    with the curve on every batch element's (i, j) tile plane."""
+    m, k = a.shape[-2:]
+    n = b.shape[-1]
+    assert b.shape[-2] == k and a.shape[:-2] == b.shape[:-2], (
+        a.shape, b.shape)
+    lead = a.shape[:-2]
+    _check_epilogue(bias, residual, activation, n, lead + (m, n))
+    mt, nt, kt = -(-m // bm), -(-n // bn), -(-k // bk)
+    out_dtype = out_dtype or a.dtype
+    kern_kw = dict(kt=kt, k_tail=k % bk, out_dtype=out_dtype,
+                   batched=batched, activation=activation,
+                   has_bias=bias is not None,
+                   has_residual=residual is not None)
+    out_shape = jax.ShapeDtypeStruct(lead + (m, n), out_dtype)
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if kt > 1 else []
+    need = sfc_vmem_bytes(bm, bn, bk, a.dtype.itemsize,
+                          jnp.dtype(out_dtype).itemsize,
+                          bias=bias is not None,
+                          residual=residual is not None, kt=kt,
+                          k_tail=k % bk)
+    grid = (lead[0], mt * nt, kt) if batched else (mt * nt, kt)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * len(grid),
+        vmem_limit_bytes=_vmem_limit(need))
+    one = (1,) if batched else ()
+
+    def spec(shape, f):
+        """A block of ``shape`` (per batch element) at the 2-D block
+        index ``f(t, kk, *sched) -> (r, c)``."""
+        if batched:
+            return pl.BlockSpec(one + shape,
+                                lambda bb_, t, kk, *s: (bb_, *f(t, kk, *s)))
+        return pl.BlockSpec(shape, f)
+
+    if use_prefetch:
+        def tile(t, sched_ref):
+            return sched_ref[2 * t], sched_ref[2 * t + 1]
+    else:
+        def tile(t):
+            return decode_step(t, schedule, mt, nt)
+
+    def a_map(t, kk, *s):
+        return tile(t, *s)[0], kk
+
+    def b_map(t, kk, *s):
+        return kk, tile(t, *s)[1]
+
+    def o_map(t, kk, *s):
+        return tile(t, *s)
+
+    def bias_map(t, kk, *s):
+        return 0, tile(t, *s)[1]
+
+    bias_spec = pl.BlockSpec(
+        (1, 1, bn), lambda bb_, t, kk, *s: (0, *bias_map(t, kk, *s))) \
+        if batched else pl.BlockSpec((1, bn), bias_map)
+    ep_specs, ep_ops = _epilogue_operands(
+        bias, residual, one + (1, n), bias_spec, spec((bm, bn), o_map))
+    in_specs = [spec((bm, bk), a_map), spec((bk, bn), b_map), *ep_specs]
+    out_specs = spec((bm, bn), o_map)
+    if not use_prefetch:
+        return pl.pallas_call(
+            functools.partial(_mm_kernel, **kern_kw),
+            grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=params, interpret=interpret,
+        )(a, b, *ep_ops)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch)
+    return pl.pallas_call(
+        functools.partial(_mm_kernel_prefetch, **kern_kw),
+        grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=params, interpret=interpret,
+    )(_flat_schedule(schedule, mt, nt, g), a, b, *ep_ops)
+
+
+_STATIC = ("schedule", "bm", "bn", "bk", "out_dtype", "use_prefetch",
+           "interpret", "g", "activation")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def sfc_matmul_pallas(
     a,
     b,
@@ -172,140 +411,22 @@ def sfc_matmul_pallas(
 ):
     """C = act(A @ B + bias) + residual with SFC-ordered tile traversal.
 
-    Shapes must be multiples of the block sizes (use
-    :func:`repro.kernels.ops.sfc_matmul` for the padding wrapper).
-    ``g`` is the supertile factor (``schedule="supertile"`` only; 0 means
-    the schedule's default).  ``bias`` is (N,), ``residual`` is (M, N);
-    both optional -- the epilogue runs on the f32 accumulator inside the
+    Blocks need not divide the shapes (see the module docstring; the
+    wrapper :func:`repro.kernels.ops.sfc_matmul` derives a block when
+    none is named).  ``g`` is the
+    supertile factor (``schedule="supertile"`` only; 0 means the
+    schedule's default).  ``bias`` is (N,), ``residual`` is (M, N); both
+    optional -- the epilogue runs on the f32 accumulator inside the
     last-k flush, costing zero extra HBM output traffic (DESIGN.md §9).
     """
-    m, k = a.shape
-    k2, n = b.shape
-    assert k == k2, (a.shape, b.shape)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (
-        (m, n, k), (bm, bn, bk))
-    _check_epilogue(bias, residual, activation, n, (m, n))
-    mt, nt, kt = m // bm, n // bn, k // bk
-    out_dtype = out_dtype or a.dtype
-    grid = (mt * nt, kt)
-    kern_kw = dict(kt=kt, out_dtype=out_dtype, activation=activation,
-                   has_bias=bias is not None,
-                   has_residual=residual is not None)
-    out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-    semantics = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"),
-    )
-
-    if not use_prefetch:
-        def a_map(t, kk):
-            i, _ = decode_step(t, schedule, mt, nt)
-            return i, kk
-
-        def b_map(t, kk):
-            _, j = decode_step(t, schedule, mt, nt)
-            return kk, j
-
-        def o_map(t, kk):
-            return decode_step(t, schedule, mt, nt)
-
-        def bias_map(t, kk):
-            _, j = decode_step(t, schedule, mt, nt)
-            return 0, j
-
-        ep_specs, ep_ops = _epilogue_operands(
-            bias, residual, (1, n),
-            pl.BlockSpec((1, bn), bias_map), pl.BlockSpec((bm, bn), o_map))
-        return pl.pallas_call(
-            functools.partial(_mm_kernel, **kern_kw),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bm, bk), a_map),
-                pl.BlockSpec((bk, bn), b_map),
-                *ep_specs,
-            ],
-            out_specs=pl.BlockSpec((bm, bn), o_map),
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            compiler_params=semantics,
-            interpret=interpret,
-        )(a, b, *ep_ops)
-
-    # --- scalar-prefetch variant: host-precomputed schedule table ---------
-    sched = _flat_schedule(schedule, mt, nt, g)
-
-    def a_map(t, kk, sched_ref):
-        return sched_ref[2 * t], kk
-
-    def b_map(t, kk, sched_ref):
-        return kk, sched_ref[2 * t + 1]
-
-    def o_map(t, kk, sched_ref):
-        return sched_ref[2 * t], sched_ref[2 * t + 1]
-
-    def bias_map(t, kk, sched_ref):
-        return 0, sched_ref[2 * t + 1]
-
-    ep_specs, ep_ops = _epilogue_operands(
-        bias, residual, (1, n),
-        pl.BlockSpec((1, bn), bias_map), pl.BlockSpec((bm, bn), o_map))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), a_map),
-            pl.BlockSpec((bk, bn), b_map),
-            *ep_specs,
-        ],
-        out_specs=pl.BlockSpec((bm, bn), o_map),
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        functools.partial(_mm_kernel_prefetch, **kern_kw),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=semantics,
-        interpret=interpret,
-    )(sched, a, b, *ep_ops)
+    assert a.ndim == 2 and b.ndim == 2, (a.shape, b.shape)
+    return _sfc_call(a, b, bias, residual, batched=False, schedule=schedule,
+                     bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
+                     use_prefetch=use_prefetch, interpret=interpret, g=g,
+                     activation=activation)
 
 
-# ---------------------------------------------------------------------------
-# Batched variant: 3-D grid (batch, sfc tile step, k)
-# ---------------------------------------------------------------------------
-
-def _bmm_kernel(a_ref, b_ref, *rest, kt: int, out_dtype,
-                activation: str = "none", has_bias: bool = False,
-                has_residual: bool = False):
-    rest = list(rest)
-    acc_ref = rest.pop()
-    o_ref = rest.pop()
-    bias_ref = rest[0] if has_bias else None
-    res_ref = rest[-1] if has_residual else None
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(
-        a_ref[0], b_ref[0], preferred_element_type=jnp.float32
-    )
-
-    @pl.when(k == kt - 1)
-    def _flush():
-        o_ref[0] = _fused_flush(acc_ref[...], bias_ref, res_ref,
-                                activation, out_dtype, batched=True)
-
-
-def _bmm_kernel_prefetch(sched_ref, *args, **kwargs):
-    _bmm_kernel(*args, **kwargs)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("schedule", "bm", "bn", "bk", "out_dtype",
-                     "use_prefetch", "interpret", "g", "activation"),
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def sfc_matmul_batched_pallas(
     a,
     b,
@@ -327,100 +448,15 @@ def sfc_matmul_batched_pallas(
     Grid is (batch, T, kt) with the curve applied to the (i, j) output
     tile plane -- the batch dim is outermost, so each batch element
     replays the full SFC sweep and inherits its locality (consecutive
-    tile steps within one batch element elide A/B block DMAs exactly as
+    tile steps within one batch element keep a resident block exactly as
     in the 2-D kernel; the k-accumulator carries across the innermost
     dim only).  ``bias`` is (N,), shared across batch elements;
-    ``residual`` matches the (batch, M, N) output.  Shapes must be
-    multiples of the block sizes (see
-    :func:`repro.kernels.ops.sfc_matmul_batched` for padding + batching
-    of arbitrary leading dims).
+    ``residual`` matches the (batch, M, N) output.  See
+    :func:`repro.kernels.ops.sfc_matmul_batched` for batching of
+    arbitrary leading dims.
     """
-    bsz, m, k = a.shape
-    bsz2, k2, n = b.shape
-    assert bsz == bsz2 and k == k2, (a.shape, b.shape)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (
-        (m, n, k), (bm, bn, bk))
-    _check_epilogue(bias, residual, activation, n, (bsz, m, n))
-    mt, nt, kt = m // bm, n // bn, k // bk
-    out_dtype = out_dtype or a.dtype
-    grid = (bsz, mt * nt, kt)
-    kern_kw = dict(kt=kt, out_dtype=out_dtype, activation=activation,
-                   has_bias=bias is not None,
-                   has_residual=residual is not None)
-    out_shape = jax.ShapeDtypeStruct((bsz, m, n), out_dtype)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
-    semantics = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-    )
-
-    if not use_prefetch:
-        def a_map(bb_, t, kk):
-            i, _ = decode_step(t, schedule, mt, nt)
-            return bb_, i, kk
-
-        def b_map(bb_, t, kk):
-            _, j = decode_step(t, schedule, mt, nt)
-            return bb_, kk, j
-
-        def o_map(bb_, t, kk):
-            i, j = decode_step(t, schedule, mt, nt)
-            return bb_, i, j
-
-        def bias_map(bb_, t, kk):
-            _, j = decode_step(t, schedule, mt, nt)
-            return 0, 0, j
-
-        ep_specs, ep_ops = _epilogue_operands(
-            bias, residual, (1, 1, n),
-            pl.BlockSpec((1, 1, bn), bias_map),
-            pl.BlockSpec((1, bm, bn), o_map))
-        return pl.pallas_call(
-            functools.partial(_bmm_kernel, **kern_kw),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bm, bk), a_map),
-                pl.BlockSpec((1, bk, bn), b_map),
-                *ep_specs,
-            ],
-            out_specs=pl.BlockSpec((1, bm, bn), o_map),
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            compiler_params=semantics,
-            interpret=interpret,
-        )(a, b, *ep_ops)
-
-    sched = _flat_schedule(schedule, mt, nt, g)
-
-    def a_map(bb_, t, kk, sched_ref):
-        return bb_, sched_ref[2 * t], kk
-
-    def b_map(bb_, t, kk, sched_ref):
-        return bb_, kk, sched_ref[2 * t + 1]
-
-    def o_map(bb_, t, kk, sched_ref):
-        return bb_, sched_ref[2 * t], sched_ref[2 * t + 1]
-
-    def bias_map(bb_, t, kk, sched_ref):
-        return 0, 0, sched_ref[2 * t + 1]
-
-    ep_specs, ep_ops = _epilogue_operands(
-        bias, residual, (1, 1, n),
-        pl.BlockSpec((1, 1, bn), bias_map), pl.BlockSpec((1, bm, bn), o_map))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), a_map),
-            pl.BlockSpec((1, bk, bn), b_map),
-            *ep_specs,
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), o_map),
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        functools.partial(_bmm_kernel_prefetch, **kern_kw),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=semantics,
-        interpret=interpret,
-    )(sched, a, b, *ep_ops)
+    assert a.ndim == 3 and b.ndim == 3, (a.shape, b.shape)
+    return _sfc_call(a, b, bias, residual, batched=True, schedule=schedule,
+                     bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
+                     use_prefetch=use_prefetch, interpret=interpret, g=g,
+                     activation=activation)

@@ -48,9 +48,14 @@ class DotEngine:
     under schedule="auto": winners are then scored with the hop-weighted
     bytes-over-links term and cached under the mesh keyspace.  None
     (default) keeps every single-chip cache key byte-identical.
+
+    block: the kernel's (bm, bn, bk) for every GEMM, or None (default):
+    each GEMM's block is then derived from its shape, dtypes and
+    epilogue (:func:`repro.kernels.sfc_matmul.sfc_blocks`).  Ignored
+    under "xla" and "auto" (the tuner picks blocks).
     """
     schedule: str = "xla"
-    block: tuple = (128, 128, 128)
+    block: tuple | None = None
     use_prefetch: bool = True
     interpret: bool = False
     objective: str = "time"
@@ -85,7 +90,7 @@ class DotEngine:
         x2 = x.reshape(-1, x.shape[-1])
         res2 = residual.reshape(-1, w.shape[-1]) \
             if residual is not None else None
-        bm, bn, bk = self.block
+        bm, bn, bk = self.block or (None, None, None)
         out = sfc_matmul(
             x2, w, schedule=self.schedule, bm=bm, bn=bn, bk=bk,
             use_prefetch=self.use_prefetch, interpret=self.interpret,
@@ -111,7 +116,7 @@ class DotEngine:
                 out_dtype=out_dtype or jnp.result_type(x, w))
         from repro.kernels.ops import sfc_matmul_batched
 
-        bm, bn, bk = self.block
+        bm, bn, bk = self.block or (None, None, None)
         return sfc_matmul_batched(
             x, w, schedule=self.schedule, bm=bm, bn=bn, bk=bk,
             use_prefetch=self.use_prefetch, interpret=self.interpret,

@@ -43,8 +43,9 @@ def test_contract_epilogue_tightens_vmem():
     base = gemm_vmem_bytes(TuneConfig(bm=256, bn=256, bk=256))
     ep = EpilogueSpec(bias=True, activation="gelu", residual=True)
     full = gemm_vmem_bytes(TuneConfig(bm=256, bn=256, bk=256), 4, ep)
-    # bias (1, bn) tile + residual (bm, bn) tile
-    assert full == base + 256 * 4 + 256 * 256 * 4
+    # bias row (an 8 x 128 f32 tile per 128 columns) + residual (bm, bn)
+    # block, each double-buffered as the kernel's pipeline holds them
+    assert full == base + 2 * (8 * 256 * 4 + 256 * 256 * 4)
 
 
 def test_contract_rejects_prefetchless_nonsquare():
@@ -258,6 +259,9 @@ def test_report_serialises_and_raises():
 def test_vmem_budget_tracks_hw():
     cfg = TuneConfig(bm=256, bn=256, bk=256)
     need = gemm_vmem_bytes(cfg)
-    assert need == (3 * 256 * 256) * 4 + 256 * 256 * 4
+    # A, B, C double-buffered; the dot's f32 product and accumulator
+    assert need == 2 * (3 * 256 * 256) * 4 + 2 * 256 * 256 * 4
+    # K in one block holds no accumulator
+    assert gemm_vmem_bytes(cfg, k=256) == need - 256 * 256 * 4
     rep = check_gemm_contract(cfg, 1024, 1024, 1024, level="fast")
     assert rep.stats["vmem_budget"] == int(TPU_V5E.vmem_per_chip * 0.9)

@@ -9,6 +9,10 @@ show on the chip.  These tests compile for one chip of a *described*
   shape of the model, under every config the tuner proposes there
   (schedules x the blocks of ``tune/autotune._BLOCK_CANDIDATES``), and
   under each epilogue;
+* the blocks ``sfc_blocks`` derives, through the ``sfc_matmul`` wrapper,
+  at every GEMM of the benchmark cells' configurations (qwen3-1.7B and
+  GLM-4-9B, a 2,048-row window and a 16-slot decode step) under the
+  epilogue the forward gives it;
 * ``paged_decode_attention_pallas`` with pages of 8 and 16.
 
 The topology is described inside a fixture, never at import, so that
@@ -21,7 +25,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _gemms import cell_gemm_cases, forward_gemms
 from repro.configs import get_config
+from repro.kernels.ops import sfc_matmul
 from repro.kernels.paged_attention import paged_decode_attention_pallas
 from repro.kernels.sfc_matmul import sfc_matmul_pallas
 from repro.serve.paged_kv import default_pool_pages, default_slot_pages
@@ -136,6 +142,28 @@ def test_sfc_matmul_epilogues_compile(one_chip, epilogue):
             continue
         hlo = _compile_gemm(one_chip, c, 2048, n, k, **EPILOGUES[epilogue])
         assert CUSTOM_CALL in hlo, c
+
+
+@pytest.mark.parametrize("arch,role,rows", cell_gemm_cases())
+def test_sfc_matmul_derived_blocks_compile(one_chip, arch, role, rows):
+    """The wrapper's shape-derived blocks, operands unpadded, with the
+    kernel's op still named ``sfc_matmul_pallas`` (the trace readers
+    find it by that name)."""
+    n, k, ep = forward_gemms(arch)[role]
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def call(a, b, res):
+        return sfc_matmul(a, b, force_pallas=True, residual=res,
+                          activation=ep.get("activation", "none"),
+                          out_dtype=ep.get("out_dtype"))
+
+    res = spec(rows, n) if ep.get("residual") else None
+    hlo = jax.jit(call).lower(spec(rows, k), spec(k, n), res) \
+        .compile().as_text()
+    assert CUSTOM_CALL in hlo
+    assert "%sfc_matmul_pallas" in hlo
 
 
 @pytest.mark.parametrize("page_size", [8, 16])
