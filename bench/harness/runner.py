@@ -12,9 +12,9 @@ import sys
 import time
 from pathlib import Path
 
-from harness import model as model_mod
 from harness import profile
 from harness.cell import BENCH, ROOT, Cell, loop_module, metric_reader
+from harness.model import check_layout
 from harness.record import Record
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -33,7 +33,7 @@ class Reading:
     trace: profile.Trace | None
     lo: float | None          # the window in the trace, ns
     hi: float | None
-    shapes: model_mod.Shapes
+    shapes: object            # the family's shapes (harness/cell.py)
     peaks: dict
     cell: Cell
     setup_s: float
@@ -105,14 +105,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     marks = [("start", t_start),
              ("imports and device init", time.perf_counter())]
-    cfg = model_mod.program_config(cell.model)
-    s = model_mod.shapes(cell.model)
-    params = model_mod.make_params(s, seed, cell.model)
-    model_mod.check_layout(params, cfg)
+    family = cell.family()
+    cfg = family.program_config(cell.model)
+    s = family.shapes(cell.model)
+    params = family.make_params(s, seed, cell.model)
+    check_layout(params, cfg)
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
     loop = loop_module(cell.mix["loop"])
-    job = loop.Job(cell, cfg, params, s, seed)
+    job = loop.Job(cell, cfg, params, s, seed, family.reference)
     if hooks is not None:
         hooks(job)
     marks.append(("traffic and program", time.perf_counter()))

@@ -13,10 +13,13 @@ names each device op by its instruction, so a trace's scope map is
 themselves carry no op_name on a v5e: their only stats are offsets and
 durations.
 
-A GEMM's role is the innermost of ``ROLES`` in its op's path: the SFC
-kernel's op is named after the jitted function that calls
-``pallas_call`` (``sfc_matmul_pallas.N``, one per GEMM of the program),
-and the path says which GEMM it is.
+A GEMM's role is the longest of the roles its cell's family declares
+(``Gemm.role`` of the family's ``gemms()``, a scope path such as
+``attn/q`` or ``mlp/down``) that lies on its op's path: the SFC kernel's
+op is named after the jitted function that calls ``pallas_call``
+(``sfc_matmul_pallas.N``, one per GEMM of the program), and the path
+says which GEMM it is.  So ``moe/experts/gate`` and ``mlp/gate`` stay
+apart.
 """
 from __future__ import annotations
 
@@ -26,8 +29,11 @@ import re
 
 from harness import profile
 from harness.cell import ROOT
+from harness.model import gemm_min_seconds
 
-ROLES = ("q", "k", "v", "o", "gate", "up", "down", "head")
+# the program's scopes of work other than a GEMM (repro.models), which
+# the by-part breakdown names beside the GEMM roles
+PARTS = ("embed", "layers", "attn", "attn/core", "mlp", "final_norm")
 # where bench/run.py's traced run keeps its profile while the readers run
 # (harness/runner.py: <checkout>/bench_out/trace-<cell>-<seed>)
 PROFILES = ROOT / "bench_out"
@@ -39,16 +45,29 @@ META_PLANE, HLO_STAT = b"/host:metadata", b"Hlo Proto"
 _LOADED: dict = {}
 
 
-def role(path: str) -> str | None:
-    """The innermost of ``ROLES`` among the path's parts, if any."""
-    hit = [p for p in path.split("/") if p in ROLES]
-    return hit[-1] if hit else None
-
-
 def under(path: str, scope: str) -> bool:
     """Whether ``scope`` (one part or several, ``a/b``) lies on the
     path as whole parts."""
     return f"/{scope}/" in f"/{path}/"
+
+
+def longest(path: str, scopes) -> str | None:
+    """The scope of most parts among ``scopes`` that lies on the path
+    (of two as long, the innermost), if any."""
+    hit = [(s.count("/"), f"/{path}/".rfind(f"/{s}/"), s) for s in scopes
+           if under(path, s)]
+    return max(hit)[2] if hit else None
+
+
+def roles_of(s) -> tuple:
+    """The GEMM roles a family's shapes declare."""
+    return tuple(dict.fromkeys(g.role for g in s.gemms()))
+
+
+def role(path: str, declared) -> str | None:
+    """The op's GEMM role: the longest of the ``declared`` roles on its
+    path."""
+    return longest(path, declared)
 
 
 def _fields(buf: bytes) -> list:
@@ -190,13 +209,13 @@ def _seconds(trace, lo: float, hi: float, keep) -> float:
     return tot / len(planes) / 1e9
 
 
-def role_seconds(trace, scopes: dict, kernel: str, roles, lo: float,
-                 hi: float) -> float:
+def role_seconds(trace, scopes: dict, kernel: str, roles, declared,
+                 lo: float, hi: float) -> float:
     """Device seconds of the ``kernel`` ops (a regular expression on the
-    op name) whose role is one of ``roles``."""
+    op name) whose role among the ``declared`` is one of ``roles``."""
     rx = re.compile(kernel)
     return _seconds(trace, lo, hi, lambda n: bool(rx.search(n)) and
-                    role(scopes.get(n, "")) in roles)
+                    role(scopes.get(n, ""), declared) in roles)
 
 
 def scope_seconds(trace, scopes: dict, scope: str, lo: float,
@@ -208,60 +227,30 @@ def scope_seconds(trace, scopes: dict, scope: str, lo: float,
                     and profile.op_kind(n) not in profile.CONTROL_FLOW)
 
 
-def part_seconds(trace, scopes: dict, lo: float, hi: float) -> dict:
+def part_seconds(trace, scopes: dict, declared, lo: float,
+                 hi: float) -> dict:
     """{part: device seconds}: each op (control flow left out) counted
-    once, under the innermost of embed, layers, attn/<role or core>,
-    mlp/<role>, final_norm and head on its path, or "outside" the
-    program's scopes."""
+    once, under its part (``part``), or "outside" the program's
+    scopes."""
     acc: dict = {}
     planes = [ops for ops in trace.device_ops.values() if ops]
     for ops in planes:
         for s, e, n in ops:
             if e > lo and s < hi and \
                     profile.op_kind(n) not in profile.CONTROL_FLOW:
-                k = part(scopes.get(n, ""))
+                k = part(scopes.get(n, ""), declared)
                 acc[k] = acc.get(k, 0.0) + min(e, hi) - max(s, lo)
     return {k: v / len(planes) / 1e9 for k, v in acc.items()}
 
 
-def part(path: str) -> str:
-    """The part of the program an op's scope path puts it in."""
-    parts = path.split("/")
-    for sub in ("attn", "mlp"):
-        if sub in parts:
-            inner = [p for p in parts[parts.index(sub) + 1:]
-                     if p in ROLES or p == "core"]
-            return f"{sub}/{inner[-1]}" if inner else sub
-    for top in ("head", "final_norm", "layers", "embed"):
-        if top in parts:
-            return top
-    return "outside"
+def part(path: str, declared) -> str:
+    """The part of the program an op's scope path puts it in: the
+    longest of the ``declared`` GEMM roles and ``PARTS`` on it, or
+    "outside"."""
+    return longest(path, (*declared, *PARTS)) or "outside"
 
 
 # -------------------------------------------------------------- work ------
-def role_min_seconds(s, roles, rows: int, head_rows: int,
-                     peak_flops: float, bw: float,
-                     dtype_bytes: int = 2) -> float:
-    """The least time of one step's matmuls of the given roles, by the
-    rule of ``Shapes.gemm_min_seconds`` (per matmul the larger of its
-    FLOPs over the peak and its bytes over the bandwidth): the layer
-    GEMMs over ``rows`` tokens, the head over ``head_rows``.  Over all of
-    ``ROLES`` the roles sum to ``gemm_min_seconds``."""
-    total = 0.0
-    if rows > 0:
-        for r, (k, n) in zip(ROLES, s.layer_gemms()):
-            if r in roles:
-                flops = 2.0 * rows * k * n
-                byts = dtype_bytes * (k * n + rows * k + rows * n)
-                total += s.layers * max(flops / peak_flops, byts / bw)
-    if head_rows > 0 and "head" in roles:
-        k, n = s.d, s.vocab
-        flops = 2.0 * head_rows * k * n
-        byts = dtype_bytes * (k * n + head_rows * k) + 4 * head_rows * n
-        total += max(flops / peak_flops, byts / bw)
-    return total
-
-
 def roofline(r, roles, kernel: str) -> float | None:
     """The ``kernel``'s share of its roofline over the GEMMs of
     ``roles``: their least time for the window's tokens over the device
@@ -270,7 +259,9 @@ def roofline(r, roles, kernel: str) -> float | None:
     if r.trace is None or profile.kernel_seconds(r.trace, kernel, r.lo,
                                                  r.hi) <= 0:
         return None
-    t_kernel = role_seconds(r.trace, of(r), kernel, roles, r.lo, r.hi)
+    gemms = r.shapes.gemms()
+    t_kernel = role_seconds(r.trace, of(r), kernel, roles,
+                            roles_of(r.shapes), r.lo, r.hi)
     if t_kernel <= 0:
         return None
     flops, bw = r.peaks["bf16_flops_per_s"], r.peaks["hbm_bytes_per_s"]
@@ -278,6 +269,6 @@ def roofline(r, roles, kernel: str) -> float | None:
     for st in r.rec.window():
         rows = sum(n for n, _, _ in st.segments)
         head_rows = sum(n for n, _, head in st.segments if head)
-        t_min += role_min_seconds(r.shapes, roles, rows, head_rows, flops,
-                                  bw)
+        t_min += gemm_min_seconds(gemms, rows, head_rows, flops, bw,
+                                  roles=roles)
     return 100.0 * t_min / t_kernel

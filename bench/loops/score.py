@@ -34,7 +34,6 @@ from collections import deque
 import jax
 import numpy as np
 
-import reference
 from harness.record import Record, Step
 
 FIRST_TOKEN_ID = 2   # ids 0 and 1 are pad and eos by the program's default
@@ -71,10 +70,13 @@ def scorer(cfg, engine):
 
 
 class Job:
-    def __init__(self, cell, cfg, params, s, seed):
+    def __init__(self, cell, cfg, params, s, seed, reference):
+        """``reference(params, tokens, s, mode)``: the family's plain
+        reference (``bench/families/<family>.py``)."""
         from repro.models import DotEngine
 
         self.mix, self.s, self.params = cell.mix, s, params
+        self.reference = reference
         self.seq = int(cell.mix["seq"])
         self.corpus = corpus(cell.mix, seed, s.vocab)
         self.fn = scorer(cfg, DotEngine(**cell.model["engine"]))
@@ -149,12 +151,12 @@ class Job:
         for k in sorted(pick):
             i, r = cells[k]
             toks = jnp.asarray(self.corpus[i, r])
-            want = np.asarray(reference.next_token_logprobs(
-                self.params, toks, s=self.s))
+            want = np.asarray(self.reference(self.params, toks, self.s,
+                                             "f32"))
             worst = max(worst, widest(self.outs[i][r], want))
             if control:
-                got8 = np.asarray(reference.next_token_logprobs(
-                    self.params, toks, s=self.s, mode="fp8"))
+                got8 = np.asarray(self.reference(self.params, toks, self.s,
+                                                 "fp8"))
                 worst_ctl = max(worst_ctl, widest(got8, want))
             n_tok += want.size
         out = {"rows": len(pick), "tokens": n_tok, GAP: worst}

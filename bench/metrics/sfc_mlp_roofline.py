@@ -10,7 +10,7 @@ from harness import scopes
 # the Pallas GEMM of kernels/sfc_matmul.py (op sfc_matmul_pallas.N), and
 # the program's scopes of the GEMMs read here (repro.models)
 KERNEL = r"^sfc_matmul_pallas\b"
-ROLES = ("gate", "up", "down")
+ROLES = ("mlp/gate", "mlp/up", "mlp/down")
 
 
 def read(r):

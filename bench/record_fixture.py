@@ -26,7 +26,7 @@ ROOT = Path(__file__).resolve().parents[1]
 def trim(kept: dict, workload: str, steps: int) -> dict:
     """The first ``steps`` steps of a kept trace and record, with the
     per-layer metrics read from them."""
-    from harness import cell, model, profile, runner
+    from harness import cell, profile, runner
     from harness.record import Record, Step
 
     c = cell.load(workload)
@@ -46,7 +46,7 @@ def trim(kept: dict, workload: str, steps: int) -> dict:
         [(n, s, min(e, hi)) for n, s, e in tr.host
          if s < hi and e > lo and n != "bench.window"]
         + [("bench.window", lo, hi)])
-    reading = runner.Reading(rec, small, lo, hi, model.shapes(c.model),
+    reading = runner.Reading(rec, small, lo, hi, c.family().shapes(c.model),
                              runner.peaks_for("TPU v5 lite"), c, 0.0)
     metrics = {}
     for m in c.per_layer:

@@ -28,29 +28,32 @@ SCOPED = ("sfc_attn_proj_roofline", "sfc_mlp_roofline", "sfc_head_roofline",
           "attention_core_pct")
 
 
-def summary(tr, scopes_map: dict, lo: float, hi: float) -> dict:
+def summary(tr, scopes_map: dict, roles, lo: float, hi: float) -> dict:
     """What the acceptance of the scope readers asks of a traced window:
-    device seconds by part, the SFC events without exactly one role, and
-    the roles' kernel seconds against the kernel's."""
+    device seconds by part, the SFC events without exactly one of the
+    family's GEMM ``roles``, and the roles' kernel seconds against the
+    kernel's."""
     from harness import profile, scopes
 
     kernel = r"^sfc_matmul_pallas\b"
-    parts = scopes.part_seconds(tr, scopes_map, lo, hi)
+    parts = scopes.part_seconds(tr, scopes_map, roles, lo, hi)
     busy = profile.busy_seconds(tr, lo, hi)
     sfc = {n for ops in tr.device_ops.values() for _, _, n in ops
            if n.startswith("sfc_matmul_pallas")}
-    by_role = {r: scopes.role_seconds(tr, scopes_map, kernel, (r,), lo, hi)
-               for r in scopes.ROLES}
+    by_role = {r: scopes.role_seconds(tr, scopes_map, kernel, (r,), roles,
+                                      lo, hi)
+               for r in roles}
     return {"busy_s": busy, "parts_s": parts,
             "outside_pct": 100.0 * parts.get("outside", 0.0) / busy,
             "sfc_ops_without_role": sorted(
-                n for n in sfc if scopes.role(scopes_map.get(n, "")) is None),
+                n for n in sfc
+                if scopes.role(scopes_map.get(n, ""), roles) is None),
             "sfc_role_paths": {n: scopes_map.get(n, "") for n in sorted(sfc)},
             "kernel_s_by_role": by_role,
             "kernel_s": profile.kernel_seconds(tr, kernel, lo, hi),
             "outside_ops": profile.top_ops(
                 profile.Trace({p: [e for e in ops if scopes.part(
-                    scopes_map.get(e[2], "")) == "outside"]
+                    scopes_map.get(e[2], ""), roles) == "outside"]
                     for p, ops in tr.device_ops.items()}, []), lo, hi)}
 
 
@@ -68,9 +71,11 @@ def main(argv=None) -> int:
     import record_fixture
     from harness import cell, profile, runner, scopes
 
+    c = cell.load(args.workload)
+    roles = scopes.roles_of(c.family().shapes(c.model))
     with tempfile.TemporaryDirectory() as tmp:
         kept = Path(tmp) / "kept.json"
-        result = runner.run(cell.load(args.workload), args.seed,
+        result = runner.run(c, args.seed,
                             args.seconds, True, t_start=time.perf_counter(),
                             keep_trace=kept)
         whole = json.loads(kept.read_text())
@@ -80,7 +85,7 @@ def main(argv=None) -> int:
     if not scopes_map:
         raise SystemExit("the run's readers found no scope map")
     print(json.dumps({"metrics": result["metrics"], "diag": result["diag"],
-                      "window": summary(tr, scopes_map, lo, hi)}))
+                      "window": summary(tr, scopes_map, roles, lo, hi)}))
 
     fx = record_fixture.trim(whole, args.workload, args.steps)
     ops = {e[2] for evs in fx["trace"]["device_ops"].values() for e in evs}

@@ -1,5 +1,6 @@
 """CPU tests of the benchmark (``pytest bench/tests``): the harness's
 pieces, and whole runs at a smoke size with the chip check skipped."""
+import functools
 import os
 import sys
 from pathlib import Path
@@ -12,6 +13,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 SMOKE_MODEL = {
     "source": "repro.configs.qwen3_1_7b SMOKE",
+    "family": "dense",
     "program": {"arch": "qwen3_1_7b", "preset": "smoke"},
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -19,6 +21,16 @@ SMOKE_MODEL = {
     "tie_word_embeddings": True, "torch_dtype": "float32", "qk_norm": True,
     "engine": {"schedule": "morton"},
 }
+
+
+@functools.cache
+def dense():
+    """The dense family (``bench/families/dense.py``), loaded as the
+    harness loads it."""
+    from harness.cell import family_module
+
+    return family_module("dense")
+
 
 SMOKE_MIX = {"loop": "score", "rows": 2, "seq": 256, "windows": 4096,
              "ahead_s": 0.2}

@@ -8,7 +8,7 @@ import re
 import pytest
 
 from harness import cell as cell_mod
-from harness import model, runner
+from harness import runner
 from harness.cell import ROOT
 
 B = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -45,8 +45,12 @@ def test_each_cell_resolves(w):
     # not correct (test_runs.py)
     assert gap in c.check and c.check["sample"] > 0
     assert c.check[gap] is None or c.check[gap] > 0
-    model.shapes(c.model)
-    model.program_config(c.model)
+    family = c.family()
+    family.program_config(c.model)
+    s = family.shapes(c.model)
+    assert s.vocab > 0 and s.token_flops(1, True) > 0
+    assert all(g.role and g.count > 0 and 0 < g.row_share
+               for g in s.gemms())
 
 
 @pytest.mark.parametrize("entry", B["configs"], ids=lambda e: e["name"])
@@ -77,4 +81,13 @@ def test_an_unstated_departure_is_refused(entry):
         g = dict(f, departures={k: v for k, v in f["departures"].items()
                                 if k != key})
         with pytest.raises(SystemExit, match="departure"):
-            model.program_config(g)
+            cell_mod.family_module(f["family"]).program_config(g)
+
+
+@pytest.mark.parametrize("family", [None, "", "no_such_family"])
+def test_a_config_without_a_family_file_is_refused(family):
+    """The family is found by the name the configuration file gives; a
+    missing name, or one with no file, stops the run and says which."""
+    with pytest.raises(SystemExit, match="no_such_family.py" if family
+                       else "names no 'family'"):
+        cell_mod.family_module(family)

@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from conftest import SMOKE_MODEL
+from conftest import SMOKE_MODEL, dense
 from harness import model, profile
 from harness.cell import BENCH, Cell, metric_reader
 from harness.record import Record, Step
@@ -54,12 +54,12 @@ def test_idle_gaps_are_named_by_the_host():
 
 def _reading(trace, rec):
     cell = Cell("x", 1, SMOKE_MODEL, {}, {}, [], [])
-    return Reading(rec, trace, 0.0, 1e9, model.shapes(SMOKE_MODEL), PEAKS,
+    return Reading(rec, trace, 0.0, 1e9, dense().shapes(SMOKE_MODEL), PEAKS,
                    cell, 1.0)
 
 
 def test_shares_from_model_shapes_and_real_tokens():
-    s = model.shapes(SMOKE_MODEL)
+    s = dense().shapes(SMOKE_MODEL)
     rec = Record(0.0, 1.0, [Step(0.0, 0.5, [(256, 0, True)] * 2),
                             Step(0.5, 1.0, [(16, 40, False)])])
     flops = 2 * sum(s.token_flops(p + 1, True) for p in range(256)) \
@@ -77,13 +77,36 @@ def test_shares_from_model_shapes_and_real_tokens():
 def test_gemm_bound_counts_weights_once_a_step():
     """The least GEMM time of one step over many rows is the larger of
     its FLOPs and its bytes; the head is counted for head rows only."""
-    s = model.shapes(SMOKE_MODEL)
+    gemms = dense().shapes(SMOKE_MODEL).gemms()
     f, bw = PEAKS["bf16_flops_per_s"], PEAKS["hbm_bytes_per_s"]
-    w = s.layers * sum(k * n for k, n in s.layer_gemms())
-    assert s.gemm_min_seconds(1, 0, f, bw) >= 2 * w / bw
-    assert s.gemm_min_seconds(512, 512, f, bw) > \
-        s.gemm_min_seconds(512, 0, f, bw)
-    assert s.gemm_min_seconds(0, 0, f, bw) == 0.0
+    w = sum(g.count * g.K * g.N for g in gemms if not g.head)
+    assert model.gemm_min_seconds(gemms, 1, 0, f, bw) >= 2 * w / bw
+    assert model.gemm_min_seconds(gemms, 512, 512, f, bw) > \
+        model.gemm_min_seconds(gemms, 512, 0, f, bw)
+    assert model.gemm_min_seconds(gemms, 0, 0, f, bw) == 0.0
+
+
+@pytest.mark.parametrize("rows", [1, 64, 4096])
+def test_a_row_share_scales_rows_not_weights(rows):
+    """A GEMM that runs over a share of the step's rows (a routed
+    expert's) does that share of the FLOPs and moves that share of the
+    rows' bytes, but reads all of its weights."""
+    k, n, share = 2048, 1408, 6 / 64
+    full = model.Gemm("moe/experts/gate", k, n, 4)
+    part = model.Gemm("moe/experts/gate", k, n, 4, row_share=share)
+    f, bw = PEAKS["bf16_flops_per_s"], PEAKS["hbm_bytes_per_s"]
+
+    def least(g, f, bw):
+        # one of the two bounds at a time: the other made free
+        return model.gemm_min_seconds([g], rows, 0, f, bw)
+    inf = float("inf")
+    assert least(part, f, inf) == pytest.approx(
+        share * least(full, f, inf), rel=1e-12)
+    weights = 4 * 2 * k * n / bw
+    assert least(part, inf, bw) - weights == pytest.approx(
+        share * (least(full, inf, bw) - weights), rel=1e-12)
+    assert model.matmul_flops([part], False) == pytest.approx(
+        share * model.matmul_flops([full], False), rel=1e-12)
 
 
 RECORDED = sorted(FIXTURES.glob("*.json"))
@@ -102,7 +125,8 @@ def test_recorded_chip_trace(path):
     cell = Cell("x", 1, json.loads((BENCH / "configs" /
                                     f"{fx['config']}.json").read_text()),
                 {}, {}, [], [])
-    r = Reading(rec, tr, lo, hi, model.shapes(cell.model), PEAKS, cell, 1.0)
+    r = Reading(rec, tr, lo, hi, cell.family().shapes(cell.model), PEAKS,
+                cell, 1.0)
     got = {}
     for name in fx["metrics"]:
         got[name] = metric_reader(name)(r)

@@ -7,7 +7,7 @@ from pathlib import Path
 
 import pytest
 
-from conftest import SMOKE_MODEL
+from conftest import SMOKE_MODEL, dense
 from harness import model, profile, scopes
 from harness.cell import BENCH, Cell, metric_reader
 from harness.record import Record, Step
@@ -18,6 +18,8 @@ PEAKS = json.loads((BENCH / "peaks.json").read_text())["devices"]["TPU v5 lite"]
 SCOPED = ["sfc_attn_proj_roofline.score", "sfc_mlp_roofline.score",
           "sfc_head_roofline.score", "attention_core_pct.score"]
 KERNEL = r"^sfc_matmul_pallas\b"
+# the dense family's GEMM roles
+ROLES = scopes.roles_of(dense().shapes(SMOKE_MODEL))
 
 # op_name paths as the program gives them (repro.models), in the scan
 # under remat and outside it
@@ -52,25 +54,54 @@ def _hand_trace():
 
 def _reading(trace, rec, m=SMOKE_MODEL):
     cell = Cell("x", 1, m, {}, {}, [], [])
-    return Reading(rec, trace, 0.0, 100.0, model.shapes(m), PEAKS, cell,
+    return Reading(rec, trace, 0.0, 100.0, dense().shapes(m), PEAKS, cell,
                    1.0)
 
 
 def test_role_is_the_innermost_gemm_scope():
-    assert [scopes.role(HAND[f"sfc_matmul_pallas.{i}"])
-            for i in range(1, 5)] == ["q", "o", "gate", "head"]
-    assert scopes.role("jit(score)/jit(_take)/gather") is None
+    assert ROLES == ("attn/q", "attn/k", "attn/v", "attn/o", "mlp/gate",
+                     "mlp/up", "mlp/down", "head")
+    assert [scopes.role(HAND[f"sfc_matmul_pallas.{i}"], ROLES)
+            for i in range(1, 5)] == ["attn/q", "attn/o", "mlp/gate", "head"]
+    assert scopes.role("jit(score)/jit(_take)/gather", ROLES) is None
     # a part is a whole path component: "core" is no role, "qk" no "q"
-    assert scopes.role(HAND["fusion.1"]) is None
-    assert scopes.role("a/qk/b") is None
-    assert scopes.role("a/head/mlp/up/b") == "up"
+    assert scopes.role(HAND["fusion.1"], ROLES) is None
+    assert scopes.role("a/attn/qk/b", ROLES) is None
+    assert scopes.role("a/head/mlp/up/b", ROLES) == "mlp/up"
     assert scopes.under(HAND["fusion.2"], "attn/core")
     assert not scopes.under("x/attn/o/core2", "attn/core")
-    assert [scopes.part(HAND[n]) for n in (
+    assert [scopes.part(HAND[n], ROLES) for n in (
         "sfc_matmul_pallas.1", "fusion.1", "sfc_matmul_pallas.3",
         "sfc_matmul_pallas.4", "dynamic-slice_bitcast_fusion.1",
         "exponential_reduce_fusion")] == [
         "attn/q", "attn/core", "mlp/gate", "head", "layers", "outside"]
+
+
+def test_roles_are_told_apart_by_their_whole_path():
+    """An expert's gate and the dense MLP's gate are two roles: each op
+    falls to the longest declared role on its path, whatever its last
+    part."""
+    declared = ("attn/q", "mlp/gate", "moe/experts/gate", "moe/router",
+                "head")
+    expert = f"{LAYER}/moe/experts/gate/{PALLAS}"
+    mlp = f"{LAYER}/mlp/gate/{PALLAS}"
+    assert scopes.role(expert, declared) == "moe/experts/gate"
+    assert scopes.role(mlp, declared) == "mlp/gate"
+    assert scopes.role(f"{LAYER}/moe/experts/up/{PALLAS}", declared) is None
+    # a gate under the experts but not the declared path's is no role
+    assert scopes.role(f"{LAYER}/experts/gate/{PALLAS}", declared) is None
+    tr = profile.Trace({"/device:TPU:0": [
+        (0, 30, "sfc_matmul_pallas.1"), (30, 40, "sfc_matmul_pallas.2")]},
+        [])
+    tr.scopes = {"sfc_matmul_pallas.1": expert, "sfc_matmul_pallas.2": mlp}
+    sec = {r: scopes.role_seconds(tr, tr.scopes, KERNEL, (r,), declared, 0,
+                                  100) * 1e9 for r in declared}
+    assert sec == pytest.approx({"attn/q": 0, "mlp/gate": 10,
+                                 "moe/experts/gate": 30, "moe/router": 0,
+                                 "head": 0})
+    assert {k: v * 1e9 for k, v in scopes.part_seconds(
+        tr, tr.scopes, declared, 0, 100).items()} == pytest.approx(
+        {"moe/experts/gate": 30, "mlp/gate": 10})
 
 
 def test_the_scope_map_is_read_from_the_profiles_programs(tmp_path):
@@ -97,7 +128,8 @@ def test_the_scope_map_is_read_from_the_profiles_programs(tmp_path):
     (name,) = [n for n in progs if n.startswith("jit_f(")]
     dots = {n: p for n, p in progs[name].items()
             if p.endswith("dot_general")}
-    assert dots and all(scopes.role(p) == "q" and scopes.part(p) == "layers"
+    assert dots and all(scopes.role(p, ("q",)) == "q"
+                        and scopes.part(p, ()) == "layers"
                         for p in dots.values()), progs[name]
     # with no device plane, every program the profile holds
     assert scopes.load(str(tmp_path)).keys() >= progs[name].keys()
@@ -106,21 +138,21 @@ def test_the_scope_map_is_read_from_the_profiles_programs(tmp_path):
 
 def test_role_reduction_on_a_hand_made_trace():
     tr = _hand_trace()
-    sec = {r: scopes.role_seconds(tr, tr.scopes, KERNEL, (r,), 0, 100)
-           * 1e9 for r in scopes.ROLES}
-    assert sec == pytest.approx({"q": 10, "k": 0, "v": 0, "o": 10,
-                                 "gate": 35, "up": 0, "down": 0,
-                                 "head": 15})
+    sec = {r: scopes.role_seconds(tr, tr.scopes, KERNEL, (r,), ROLES, 0,
+                                  100) * 1e9 for r in ROLES}
+    assert sec == pytest.approx({"attn/q": 10, "attn/k": 0, "attn/v": 0,
+                                 "attn/o": 10, "mlp/gate": 35, "mlp/up": 0,
+                                 "mlp/down": 0, "head": 15})
     assert sum(sec.values()) == pytest.approx(
         profile.kernel_seconds(tr, KERNEL, 0, 100) * 1e9)
     # clipped to the window, as the kernel's own reader clips
-    assert scopes.role_seconds(tr, tr.scopes, KERNEL, ("gate",), 0,
-                               50) * 1e9 == pytest.approx(15)
+    assert scopes.role_seconds(tr, tr.scopes, KERNEL, ("mlp/gate",), ROLES,
+                               0, 50) * 1e9 == pytest.approx(15)
     assert scopes.scope_seconds(tr, tr.scopes, "attn/core", 0, 100) \
         * 1e9 == pytest.approx(10)
     # the while spans its ops and is no part of its own
     parts = {k: v * 1e9 for k, v in
-             scopes.part_seconds(tr, tr.scopes, 0, 100).items()}
+             scopes.part_seconds(tr, tr.scopes, ROLES, 0, 100).items()}
     assert parts == pytest.approx({"layers": 5, "attn/q": 10,
                                    "attn/core": 10, "attn/o": 10,
                                    "mlp/gate": 35, "head": 15,
@@ -130,16 +162,16 @@ def test_role_reduction_on_a_hand_made_trace():
 
     rec = Record(0.0, 1.0, [Step(0.0, 1.0, [(256, 0, True)])])
     r = _reading(tr, rec)
-    s = r.shapes
+    gemms = r.shapes.gemms()
     f, bw = PEAKS["bf16_flops_per_s"], PEAKS["hbm_bytes_per_s"]
 
     def want(roles, t_ns):
-        return 100 * scopes.role_min_seconds(s, roles, 256, 256, f, bw) \
-            / (t_ns / 1e9)
+        return 100 * model.gemm_min_seconds(gemms, 256, 256, f, bw,
+                                            roles=roles) / (t_ns / 1e9)
     assert metric_reader("sfc_attn_proj_roofline.score")(r) == \
-        pytest.approx(want(("q", "k", "v", "o"), 20))
+        pytest.approx(want(("attn/q", "attn/k", "attn/v", "attn/o"), 20))
     assert metric_reader("sfc_mlp_roofline.score")(r) == \
-        pytest.approx(want(("gate", "up", "down"), 35))
+        pytest.approx(want(("mlp/gate", "mlp/up", "mlp/down"), 35))
     assert metric_reader("sfc_head_roofline.score")(r) == \
         pytest.approx(want(("head",), 15))
     assert metric_reader("attention_core_pct.score")(r) == \
@@ -166,15 +198,15 @@ def test_without_scopes_the_readers_read_nothing():
 def test_role_least_times_sum_to_the_gemm_bound(config, rows, head_rows):
     m = SMOKE_MODEL if config == "smoke" else json.loads(
         (BENCH / "configs" / f"{config}.json").read_text())
-    s = model.shapes(m)
+    gemms = dense().shapes(m).gemms()
     f, bw = PEAKS["bf16_flops_per_s"], PEAKS["hbm_bytes_per_s"]
-    parts = [scopes.role_min_seconds(s, (r,), rows, head_rows, f, bw)
-             for r in scopes.ROLES]
+    parts = [model.gemm_min_seconds(gemms, rows, head_rows, f, bw,
+                                    roles=(r,)) for r in ROLES]
     assert sum(parts) == pytest.approx(
-        s.gemm_min_seconds(rows, head_rows, f, bw), rel=1e-12)
-    assert scopes.role_min_seconds(s, scopes.ROLES, rows, head_rows, f,
-                                   bw) == pytest.approx(sum(parts),
-                                                        rel=1e-12)
+        model.gemm_min_seconds(gemms, rows, head_rows, f, bw), rel=1e-12)
+    assert model.gemm_min_seconds(gemms, rows, head_rows, f, bw,
+                                  roles=ROLES) == pytest.approx(sum(parts),
+                                                                rel=1e-12)
 
 
 RECORDED = sorted(FIXTURES.glob("*.scoped.json"))
@@ -195,7 +227,7 @@ def test_recorded_scoped_chip_trace(path):
                   for st in fx["record"]["steps"]])
     lo, hi = profile.window(tr)
     m = json.loads((BENCH / "configs" / f"{fx['config']}.json").read_text())
-    r = Reading(rec, tr, lo, hi, model.shapes(m), PEAKS,
+    r = Reading(rec, tr, lo, hi, dense().shapes(m), PEAKS,
                 Cell("x", 1, m, {}, {}, [], []), 1.0)
     want = {**fx["metrics"], **fx["scoped_metrics"]}
     assert set(fx["scoped_metrics"]) == set(SCOPED)
@@ -209,12 +241,12 @@ def test_recorded_scoped_chip_trace(path):
     sfc = {n for ops in tr.device_ops.values() for _, _, n in ops
            if n.startswith("sfc_matmul_pallas")}
     assert len(sfc) == 8
-    assert sorted(scopes.role(tr.scopes[n]) for n in sfc) == \
-        sorted(scopes.ROLES)
-    by_role = sum(scopes.role_seconds(tr, tr.scopes, KERNEL, (x,), lo, hi)
-                  for x in scopes.ROLES)
+    assert sorted(scopes.role(tr.scopes[n], ROLES) for n in sfc) == \
+        sorted(ROLES)
+    by_role = sum(scopes.role_seconds(tr, tr.scopes, KERNEL, (x,), ROLES, lo,
+                                      hi) for x in ROLES)
     assert by_role == pytest.approx(
         profile.kernel_seconds(tr, KERNEL, lo, hi), rel=1e-3)
-    parts = scopes.part_seconds(tr, tr.scopes, lo, hi)
+    parts = scopes.part_seconds(tr, tr.scopes, ROLES, lo, hi)
     assert parts.get("outside", 0.0) < 0.02 * profile.busy_seconds(
         tr, lo, hi)
