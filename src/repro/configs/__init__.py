@@ -17,6 +17,7 @@ ARCHS = [
     "h2o_danube_3_4b",
     "hubert_xlarge",
     "hymba_1_5b",
+    "moonlight_16b_a3b",
 ]
 
 _ALIASES = {a.replace("_", "-"): a for a in ARCHS}

@@ -25,6 +25,14 @@ number of leading batch dims, executed by a 3-D-grid Pallas kernel with
 the SFC schedule on the (i, j) tile plane (or by ``vmap`` over the 2-D
 kernel with ``via_vmap=True``).
 
+``sfc_gmm`` is the grouped GEMM of a routed expert layer: rows sorted
+by expert, each expert's rows padded to whole tiles of
+:data:`~repro.kernels.sfc_matmul.GROUP_ROWS`, each times its expert's
+weight, in one kernel (``sfc_gmm_pallas``) whose grid is sized for the
+static bound on rows.  Each GEMM traced onto it adds its grid steps and
+its row bound to the ``sfc_gmm.grid_steps`` / ``sfc_gmm.row_bound``
+counters.
+
 ``use_prefetch`` defaults to ``True`` across the whole stack (kernels,
 wrappers, engine): the scalar-prefetch schedule table works on any grid
 and amortises index cost to zero.  ``False`` (the paper-faithful
@@ -51,11 +59,13 @@ import jax.numpy as jnp
 
 from repro.obs.metrics import default_registry
 
-from .ref import matmul_batched_fused_ref, matmul_fused_ref
-from .sfc_matmul import grid_step_counts, sfc_blocks, \
-    sfc_matmul_batched_pallas, sfc_matmul_pallas
+from .ref import apply_epilogue_ref, matmul_batched_fused_ref, \
+    matmul_fused_ref
+from .sfc_matmul import GROUP_ROWS, grid_step_counts, sfc_blocks, \
+    sfc_gmm_pallas, sfc_matmul_batched_pallas, sfc_matmul_pallas
 
-__all__ = ["sfc_matmul", "sfc_matmul_batched", "default_backend_is_tpu"]
+__all__ = ["sfc_matmul", "sfc_matmul_batched", "sfc_gmm",
+           "default_backend_is_tpu"]
 
 
 def default_backend_is_tpu() -> bool:
@@ -335,3 +345,44 @@ def sfc_matmul_batched(
         out_dtype=out_dtype, use_prefetch=use_prefetch, interpret=interpret,
         force_pallas=force_pallas, via_vmap=via_vmap, g=g,
         bias=bias, activation=activation, residual=residual)
+
+
+def gmm_ref(a, b, group_tiles, *, activation: str = "none", out_dtype=None):
+    """The grouped GEMM in XLA (``lax.ragged_dot``): the same math as
+    ``sfc_gmm``, rows past the groups' tiles zero."""
+    acc = jax.lax.ragged_dot(a, b, group_tiles * GROUP_ROWS,
+                             preferred_element_type=jnp.float32)
+    return apply_epilogue_ref(acc, None, activation, None,
+                              out_dtype or a.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("schedule", "out_dtype", "interpret",
+                              "force_pallas", "activation"))
+def sfc_gmm(a, b, group_tiles, *, schedule: str = "morton",
+            out_dtype=None, interpret: bool | None = None,
+            force_pallas: bool = False, activation: str = "none"):
+    """Grouped GEMM: ``a`` (R, K), rows sorted by group and each group
+    padded to whole tiles of ``GROUP_ROWS`` rows, ``group_tiles[e]``
+    tiles for group ``e``; ``b`` (G, K, N) -> (R, N), ``activation`` in
+    the flush.  The column and K blocks are those :func:`sfc_blocks`
+    derives for one row tile.  Off the kernel path (the XLA baseline, or
+    a CPU without ``interpret``/``force_pallas``) the same math runs as
+    ``lax.ragged_dot``."""
+    if schedule == "auto":
+        raise ValueError("the tuner has no grouped GEMM: name a schedule")
+    out_dtype = out_dtype or a.dtype
+    if not _uses_kernel(schedule, interpret, force_pallas):
+        return gmm_ref(a, b, group_tiles, activation=activation,
+                       out_dtype=out_dtype)
+    (r, k), n = a.shape, b.shape[-1]
+    _, bn, bk = sfc_blocks(GROUP_ROWS, n, k, jnp.dtype(a.dtype).itemsize,
+                           jnp.dtype(out_dtype).itemsize)
+    reg = default_registry()
+    if reg.enabled:
+        reg.counter("sfc_gmm.grid_steps").inc(
+            -(-r // GROUP_ROWS) * -(-n // bn) * -(-k // bk))
+        reg.counter("sfc_gmm.row_bound").inc(r)
+    return sfc_gmm_pallas(a, b, group_tiles, schedule=schedule, bn=bn, bk=bk,
+                          out_dtype=out_dtype, interpret=bool(interpret),
+                          activation=activation)

@@ -44,6 +44,15 @@ block that lies outside C; a last block that overhangs K is zeroed past
 K inside the kernel before its product, so the overhang adds nothing.
 Each call raises the compiler's scoped VMEM limit to its double-buffered
 working set (:func:`sfc_vmem_bytes`) where that exceeds the default.
+
+The grouped kernel (:func:`sfc_gmm_pallas`) runs a routed expert layer's
+GEMM: rows sorted by expert, each expert's rows padded to whole tiles of
+:data:`GROUP_ROWS`, each tile times its expert's weight.  The number of
+live tiles is known only at run time, so the grid is sized for the
+static bound and the scalar prefetch carries the walk, each tile's
+expert and the live step count; the steps past the live ones compute
+nothing and repeat the last live step's block indices, so they start no
+copy.
 """
 from __future__ import annotations
 
@@ -62,8 +71,9 @@ from repro.core.schedule import grid_schedule, is_pow2, \
     schedule_extra_kwargs
 from repro.kernels.ref import ACTIVATIONS, apply_activation
 
-__all__ = ["sfc_matmul_pallas", "sfc_matmul_batched_pallas", "decode_step",
-           "sfc_blocks", "sfc_vmem_bytes", "grid_step_counts"]
+__all__ = ["sfc_matmul_pallas", "sfc_matmul_batched_pallas", "sfc_gmm_pallas",
+           "decode_step", "sfc_blocks", "sfc_vmem_bytes", "grid_step_counts",
+           "GROUP_ROWS"]
 
 # the compiler's default scoped VMEM limit on a v5e; a kernel whose
 # working set is larger sets ``vmem_limit_bytes`` itself
@@ -84,6 +94,9 @@ BM_MAX, BK_MAX = 512, 4096
 BN_STEPS = (1024, 2048)
 STEP_INTENSITY = 1.2 * TPU_V5E.peak_flops / TPU_V5E.hbm_bw
 LANES = 128
+# the row tile of the grouped GEMM, to which each group is padded: one
+# MXU pass high, so a group wastes at most 127 rows
+GROUP_ROWS = 128
 
 
 def decode_step(t, schedule: str, mt: int, nt: int):
@@ -209,10 +222,11 @@ def _fused_flush(acc, bias_ref, res_ref, activation: str, out_dtype, ld):
 
 def _mm_kernel(a_ref, b_ref, *rest, kt: int, k_tail: int, out_dtype,
                batched: bool, activation: str = "none",
-               has_bias: bool = False, has_residual: bool = False):
+               has_bias: bool = False, has_residual: bool = False, k=None):
     """One grid step.  ``batched`` kernels see (1, ...) blocks and the k
-    index on grid dim 2; ``k_tail`` is the width of the last k block
-    that lies inside K (0: it lies inside whole)."""
+    index on grid dim 2 (or as ``k``, where the caller read it);
+    ``k_tail`` is the width of the last k block that lies inside K (0:
+    it lies inside whole)."""
     # rest: [bias_ref], [residual_ref], o_ref, [acc_ref] (inputs before
     # outputs before scratch -- pallas_call calling convention)
     rest = list(rest)
@@ -244,7 +258,8 @@ def _mm_kernel(a_ref, b_ref, *rest, kt: int, k_tail: int, out_dtype,
     if kt == 1:
         flush(product(bool(k_tail)))
         return
-    k = pl.program_id(2 if batched else 1)
+    if k is None:
+        k = pl.program_id(2 if batched else 1)
 
     @pl.when(k == 0)
     def _zero():
@@ -460,3 +475,95 @@ def sfc_matmul_batched_pallas(
                      bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
                      use_prefetch=use_prefetch, interpret=interpret, g=g,
                      activation=activation)
+
+
+def _gmm_kernel(steps_ref, groups_ref, live_ref, *args, **kwargs):
+    """One grid step of the grouped GEMM: the dense kernel's body on the
+    step's blocks, or nothing past the live steps."""
+    k = pl.program_id(1)
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _live():
+        _mm_kernel(*args, k=k, **kwargs)
+
+
+def gmm_steps(schedule: str, mt: int, nt: int, live_tiles):
+    """The grouped GEMM's walk of its (mt, nt) tile grid, flat as the
+    scalar-prefetch table wants it, and its number of live steps: the
+    curve's steps over the first ``live_tiles`` row tiles in the
+    curve's order, then every other step set to the last live one, so
+    that those steps keep the blocks they find resident."""
+    base = jnp.asarray(
+        grid_schedule(schedule, mt, nt, **schedule_extra_kwargs(schedule, 0)),
+        dtype=jnp.int32)
+    order = jnp.argsort(base[:, 0] >= live_tiles, stable=True)
+    steps = base[order]
+    live = live_tiles * nt
+    last = steps[jnp.maximum(live - 1, 0)]
+    steps = jnp.where(jnp.arange(mt * nt)[:, None] < live, steps, last)
+    return steps.reshape(-1), live
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "schedule", "bn", "bk", "out_dtype", "interpret", "activation"))
+def sfc_gmm_pallas(a, b, group_tiles, *, schedule: str = "morton",
+                   bn: int = 128, bk: int = 128, out_dtype=None,
+                   interpret: bool = False, activation: str = "none"):
+    """Grouped GEMM: rows of ``a`` (R, K) sorted by group, group ``e``
+    taking ``group_tiles[e]`` whole tiles of ``GROUP_ROWS`` rows, times
+    its own ``b[e]`` (K, N) -> (R, N), with ``activation`` on the f32
+    accumulator in the flush.
+
+    The grid is sized for R, the static bound; each row tile's group
+    comes in the scalar prefetch, and the (row tile, column tile) grid
+    is walked in ``schedule`` order over the live tiles.  Steps past
+    them do no compute, and their index maps repeat the last live
+    step's blocks, so they start no copy.  Rows past the live tiles are
+    left as the output buffer had them."""
+    r, k = a.shape
+    ng, _, n = b.shape
+    bm = GROUP_ROWS
+    assert r % bm == 0 and b.shape[1] == k, (a.shape, b.shape)
+    _check_epilogue(None, None, activation, n, (r, n))
+    mt, nt, kt = r // bm, -(-n // bn), -(-k // bk)
+    out_dtype = out_dtype or a.dtype
+    ends = jnp.cumsum(group_tiles)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(mt), side="right"),
+        ng - 1).astype(jnp.int32)
+    steps, live = gmm_steps(schedule, mt, nt, ends[-1])
+
+    def kk_of(t, kk, live_ref):
+        return jnp.where(t < live_ref[0], kk, kt - 1)
+
+    def a_map(t, kk, steps_ref, groups_ref, live_ref):
+        return steps_ref[2 * t], kk_of(t, kk, live_ref)
+
+    def b_map(t, kk, steps_ref, groups_ref, live_ref):
+        return (groups_ref[steps_ref[2 * t]], kk_of(t, kk, live_ref),
+                steps_ref[2 * t + 1])
+
+    def o_map(t, kk, steps_ref, groups_ref, live_ref):
+        return steps_ref[2 * t], steps_ref[2 * t + 1]
+
+    need = sfc_vmem_bytes(bm, bn, bk, a.dtype.itemsize,
+                          jnp.dtype(out_dtype).itemsize, kt=kt,
+                          k_tail=k % bk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(mt * nt, kt),
+        in_specs=[pl.BlockSpec((bm, bk), a_map),
+                  pl.BlockSpec((None, bk, bn), b_map)],
+        out_specs=pl.BlockSpec((bm, bn), o_map),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if kt > 1
+        else [])
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, kt=kt, k_tail=k % bk,
+                          out_dtype=out_dtype, batched=False,
+                          activation=activation),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(need)),
+        interpret=interpret,
+    )(steps, tile_group, live.reshape(1).astype(jnp.int32), a, b)

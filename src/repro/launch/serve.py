@@ -45,6 +45,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import _engine_for
 from repro.models import DotEngine, decode_step, \
     fused_epilogue_savings_bytes, init_decode_state, init_model
+from repro.models.attention import refuse_mla
 from repro.models.transformer import prefill_kv_chunk
 from repro.obs import MetricsRegistry, Tracer, default_registry, \
     default_tracer, null_registry
@@ -95,6 +96,7 @@ class ServeLoop:
                         else KVLayout.CONTIGUOUS
             config = ServeConfig(**legacy)
         sc = config if config is not None else ServeConfig()
+        refuse_mla(cfg, "serving")
         self.config = sc
         self.cfg = cfg
         self.params = params

@@ -11,6 +11,12 @@ Supports: GQA (kv head grouping), RoPE, qwen3-style per-head qk-norm,
 sliding-window attention (SWA), encoder (bidirectional) mode, and a decode
 step against a KV cache (the distributed sequence-parallel decode lives in
 ``repro.distributed.sp_attention``).
+
+Latent attention (MLA, DeepSeek-V2/V3, :func:`mla_attention`) runs the
+full-sequence forward only: q from its own projection; the keys and
+values from a low-rank latent (``wkv_a``, RMS-normed, then ``wkv_b``),
+each key's rotary part one vector shared by every head.  It has no
+decode path yet: that needs a latent KV cache (ROADMAP Reach 5).
 """
 from __future__ import annotations
 
@@ -23,12 +29,18 @@ from jax.sharding import PartitionSpec as P
 
 from .layers import DotEngine, apply_rope, init_linear, init_rms, rms_norm
 
-__all__ = ["init_attention", "attention", "decode_attention",
-           "paged_decode_attention", "prefill_kv"]
+__all__ = ["init_attention", "attention", "mla_attention",
+           "decode_attention", "paged_decode_attention", "prefill_kv"]
+
+# the RMSNorm epsilon of MLA's latent (DeepSeek-V3's kv_a_layernorm takes
+# its norm's default; config.json does not give it)
+KV_A_NORM_EPS = 1e-6
 
 
 def init_attention(key, cfg, dtype=jnp.float32):
     d, h, hkv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    if cfg.mla:
+        return _init_mla(key, cfg, dtype)
     ks = jax.random.split(key, 4)
     p = {
         "wq": init_linear(ks[0], d, h * dh, dtype),
@@ -42,6 +54,28 @@ def init_attention(key, cfg, dtype=jnp.float32):
     return p
 
 
+def refuse_mla(cfg, what: str) -> None:
+    """Raise where a latent-attention config asks for ``what``, which
+    needs the latent KV cache it does not have yet."""
+    if cfg.mla:
+        raise NotImplementedError(
+            f"{cfg.name} has latent attention (MLA): {what} needs a latent "
+            f"KV cache, which is not built yet (ROADMAP Reach 5)")
+
+
+def _init_mla(key, cfg, dtype):
+    d, h, r, rd = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": init_linear(ks[0], d, h * (cfg.d_head + rd), dtype),
+        "wkv_a": init_linear(ks[1], d, r + rd, dtype),
+        "kv_norm": init_rms(r, dtype),
+        "wkv_b": init_linear(ks[2], r, h * (cfg.d_head + cfg.v_head_dim),
+                             dtype),
+        "wo": init_linear(ks[3], h * cfg.v_head_dim, d, dtype),
+    }
+
+
 def _project_qkv(x, p, cfg, engine: DotEngine, cos, sin):
     b, s, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
@@ -53,8 +87,8 @@ def _project_qkv(x, p, cfg, engine: DotEngine, cos, sin):
         v = engine.dot(x, p["wv"]).reshape(b, s, hkv, dh)
     with jax.named_scope("core"):
         if cfg.qk_norm:
-            q = rms_norm(q, p["q_norm"])
-            k = rms_norm(k, p["k_norm"])
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
         if cfg.rope:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
@@ -62,7 +96,8 @@ def _project_qkv(x, p, cfg, engine: DotEngine, cos, sin):
 
 
 def _sdpa(q, k, v, mask, scale):
-    """q: (B,Sq,H,dh), k/v: (B,Sk,Hkv,dh) -> (B,Sq,H,dh); GQA by grouping."""
+    """q/k: (B,Sq|Sk,H|Hkv,dh), v: (B,Sk,Hkv,dv) -> (B,Sq,H,dv); GQA by
+    grouping."""
     b, sq, h, dh = q.shape
     hkv = k.shape[2]
     g = h // hkv
@@ -73,7 +108,7 @@ def _sdpa(q, k, v, mask, scale):
         scores = jnp.where(mask, scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", w, v)
-    return out.reshape(b, sq, h, dh)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def attention(x, p, cfg, engine: DotEngine, cos, sin, *,
@@ -90,6 +125,9 @@ def attention(x, p, cfg, engine: DotEngine, cos, sin, *,
     """
     from repro.distributed.ctx import constrain
 
+    if cfg.mla:
+        return mla_attention(x, p, cfg, engine, cos, sin, q_chunk=q_chunk,
+                             residual=residual)
     b, s, _ = x.shape
     q, k, v = _project_qkv(x, p, cfg, engine, cos, sin)
     with jax.named_scope("core"):
@@ -106,11 +144,46 @@ def attention(x, p, cfg, engine: DotEngine, cos, sin, *,
     return (out, k, v) if return_kv else out
 
 
+def mla_attention(x, p, cfg, engine: DotEngine, cos, sin, *,
+                  q_chunk: int = 1024, residual=None):
+    """Latent attention over the whole sequence (train / prefill), as
+    DeepSeek-V3 defines it with no q-LoRA: q = x wq, split per head into
+    its plain (d_head) and rotary (qk_rope_dim) parts; x wkv_a gives the
+    latent c (kv_lora_rank), RMS-normed, and one rotary key part shared
+    by every head; c wkv_b gives each head's plain key part (d_head) and
+    value (v_head_dim).  The rotary parts take RoPE by rotating halves:
+    the configuration stores their columns de-interleaved, which equals
+    the published rotation of adjacent pairs.  The causal softmax has
+    scale (d_head + qk_rope_dim)^-1/2; ``residual`` rides the
+    out-projection as in :func:`attention`."""
+    b, s, _ = x.shape
+    h, dn, r = cfg.n_heads, cfg.d_head, cfg.kv_lora_rank
+    with jax.named_scope("q"):
+        q = engine.dot(x, p["wq"]).reshape(b, s, h, -1)
+    with jax.named_scope("kv_a"):
+        kv = engine.dot(x, p["wkv_a"])
+    with jax.named_scope("core"):
+        c = rms_norm(kv[..., :r], p["kv_norm"], KV_A_NORM_EPS)
+        k_pe = apply_rope(kv[..., None, r:], cos, sin)
+    with jax.named_scope("kv_b"):
+        kv = engine.dot(c, p["wkv_b"]).reshape(b, s, h, -1)
+    with jax.named_scope("core"):
+        q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin)],
+                            -1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, h, k_pe.shape[-1]))],
+            -1)
+        out = _full_core(q, k, kv[..., dn:], cfg, q_chunk)
+    with jax.named_scope("o"):
+        return engine.dot(out.reshape(b, s, -1), p["wo"], residual=residual)
+
+
 def _full_core(q, k, v, cfg, q_chunk: int):
-    """softmax(q k^T) v over the whole sequence: bidirectional, or causal
-    in statically unrolled q chunks (within the SWA window, if any)."""
+    """softmax(q k^T / sqrt(q's head width)) v over the whole sequence:
+    bidirectional, or causal in statically unrolled q chunks (within the
+    SWA window, if any)."""
     s = q.shape[1]
-    scale = 1.0 / math.sqrt(cfg.d_head)
+    scale = 1.0 / math.sqrt(q.shape[-1])
     window = cfg.swa_window
     if not cfg.causal:
         return _sdpa(q, k, v, None, scale)

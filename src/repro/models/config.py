@@ -41,10 +41,22 @@ class ArchConfig:
     rope: bool = True
     rope_theta: float = 1e4
     causal: bool = True
+    norm_eps: float = 1e-6       # every RMSNorm of the residual stream
+    # --- latent attention (MLA, DeepSeek-V2/V3): d_head is the per-head
+    # q/k width without rotary, which rides the extra qk_rope_dim ---
+    kv_lora_rank: int = 0        # 0 -> grouped-query attention
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # --- MoE ---
-    moe_experts: int = 0
+    moe_experts: int = 0         # the router's width: every expert
     moe_topk: int = 0
     moe_dff: int = 0
+    moe_router: str = "softmax"  # | "sigmoid" (with a correction bias)
+    moe_route_scale: float = 1.0  # times the renormalised top-k weights
+    moe_shared_dff: int = 0      # width of the shared SwiGLU, 0 -> none
+    moe_first_dense: int = 0     # leading layers with a dense MLP (d_ff)
+    moe_held: int = 0            # experts this chip holds, 0 -> all
+    moe_first_held: int = 0      # the first of them
     # --- SSM (mamba2 / hybrid) ---
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -68,6 +80,19 @@ class ArchConfig:
 
     # ---------------- derived properties -----------------
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of each head's rotary part."""
+        return self.qk_rope_dim if self.mla else self.d_head
+
+    @property
+    def held_experts(self) -> int:
+        return self.moe_held or self.moe_experts
+
+    @property
     def padded_vocab(self) -> int:
         """Vocab rounded up to an MXU-aligned, TP-divisible multiple
         (Megatron-style padding; padded logits are masked in the loss)."""
@@ -83,7 +108,8 @@ class ArchConfig:
 
     @property
     def has_decode(self) -> bool:
-        return self.family != "encoder"
+        # no latent KV cache yet (ROADMAP Reach 5)
+        return self.family != "encoder" and not self.mla
 
     @property
     def subquadratic(self) -> bool:
@@ -112,15 +138,23 @@ class ArchConfig:
         if self.vocab:
             n += self.vocab * d  # untied lm head
         per_layer = 0
-        if self.has_attention:
+        if self.mla:
+            h, r, rd = self.n_heads, self.kv_lora_rank, self.qk_rope_dim
+            per_layer += d * h * (self.d_head + rd) + d * (r + rd) + r + \
+                r * h * (self.d_head + self.v_head_dim) + \
+                h * self.v_head_dim * d
+        elif self.has_attention:
             hdh = self.n_heads * self.d_head
             kvdh = self.n_kv_heads * self.d_head
             per_layer += d * hdh + 2 * d * kvdh + hdh * d
         if self.family in ("dense", "encoder", "vlm", "hybrid") and self.d_ff:
             per_layer += 3 * d * self.d_ff
         if self.family == "moe":
-            per_layer += self.moe_experts * 3 * d * self.moe_dff + \
-                d * self.moe_experts
+            experts = self.held_experts * 3 * d * self.moe_dff + \
+                d * self.moe_experts + 3 * d * self.moe_shared_dff
+            per_layer += experts
+            # the leading dense layers hold a dense MLP instead
+            n += self.moe_first_dense * (3 * d * self.d_ff - experts)
         if self.has_ssm:
             d_inner = self.ssm_heads * self.ssm_head_dim
             conv = d_inner + 2 * self.ssm_state
@@ -131,7 +165,7 @@ class ArchConfig:
         """MoE: only routed experts count towards MODEL_FLOPS."""
         if self.family != "moe":
             return self.params_count()
-        d, l = self.d_model, self.n_layers
+        d, l = self.d_model, self.n_layers - self.moe_first_dense
         dense = self.params_count() - \
-            l * self.moe_experts * 3 * d * self.moe_dff
+            l * self.held_experts * 3 * d * self.moe_dff
         return dense + l * self.moe_topk * 3 * d * self.moe_dff

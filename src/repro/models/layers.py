@@ -124,6 +124,24 @@ class DotEngine:
             bias=bias, activation=activation, residual=residual,
         )
 
+    def dot_grouped(self, x, w, group_tiles, *, activation: str = "none",
+                    out_dtype=None):
+        """Grouped GEMM of a routed expert layer: x (R, d_in), rows
+        sorted by group and each group padded to whole tiles of
+        :data:`repro.kernels.sfc_matmul.GROUP_ROWS` rows, group ``e``
+        taking ``group_tiles[e]`` tiles, times its own w[e] (d_in,
+        d_out) -> (R, d_out), ``activation`` fused.  Rows past the
+        groups' tiles are undefined.  Under an SFC schedule on a TPU it
+        is the grouped SFC kernel (``sfc_gmm_pallas``), whose blocks
+        are derived from the shape (``block`` does not apply); under
+        "xla" it is ``lax.ragged_dot``; "auto" raises (the tuner has no
+        grouped GEMM)."""
+        from repro.kernels.ops import sfc_gmm
+
+        return sfc_gmm(x, w, group_tiles, schedule=self.schedule,
+                       interpret=self.interpret, activation=activation,
+                       out_dtype=out_dtype)
+
 
 def init_linear(key, d_in: int, d_out: int, dtype=jnp.float32, scale=None):
     scale = scale if scale is not None else 1.0 / math.sqrt(d_in)

@@ -78,16 +78,34 @@ def _init_layer(key, cfg: ArchConfig, dtype, moe_pad: int | None):
     return p
 
 
+def _init_dense_layer(key, cfg: ArchConfig, dtype):
+    """A leading dense layer of an expert model: attention and a SwiGLU
+    of width ``d_ff``."""
+    ks = jax.random.split(key, 2)
+    return {"norm1": init_rms(cfg.d_model, dtype),
+            "attn": attn_mod.init_attention(ks[0], cfg, dtype),
+            "norm2": init_rms(cfg.d_model, dtype),
+            "mlp": init_swiglu(ks[1], cfg.d_model, cfg.d_ff, dtype)}
+
+
 def init_model(cfg: ArchConfig, key, moe_pad: int | None = None):
-    """moe_pad: model-axis size to pad expert count to (EP divisibility)."""
+    """moe_pad: model-axis size to pad expert count to (EP divisibility).
+
+    Per-layer weights are stacked under ``layers``; an expert model's
+    leading dense layers (``cfg.moe_first_dense``) under
+    ``dense_layers``."""
     dtype = cfg.param_jdtype()
     keys = jax.random.split(key, cfg.n_layers + 3)
-    layers = jax.vmap(
-        lambda k: _init_layer(k, cfg, dtype, moe_pad))(keys[:cfg.n_layers])
+    first = cfg.moe_first_dense
+    layers = jax.vmap(lambda k: _init_layer(k, cfg, dtype, moe_pad))(
+        keys[first:cfg.n_layers])
     params: dict[str, Any] = {
         "layers": layers,
         "final_norm": init_rms(cfg.d_model, dtype),
     }
+    if first:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _init_dense_layer(k, cfg, dtype))(keys[:first])
     if cfg.vocab:
         # vocab padded to a TP-divisible multiple (config.padded_vocab);
         # the loss/decode paths mask the padded logit columns.
@@ -102,7 +120,10 @@ def init_model(cfg: ArchConfig, key, moe_pad: int | None = None):
 
 
 # ------------------------------------------------------------- forward -----
-def _layer_fwd(x, lp, cfg: ArchConfig, engine: DotEngine, cos, sin, mesh):
+def _layer_fwd(x, lp, cfg: ArchConfig, engine: DotEngine, cos, sin, mesh,
+               routes: bool = False):
+    """One layer: (x, aux), or with ``routes`` (x, (aux, the expert
+    layer's choice of experts (T, k)))."""
     from repro.distributed import ctx as dctx
 
     c = dctx.current()
@@ -110,37 +131,40 @@ def _layer_fwd(x, lp, cfg: ArchConfig, engine: DotEngine, cos, sin, mesh):
         mesh = c.mesh
     x = dctx.constrain(x, "dp", None, None)
     aux = jnp.zeros((), jnp.float32)
+    eps = cfg.norm_eps
     # residual adds ride the out-projection / down-projection GEMMs'
     # fused epilogues instead of separate elementwise passes (DESIGN.md §9)
-    if cfg.family in ("dense", "encoder", "vlm"):
+    if cfg.family in ("dense", "encoder", "vlm", "moe"):
         with jax.named_scope("attn"):
-            x = attn_mod.attention(rms_norm(x, lp["norm1"]), lp["attn"],
+            x = attn_mod.attention(rms_norm(x, lp["norm1"], eps), lp["attn"],
                                    cfg, engine, cos, sin,
                                    q_chunk=cfg.attn_q_chunk, residual=x)
-        with jax.named_scope("mlp"):
-            x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
-                           residual=x)
-    elif cfg.family == "moe":
-        with jax.named_scope("attn"):
-            x = attn_mod.attention(rms_norm(x, lp["norm1"]), lp["attn"],
-                                   cfg, engine, cos, sin,
-                                   q_chunk=cfg.attn_q_chunk, residual=x)
-        y, aux = moe_mod.moe_ffn(
-            rms_norm(x, lp["norm2"]), lp["moe"], cfg, engine, mesh=mesh,
-            data_axes=(c.dp if c is not None else ("data",)))
-        x = x + y
+        if "moe" in lp:
+            with jax.named_scope("moe"):
+                out = moe_mod.moe_ffn(
+                    rms_norm(x, lp["norm2"], eps), lp["moe"], cfg, engine,
+                    mesh=mesh, data_axes=(c.dp if c is not None
+                                          else ("data",)), routes=routes)
+            x = x + out[0]
+            if routes:
+                return x, out[1:]
+            aux = out[1]
+        else:  # dense, or an expert model's leading dense layer
+            with jax.named_scope("mlp"):
+                x = swiglu_mlp(rms_norm(x, lp["norm2"], eps), lp["mlp"],
+                               engine, residual=x)
     elif cfg.family == "ssm":
-        x = x + ssm_mod.ssd_forward(rms_norm(x, lp["norm1"]), lp["ssm"], cfg,
-                                    engine, chunk=cfg.ssd_chunk)
+        x = x + ssm_mod.ssd_forward(rms_norm(x, lp["norm1"], eps), lp["ssm"],
+                                    cfg, engine, chunk=cfg.ssd_chunk)
     elif cfg.family == "hybrid":
-        h = rms_norm(x, lp["norm1"])
+        h = rms_norm(x, lp["norm1"], eps)
         a = attn_mod.attention(h, lp["attn"], cfg, engine, cos, sin,
                                q_chunk=cfg.attn_q_chunk)
         s = ssm_mod.ssd_forward(h, lp["ssm"], cfg, engine,
                                 chunk=cfg.ssd_chunk)
-        x = x + 0.5 * (rms_norm(a, lp["attn_out_norm"])
-                       + rms_norm(s, lp["ssm_out_norm"]))
-        x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
+        x = x + 0.5 * (rms_norm(a, lp["attn_out_norm"], eps)
+                       + rms_norm(s, lp["ssm_out_norm"], eps))
+        x = swiglu_mlp(rms_norm(x, lp["norm2"], eps), lp["mlp"], engine,
                        residual=x)
     else:
         raise ValueError(cfg.family)
@@ -169,8 +193,10 @@ def embed_inputs(params, cfg: ArchConfig, batch, engine: DotEngine):
 
 
 def forward(params, cfg: ArchConfig, batch, engine: DotEngine | None = None,
-            mesh=None):
-    """Full-sequence forward -> (logits (B,S,V) f32, aux_loss)."""
+            mesh=None, routes: bool = False):
+    """Full-sequence forward -> (logits (B,S,V) f32, aux_loss); with
+    ``routes`` (an expert model on the dropless path), also each expert
+    layer's choice of experts, (expert layers, B, S, k) int32."""
     from repro.distributed.ctx import constrain
     engine = engine or DotEngine()
     with jax.named_scope("embed"):
@@ -178,25 +204,34 @@ def forward(params, cfg: ArchConfig, batch, engine: DotEngine | None = None,
         x = constrain(x, "dp", None, None)
         b, s, _ = x.shape
         if cfg.has_attention and cfg.rope:
-            cos, sin = rope(jnp.arange(s), cfg.d_head, cfg.rope_theta)
+            cos, sin = rope(jnp.arange(s), cfg.rope_dim, cfg.rope_theta)
         else:
             cos = sin = None
 
-    def body(x, lp):
-        return _layer_fwd(x, lp, cfg, engine, cos, sin, mesh)
+    def layer_body(routes):
+        def body(x, lp):
+            return _layer_fwd(x, lp, cfg, engine, cos, sin, mesh, routes)
 
-    if cfg.remat:
-        if cfg.remat_policy == "dots":
-            # save GEMM outputs, recompute only elementwise chains --
-            # cuts backward recompute flops and activation traffic
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            body = jax.checkpoint(body, policy=policy)
-        else:
-            body = jax.checkpoint(body)
+        if cfg.remat:
+            if cfg.remat_policy == "dots":
+                # save GEMM outputs, recompute only elementwise chains --
+                # cuts backward recompute flops and activation traffic
+                policy = \
+                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+                return jax.checkpoint(body, policy=policy)
+            return jax.checkpoint(body)
+        return body
+
     with jax.named_scope("layers"):
-        x, auxs = jax.lax.scan(body, x, params["layers"])
+        if "dense_layers" in params:
+            x, _ = jax.lax.scan(layer_body(False), x, params["dense_layers"])
+        x, auxs = jax.lax.scan(layer_body(routes), x, params["layers"])
     logits = _head(params, cfg, x, engine)
-    return constrain(logits, "dp", None, "model"), auxs.mean()
+    logits = constrain(logits, "dp", None, "model")
+    if routes:
+        auxs, idx = auxs
+        return logits, auxs.mean(), idx.reshape(idx.shape[0], b, s, -1)
+    return logits, auxs.mean()
 
 
 def _head(params, cfg: ArchConfig, x, engine: DotEngine):
@@ -205,7 +240,7 @@ def _head(params, cfg: ArchConfig, x, engine: DotEngine):
     no vocab).  The f32 cast is fused into the GEMM's single output
     write instead of a separate full-logits cast pass."""
     with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["final_norm"])
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if not cfg.vocab:
         return x
     with jax.named_scope("head"):
@@ -262,6 +297,7 @@ def init_decode_state(cfg: ArchConfig, batch: int, cache_len: int,
     still accepted with a ``DeprecationWarning``.
     """
     from repro.serve.state import DecodeState, KVLayout, resolve_layout
+    attn_mod.refuse_mla(cfg, "a decode state")
     layout = resolve_layout(layout, paged)
     if layout is KVLayout.PAGED:
         from repro.serve.paged_kv import init_paged_decode_state
@@ -318,6 +354,7 @@ def prefill_kv(params, cfg: ArchConfig, state, tokens, slot: int = 0,
     families (dense / vlm / moe); ssm and hybrid states decode-prefill
     through ``decode_step`` instead.
     """
+    attn_mod.refuse_mla(cfg, "prefill")
     engine = engine or DotEngine()
     if not cfg.has_attention or cfg.has_ssm:
         raise ValueError(
@@ -333,17 +370,17 @@ def prefill_kv(params, cfg: ArchConfig, state, tokens, slot: int = 0,
         cos = sin = None
 
     def body(x, lp):
-        h = rms_norm(x, lp["norm1"])
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
         # q_chunk=seq: one exact-softmax chunk for any prompt length
         x, k, v = attn_mod.attention(h, lp["attn"], cfg, engine, cos, sin,
                                      q_chunk=seq, residual=x,
                                      return_kv=True)
         if cfg.family in ("dense", "vlm"):
-            x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
-                           residual=x)
+            x = swiglu_mlp(rms_norm(x, lp["norm2"], cfg.norm_eps), lp["mlp"],
+                           engine, residual=x)
         else:  # moe
             y, _ = moe_mod.moe_ffn(
-                rms_norm(x, lp["norm2"]), lp["moe"], cfg, engine,
+                rms_norm(x, lp["norm2"], cfg.norm_eps), lp["moe"], cfg, engine,
                 impl="dense")
             x = x + y
         return x, (k, v)
@@ -404,6 +441,7 @@ def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
     the prompt's last token, DESIGN.md §11), so the final-norm/lm_head
     compute is skipped.  Attention-only families, like ``prefill_kv``.
     """
+    attn_mod.refuse_mla(cfg, "chunked prefill")
     engine = engine or DotEngine()
     if not cfg.has_attention or cfg.has_ssm:
         raise ValueError(
@@ -454,7 +492,7 @@ def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
         """One layer: project the chunk, scatter K/V, attend over the
         slot's full written span, finish the block.  Returns
         (x', k_cache', v_cache')."""
-        h = rms_norm(x, lp["norm1"])
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
         q, k, v = attn_mod._project_qkv(h, lp["attn"], cfg, engine,
                                         cos, sin)
         if paged:
@@ -489,11 +527,11 @@ def prefill_kv_chunk(params, cfg: ArchConfig, state, tokens, slots,
             x = engine.dot(o.reshape(g, chunk, -1), lp["attn"]["wo"],
                            residual=x)
         if cfg.family in ("dense", "vlm"):
-            x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
-                           residual=x)
+            x = swiglu_mlp(rms_norm(x, lp["norm2"], cfg.norm_eps), lp["mlp"],
+                           engine, residual=x)
         else:  # moe
             y, _ = moe_mod.moe_ffn(
-                rms_norm(x, lp["norm2"]), lp["moe"], cfg, engine,
+                rms_norm(x, lp["norm2"], cfg.norm_eps), lp["moe"], cfg, engine,
                 impl="dense")
             x = x + y
         return x, k_cache, v_cache
@@ -556,16 +594,16 @@ def _decode_step_paged(params, cfg: ArchConfig, state, tokens, pos,
         # reserved zero row (exact parity with never-written contiguous
         # cache rows)
         phys = physical_rows(layer["perm"], bt, zero_row)
-        h = rms_norm(x, lp["norm1"])
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
         x, kp, vp = attn_mod.paged_decode_attention(
             h, lp["attn"], cfg, engine, kp, vp, phys, bt, pos, cos, sin,
             row_mask, residual=x)
         if cfg.family in ("dense", "vlm"):
-            x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
-                           residual=x)
+            x = swiglu_mlp(rms_norm(x, lp["norm2"], cfg.norm_eps), lp["mlp"],
+                           engine, residual=x)
         else:  # moe (state construction rejects ssm/hybrid)
             y, _ = moe_mod.moe_ffn(
-                rms_norm(x, lp["norm2"]), lp["moe"], cfg, engine,
+                rms_norm(x, lp["norm2"], cfg.norm_eps), lp["moe"], cfg, engine,
                 impl="dense")
             x = x + y
         return (x, kp, vp), None
@@ -595,6 +633,7 @@ def decode_step(params, cfg: ArchConfig, state, tokens, pos,
     ``row_mask`` (B,) bool: rows with False keep their caches/states
     untouched (slot-isolated writes for continuous batching).
     """
+    attn_mod.refuse_mla(cfg, "decode")
     engine = engine or DotEngine()
     if _is_paged(state):
         return _decode_step_paged(params, cfg, state, tokens, pos,
@@ -615,33 +654,33 @@ def decode_step(params, cfg: ArchConfig, state, tokens, pos,
         outs = {}
         if cfg.family in ("dense", "vlm"):
             x, knew, vnew = attn_mod.decode_attention(
-                rms_norm(x, lp["norm1"]), lp["attn"], cfg, engine,
-                layer["k"], layer["v"], state["kv_pos"], slot, pos, cos,
-                sin, row_mask, residual=x)
-            x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
-                           residual=x)
+                rms_norm(x, lp["norm1"], cfg.norm_eps), lp["attn"], cfg,
+                engine, layer["k"], layer["v"], state["kv_pos"], slot, pos,
+                cos, sin, row_mask, residual=x)
+            x = swiglu_mlp(rms_norm(x, lp["norm2"], cfg.norm_eps), lp["mlp"],
+                           engine, residual=x)
             outs.update(k=knew, v=vnew)
         elif cfg.family == "moe":
             x, knew, vnew = attn_mod.decode_attention(
-                rms_norm(x, lp["norm1"]), lp["attn"], cfg, engine,
-                layer["k"], layer["v"], state["kv_pos"], slot, pos, cos,
-                sin, row_mask, residual=x)
+                rms_norm(x, lp["norm1"], cfg.norm_eps), lp["attn"], cfg,
+                engine, layer["k"], layer["v"], state["kv_pos"], slot, pos,
+                cos, sin, row_mask, residual=x)
             # decode T is tiny: dense all-experts combine is exact
             # (dropless) and avoids sort/scatter under SPMD
             y, _ = moe_mod.moe_ffn(
-                rms_norm(x, lp["norm2"]), lp["moe"], cfg, engine,
+                rms_norm(x, lp["norm2"], cfg.norm_eps), lp["moe"], cfg, engine,
                 impl="dense")
             x = x + y
             outs.update(k=knew, v=vnew)
         elif cfg.family == "ssm":
             y, ssm_new = ssm_mod.ssm_decode(
-                rms_norm(x, lp["norm1"]), lp["ssm"], cfg, engine,
+                rms_norm(x, lp["norm1"], cfg.norm_eps), lp["ssm"], cfg, engine,
                 {"h": layer["ssm_h"], "conv": layer["ssm_conv"]},
                 row_mask=row_mask)
             x = x + y
             outs.update(ssm_h=ssm_new["h"], ssm_conv=ssm_new["conv"])
         elif cfg.family == "hybrid":
-            h = rms_norm(x, lp["norm1"])
+            h = rms_norm(x, lp["norm1"], cfg.norm_eps)
             a, knew, vnew = attn_mod.decode_attention(
                 h, lp["attn"], cfg, engine,
                 layer["k"], layer["v"], state["kv_pos"], slot, pos, cos,
@@ -650,10 +689,10 @@ def decode_step(params, cfg: ArchConfig, state, tokens, pos,
                 h, lp["ssm"], cfg, engine,
                 {"h": layer["ssm_h"], "conv": layer["ssm_conv"]},
                 row_mask=row_mask)
-            x = x + 0.5 * (rms_norm(a, lp["attn_out_norm"])
-                           + rms_norm(s, lp["ssm_out_norm"]))
-            x = swiglu_mlp(rms_norm(x, lp["norm2"]), lp["mlp"], engine,
-                           residual=x)
+            x = x + 0.5 * (rms_norm(a, lp["attn_out_norm"], cfg.norm_eps)
+                           + rms_norm(s, lp["ssm_out_norm"], cfg.norm_eps))
+            x = swiglu_mlp(rms_norm(x, lp["norm2"], cfg.norm_eps), lp["mlp"],
+                           engine, residual=x)
             outs.update(k=knew, v=vnew, ssm_h=ssm_new["h"],
                         ssm_conv=ssm_new["conv"])
         return x, outs

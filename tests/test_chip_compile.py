@@ -13,7 +13,11 @@ show on the chip.  These tests compile for one chip of a *described*
   at every GEMM of the benchmark cells' configurations (qwen3-1.7B and
   GLM-4-9B, a 2,048-row window and a 16-slot decode step) under the
   epilogue the forward gives it;
-* ``paged_decode_attention_pallas`` with pages of 8 and 16.
+* ``paged_decode_attention_pallas`` with pages of 8 and 16;
+* at the Moonlight-16B-A3B cell's shapes (two 2,048-token windows a
+  call): the grouped expert GEMM ``sfc_gmm_pallas`` (16 held experts of
+  width 1,408, its grid sized for the worst case), and latent
+  attention's kv_a (N 576) and kv_b (K 512) projections.
 
 The topology is described inside a fixture, never at import, so that
 only the test worker that runs this file loads the TPU library.
@@ -27,9 +31,9 @@ import jax.numpy as jnp
 
 from _gemms import cell_gemm_cases, forward_gemms
 from repro.configs import get_config
-from repro.kernels.ops import sfc_matmul
+from repro.kernels.ops import sfc_gmm, sfc_matmul
 from repro.kernels.paged_attention import paged_decode_attention_pallas
-from repro.kernels.sfc_matmul import sfc_matmul_pallas
+from repro.kernels.sfc_matmul import GROUP_ROWS, sfc_matmul_pallas
 from repro.serve.paged_kv import default_pool_pages, default_slot_pages
 from repro.tune import candidate_configs
 
@@ -183,3 +187,47 @@ def test_paged_attention_compiles(one_chip, page_size):
              jnp.int32),
         spec((slots,), jnp.int32)).compile().as_text()
     assert CUSTOM_CALL in hlo
+
+
+MOON = get_config("moonlight_16b_a3b")
+# the cell's tokens a call (2 windows of 2,048), and its held experts
+MOON_ROWS, MOON_HELD = 4096, 16
+
+
+@pytest.mark.parametrize("role", ["gate", "down"])
+def test_grouped_expert_gemm_compiles(one_chip, role):
+    """The grouped kernel over the worst case of rows (every token's
+    top 6 held, each expert's group padded to whole row tiles)."""
+    d, ff = MOON.d_model, MOON.moe_dff
+    k, n = (d, ff) if role == "gate" else (ff, d)
+    bound = MOON_ROWS * MOON.moe_topk + MOON_HELD * (GROUP_ROWS - 1)
+    rows = -(-bound // GROUP_ROWS) * GROUP_ROWS
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(a, b, tiles):
+        return sfc_gmm(a, b, tiles, force_pallas=True,
+                       activation="silu" if role == "gate" else "none")
+
+    hlo = jax.jit(call).lower(spec((rows, k)), spec((MOON_HELD, k, n)),
+                              spec((MOON_HELD,), jnp.int32)) \
+        .compile().as_text()
+    assert CUSTOM_CALL in hlo
+    assert "%sfc_gmm_pallas" in hlo and "%sfc_matmul_pallas" not in hlo
+
+
+@pytest.mark.parametrize("role", ["kv_a", "kv_b"])
+def test_mla_projections_compile(one_chip, role):
+    """kv_a's 576 columns (512 latent + 64 rotary: a ragged last block)
+    and kv_b's 512-deep K, with their derived blocks."""
+    d, r, rd = MOON.d_model, MOON.kv_lora_rank, MOON.qk_rope_dim
+    hv = MOON.n_heads * (MOON.d_head + MOON.v_head_dim)
+    k, n = (d, r + rd) if role == "kv_a" else (r, hv)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    hlo = jax.jit(lambda a, b: sfc_matmul(a, b, force_pallas=True)).lower(
+        spec(MOON_ROWS, k), spec(k, n)).compile().as_text()
+    assert CUSTOM_CALL in hlo and "%sfc_matmul_pallas" in hlo
