@@ -49,6 +49,16 @@ a resident A or B block to the ``sfc.grid_steps`` /
 ``sfc.copies_elided`` counters of ``repro.obs``'s default registry
 (host arithmetic while the jitted wrapper traces, so once per trace and
 never per call).
+
+``layer=`` (both ``sfc_matmul`` and ``sfc_gmm``): ``b`` is a weight
+stacked on a leading layer axis and ``layer`` the traced index of the
+GEMM's layer.  The kernel reads that layer in place (the index in its
+scalar prefetch); the XLA fallback takes ``b[layer]``, which fuses into
+its dot.  Blocks are derived from the layer's shape.  Each GEMM traced
+onto a kernel with a stacked weight adds one to
+``sfc.weights_in_place``, counted where the wrapper is called rather
+than in its jitted body, which traces once per shape (so k and v, which
+share one, count apart).
 """
 from __future__ import annotations
 
@@ -104,6 +114,16 @@ def _count_grid(schedule: str, m, n, k, bm, bn, bk, g, batch=1) -> None:
     reg.counter("sfc.copies_elided").inc(elided)
 
 
+def _count_in_place(layer, schedule: str, interpret, force_pallas: bool
+                     ) -> None:
+    """Count a GEMM whose stacked weight a kernel reads in place (see
+    the module docstring for why here and not in a jitted body)."""
+    reg = default_registry()
+    if reg.enabled and layer is not None and _uses_kernel(
+            schedule, interpret, force_pallas):
+        reg.counter("sfc.weights_in_place").inc()
+
+
 def _resolve_auto(m: int, n: int, k: int, dtype, batched: bool = False,
                   objective: str = "time", has_bias: bool = False,
                   activation: str = "none", has_residual: bool = False,
@@ -156,22 +176,25 @@ def _sfc_matmul(
     bias=None,
     activation: str = "none",
     residual=None,
+    layer=None,
 ):
     out_dtype = out_dtype or a.dtype
     if not _uses_kernel(schedule, interpret, force_pallas):
         # CPU/GPU fallback for real execution paths; kernels are still
         # exercised on CPU via interpret=True in tests/benchmarks.  The
-        # fused math is reproduced exactly (f32 epilogue, single cast).
-        return matmul_fused_ref(a, b, bias=bias, activation=activation,
+        # fused math is reproduced exactly (f32 epilogue, single cast);
+        # a stacked weight's slice fuses into XLA's dot.
+        return matmul_fused_ref(a, b if layer is None else b[layer],
+                                bias=bias, activation=activation,
                                 residual=residual, out_dtype=out_dtype)
 
-    _count_grid(schedule, a.shape[0], b.shape[1], a.shape[1], bm, bn, bk,
+    _count_grid(schedule, a.shape[0], b.shape[-1], a.shape[1], bm, bn, bk,
                 g)
     return sfc_matmul_pallas(
         a, b, schedule=schedule, bm=bm, bn=bn, bk=bk,
         out_dtype=out_dtype, use_prefetch=use_prefetch,
         interpret=bool(interpret), g=g,
-        bias=bias, activation=activation, residual=residual,
+        bias=bias, activation=activation, residual=residual, layer=layer,
     )
 
 
@@ -193,6 +216,7 @@ def sfc_matmul(
     bias=None,
     activation: str = "none",
     residual=None,
+    layer=None,
 ):
     """C = act(A @ B + bias) + residual, tiles visited in ``schedule`` order.
 
@@ -214,22 +238,26 @@ def sfc_matmul(
     * ``use_prefetch=True`` (default) amortises curve-index computation
       via scalar prefetch (beyond-paper; handles non-square grids),
       ``False`` decodes in ``index_map`` (paper-faithful trade of compute
-      for locality).
+      for locality);
+    * ``layer`` (an int32 scalar): ``b`` is a stack (L, K, N) and the
+      GEMM's weight its layer ``layer``, which the kernel reads in place
+      (the index in its scalar prefetch) and the XLA path slices.
     """
     if schedule == "auto":
         schedule, bm, bn, bk, use_prefetch, g = _resolve_auto(
-            a.shape[0], b.shape[1], a.shape[1], a.dtype,
+            a.shape[0], b.shape[-1], a.shape[1], a.dtype,
             objective=objective, has_bias=bias is not None,
             activation=activation, has_residual=residual is not None,
             comm=comm)
     bm, bn, bk = _resolve_blocks(
-        a.shape[0], b.shape[1], a.shape[1], a.dtype, out_dtype, bm, bn, bk,
+        a.shape[0], b.shape[-1], a.shape[1], a.dtype, out_dtype, bm, bn, bk,
         has_bias=bias is not None, has_residual=residual is not None)
+    _count_in_place(layer, schedule, interpret, force_pallas)
     return _sfc_matmul(
         a, b, schedule=schedule, bm=bm, bn=bn, bk=bk,
         out_dtype=out_dtype, use_prefetch=use_prefetch, interpret=interpret,
         force_pallas=force_pallas, g=g,
-        bias=bias, activation=activation, residual=residual)
+        bias=bias, activation=activation, residual=residual, layer=layer)
 
 
 @functools.partial(
@@ -356,25 +384,36 @@ def gmm_ref(a, b, group_tiles, *, activation: str = "none", out_dtype=None):
                               out_dtype or a.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("schedule", "out_dtype", "interpret",
-                              "force_pallas", "activation"))
 def sfc_gmm(a, b, group_tiles, *, schedule: str = "morton",
             out_dtype=None, interpret: bool | None = None,
-            force_pallas: bool = False, activation: str = "none"):
+            force_pallas: bool = False, activation: str = "none",
+            layer=None):
     """Grouped GEMM: ``a`` (R, K), rows sorted by group and each group
     padded to whole tiles of ``GROUP_ROWS`` rows, ``group_tiles[e]``
     tiles for group ``e``; ``b`` (G, K, N) -> (R, N), ``activation`` in
     the flush.  The column and K blocks are those :func:`sfc_blocks`
-    derives for one row tile.  Off the kernel path (the XLA baseline, or
-    a CPU without ``interpret``/``force_pallas``) the same math runs as
-    ``lax.ragged_dot``."""
+    derives for one row tile.  With ``layer``, ``b`` is a stack (L, G,
+    K, N) whose layer ``layer`` the kernel reads in place.  Off the
+    kernel path (the XLA baseline, or a CPU without
+    ``interpret``/``force_pallas``) the same math runs as
+    ``lax.ragged_dot``, on the layer's slice."""
     if schedule == "auto":
         raise ValueError("the tuner has no grouped GEMM: name a schedule")
+    _count_in_place(layer, schedule, interpret, force_pallas)
+    return _sfc_gmm(a, b, group_tiles, layer, schedule=schedule,
+                    out_dtype=out_dtype, interpret=interpret,
+                    force_pallas=force_pallas, activation=activation)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("schedule", "out_dtype", "interpret",
+                              "force_pallas", "activation"))
+def _sfc_gmm(a, b, group_tiles, layer, *, schedule: str, out_dtype,
+             interpret: bool | None, force_pallas: bool, activation: str):
     out_dtype = out_dtype or a.dtype
     if not _uses_kernel(schedule, interpret, force_pallas):
-        return gmm_ref(a, b, group_tiles, activation=activation,
-                       out_dtype=out_dtype)
+        return gmm_ref(a, b if layer is None else b[layer], group_tiles,
+                       activation=activation, out_dtype=out_dtype)
     (r, k), n = a.shape, b.shape[-1]
     _, bn, bk = sfc_blocks(GROUP_ROWS, n, k, jnp.dtype(a.dtype).itemsize,
                            jnp.dtype(out_dtype).itemsize)
@@ -385,4 +424,4 @@ def sfc_gmm(a, b, group_tiles, *, schedule: str = "morton",
         reg.counter("sfc_gmm.row_bound").inc(r)
     return sfc_gmm_pallas(a, b, group_tiles, schedule=schedule, bn=bn, bk=bk,
                           out_dtype=out_dtype, interpret=bool(interpret),
-                          activation=activation)
+                          activation=activation, layer=layer)

@@ -45,6 +45,14 @@ K inside the kernel before its product, so the overhang adds nothing.
 Each call raises the compiler's scoped VMEM limit to its double-buffered
 working set (:func:`sfc_vmem_bytes`) where that exceeds the default.
 
+Both the 2-D kernel and the grouped one take a weight stacked on a
+leading layer axis with a ``layer`` index: the index rides the scalar
+prefetch and B's index map puts it first, so a layer scan's GEMM reads
+its layer's blocks in place.  A slice ``w[i]`` handed to a Pallas call
+is a buffer of its own, which XLA writes out on every iteration; the
+stack is read as the scan holds it.  Per step the kernel moves the same
+bytes and does the same FLOPs either way.
+
 The grouped kernel (:func:`sfc_gmm_pallas`) runs a routed expert layer's
 GEMM: rows sorted by expert, each expert's rows padded to whole tiles of
 :data:`GROUP_ROWS`, each tile times its expert's weight.  The number of
@@ -281,9 +289,10 @@ def _mm_kernel(a_ref, b_ref, *rest, kt: int, k_tail: int, out_dtype,
         flush(acc_ref[...])
 
 
-def _mm_kernel_prefetch(sched_ref, *args, **kwargs):
-    # identical body; the schedule ref is consumed by the index_maps only
-    _mm_kernel(*args, **kwargs)
+def _mm_kernel_prefetch(*refs, n_prefetch: int, **kwargs):
+    # identical body; the scalar-prefetch refs (the walk, the layer
+    # index) are consumed by the index_maps only
+    _mm_kernel(*refs[n_prefetch:], **kwargs)
 
 
 def _flat_schedule(schedule: str, mt: int, nt: int, g: int):
@@ -322,14 +331,25 @@ def _epilogue_operands(bias, residual, bias_shape, bias_spec, res_spec):
     return specs, ops
 
 
-def _sfc_call(a, b, bias, residual, *, batched: bool, schedule: str,
+def _layer_operand(layer):
+    """A layer index as the (1,) int32 scalar-prefetch operand."""
+    return jnp.reshape(layer, (1,)).astype(jnp.int32)
+
+
+def _sfc_call(a, b, bias, residual, layer, *, batched: bool, schedule: str,
               bm: int, bn: int, bk: int, out_dtype, use_prefetch: bool,
               interpret: bool, g: int, activation: str):
     """The pallas_call of both kernels: grid (T, kt), or (batch, T, kt)
-    with the curve on every batch element's (i, j) tile plane."""
+    with the curve on every batch element's (i, j) tile plane.  Where
+    ``layer`` is given (2-D kernel only), ``b`` is a stack (L, K, N) and
+    its layer ``layer`` is read in place: the index rides the scalar
+    prefetch and B's index map puts it first."""
     m, k = a.shape[-2:]
     n = b.shape[-1]
-    assert b.shape[-2] == k and a.shape[:-2] == b.shape[:-2], (
+    stacked = layer is not None
+    assert not (batched and stacked)
+    assert b.shape[-2] == k and a.shape[:-2] == b.shape[:b.ndim - 2
+                                                        - stacked], (
         a.shape, b.shape)
     lead = a.shape[:-2]
     _check_epilogue(bias, residual, activation, n, lead + (m, n))
@@ -360,12 +380,18 @@ def _sfc_call(a, b, bias, residual, *, batched: bool, schedule: str,
                                 lambda bb_, t, kk, *s: (bb_, *f(t, kk, *s)))
         return pl.BlockSpec(shape, f)
 
+    # the scalar-prefetch operands: the walk, then the layer index
+    prefetch = []
     if use_prefetch:
-        def tile(t, sched_ref):
+        prefetch.append(_flat_schedule(schedule, mt, nt, g))
+
+        def tile(t, sched_ref, *_):
             return sched_ref[2 * t], sched_ref[2 * t + 1]
     else:
-        def tile(t):
+        def tile(t, *_):
             return decode_step(t, schedule, mt, nt)
+    if stacked:
+        prefetch.append(_layer_operand(layer))
 
     def a_map(t, kk, *s):
         return tile(t, *s)[0], kk
@@ -384,9 +410,12 @@ def _sfc_call(a, b, bias, residual, *, batched: bool, schedule: str,
         if batched else pl.BlockSpec((1, bn), bias_map)
     ep_specs, ep_ops = _epilogue_operands(
         bias, residual, one + (1, n), bias_spec, spec((bm, bn), o_map))
-    in_specs = [spec((bm, bk), a_map), spec((bk, bn), b_map), *ep_specs]
+    b_spec = pl.BlockSpec(
+        (None, bk, bn), lambda t, kk, *s: (s[-1][0], *b_map(t, kk, *s))) \
+        if stacked else spec((bk, bn), b_map)
+    in_specs = [spec((bm, bk), a_map), b_spec, *ep_specs]
     out_specs = spec((bm, bn), o_map)
-    if not use_prefetch:
+    if not prefetch:
         return pl.pallas_call(
             functools.partial(_mm_kernel, **kern_kw),
             grid=grid, in_specs=in_specs, out_specs=out_specs,
@@ -394,13 +423,14 @@ def _sfc_call(a, b, bias, residual, *, batched: bool, schedule: str,
             compiler_params=params, interpret=interpret,
         )(a, b, *ep_ops)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+        num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
         out_specs=out_specs, scratch_shapes=scratch)
     return pl.pallas_call(
-        functools.partial(_mm_kernel_prefetch, **kern_kw),
+        functools.partial(_mm_kernel_prefetch, n_prefetch=len(prefetch),
+                          **kern_kw),
         grid_spec=grid_spec, out_shape=out_shape,
         compiler_params=params, interpret=interpret,
-    )(_flat_schedule(schedule, mt, nt, g), a, b, *ep_ops)
+    )(*prefetch, a, b, *ep_ops)
 
 
 _STATIC = ("schedule", "bm", "bn", "bk", "out_dtype", "use_prefetch",
@@ -423,6 +453,7 @@ def sfc_matmul_pallas(
     bias=None,
     activation: str = "none",
     residual=None,
+    layer=None,
 ):
     """C = act(A @ B + bias) + residual with SFC-ordered tile traversal.
 
@@ -433,12 +464,17 @@ def sfc_matmul_pallas(
     schedule's default).  ``bias`` is (N,), ``residual`` is (M, N); both
     optional -- the epilogue runs on the f32 accumulator inside the
     last-k flush, costing zero extra HBM output traffic (DESIGN.md §9).
+    ``layer`` (an int32 scalar, traced or not): ``b`` is then a stack
+    (L, K, N) of which layer ``layer`` is the operand, read in place --
+    a layer scan hands the kernel its stacked weight, not a copy of the
+    slice.  The index must lie in [0, L): nothing checks it.
     """
-    assert a.ndim == 2 and b.ndim == 2, (a.shape, b.shape)
-    return _sfc_call(a, b, bias, residual, batched=False, schedule=schedule,
-                     bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
-                     use_prefetch=use_prefetch, interpret=interpret, g=g,
-                     activation=activation)
+    assert a.ndim == 2 and b.ndim == (2 if layer is None else 3), (
+        a.shape, b.shape)
+    return _sfc_call(a, b, bias, residual, layer, batched=False,
+                     schedule=schedule, bm=bm, bn=bn, bk=bk,
+                     out_dtype=out_dtype, use_prefetch=use_prefetch,
+                     interpret=interpret, g=g, activation=activation)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -471,16 +507,19 @@ def sfc_matmul_batched_pallas(
     arbitrary leading dims.
     """
     assert a.ndim == 3 and b.ndim == 3, (a.shape, b.shape)
-    return _sfc_call(a, b, bias, residual, batched=True, schedule=schedule,
-                     bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
-                     use_prefetch=use_prefetch, interpret=interpret, g=g,
-                     activation=activation)
+    return _sfc_call(a, b, bias, residual, None, batched=True,
+                     schedule=schedule, bm=bm, bn=bn, bk=bk,
+                     out_dtype=out_dtype, use_prefetch=use_prefetch,
+                     interpret=interpret, g=g, activation=activation)
 
 
-def _gmm_kernel(steps_ref, groups_ref, live_ref, *args, **kwargs):
+def _gmm_kernel(steps_ref, groups_ref, live_ref, *args, stacked: bool,
+                **kwargs):
     """One grid step of the grouped GEMM: the dense kernel's body on the
     step's blocks, or nothing past the live steps."""
     k = pl.program_id(1)
+    if stacked:  # the layer index, read by B's index map only
+        args = args[1:]
 
     @pl.when(pl.program_id(0) < live_ref[0])
     def _live():
@@ -508,11 +547,14 @@ def gmm_steps(schedule: str, mt: int, nt: int, live_tiles):
     "schedule", "bn", "bk", "out_dtype", "interpret", "activation"))
 def sfc_gmm_pallas(a, b, group_tiles, *, schedule: str = "morton",
                    bn: int = 128, bk: int = 128, out_dtype=None,
-                   interpret: bool = False, activation: str = "none"):
+                   interpret: bool = False, activation: str = "none",
+                   layer=None):
     """Grouped GEMM: rows of ``a`` (R, K) sorted by group, group ``e``
     taking ``group_tiles[e]`` whole tiles of ``GROUP_ROWS`` rows, times
     its own ``b[e]`` (K, N) -> (R, N), with ``activation`` on the f32
-    accumulator in the flush.
+    accumulator in the flush.  With ``layer`` (an int32 scalar in [0,
+    L)), ``b`` is a stack (L, G, K, N) whose layer ``layer`` is read in
+    place, the index one more scalar-prefetch operand.
 
     The grid is sized for R, the static bound; each row tile's group
     comes in the scalar prefetch, and the (row tile, column tile) grid
@@ -521,9 +563,11 @@ def sfc_gmm_pallas(a, b, group_tiles, *, schedule: str = "morton",
     step's blocks, so they start no copy.  Rows past the live tiles are
     left as the output buffer had them."""
     r, k = a.shape
-    ng, _, n = b.shape
+    ng, _, n = b.shape[-3:]
     bm = GROUP_ROWS
-    assert r % bm == 0 and b.shape[1] == k, (a.shape, b.shape)
+    stacked = layer is not None
+    assert r % bm == 0 and b.shape[-2] == k and b.ndim == 3 + stacked, (
+        a.shape, b.shape)
     _check_epilogue(None, None, activation, n, (r, n))
     mt, nt, kt = r // bm, -(-n // bn), -(-k // bk)
     out_dtype = out_dtype or a.dtype
@@ -533,32 +577,36 @@ def sfc_gmm_pallas(a, b, group_tiles, *, schedule: str = "morton",
         ng - 1).astype(jnp.int32)
     steps, live = gmm_steps(schedule, mt, nt, ends[-1])
 
+    prefetch = [steps, tile_group, live.reshape(1).astype(jnp.int32)]
+    if stacked:
+        prefetch.append(_layer_operand(layer))
+
     def kk_of(t, kk, live_ref):
         return jnp.where(t < live_ref[0], kk, kt - 1)
 
-    def a_map(t, kk, steps_ref, groups_ref, live_ref):
+    def a_map(t, kk, steps_ref, groups_ref, live_ref, *_):
         return steps_ref[2 * t], kk_of(t, kk, live_ref)
 
-    def b_map(t, kk, steps_ref, groups_ref, live_ref):
-        return (groups_ref[steps_ref[2 * t]], kk_of(t, kk, live_ref),
-                steps_ref[2 * t + 1])
+    def b_map(t, kk, steps_ref, groups_ref, live_ref, *layer_ref):
+        return (*(r[0] for r in layer_ref), groups_ref[steps_ref[2 * t]],
+                kk_of(t, kk, live_ref), steps_ref[2 * t + 1])
 
-    def o_map(t, kk, steps_ref, groups_ref, live_ref):
+    def o_map(t, kk, steps_ref, groups_ref, live_ref, *_):
         return steps_ref[2 * t], steps_ref[2 * t + 1]
 
     need = sfc_vmem_bytes(bm, bn, bk, a.dtype.itemsize,
                           jnp.dtype(out_dtype).itemsize, kt=kt,
                           k_tail=k % bk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(mt * nt, kt),
+        num_scalar_prefetch=len(prefetch), grid=(mt * nt, kt),
         in_specs=[pl.BlockSpec((bm, bk), a_map),
-                  pl.BlockSpec((None, bk, bn), b_map)],
+                  pl.BlockSpec((None,) * (1 + stacked) + (bk, bn), b_map)],
         out_specs=pl.BlockSpec((bm, bn), o_map),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if kt > 1
         else [])
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, kt=kt, k_tail=k % bk,
-                          out_dtype=out_dtype, batched=False,
+        functools.partial(_gmm_kernel, stacked=stacked, kt=kt,
+                          k_tail=k % bk, out_dtype=out_dtype, batched=False,
                           activation=activation),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, n), out_dtype),
@@ -566,4 +614,4 @@ def sfc_gmm_pallas(a, b, group_tiles, *, schedule: str = "morton",
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(need)),
         interpret=interpret,
-    )(steps, tile_group, live.reshape(1).astype(jnp.int32), a, b)
+    )(*prefetch, a, b)

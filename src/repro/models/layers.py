@@ -4,6 +4,12 @@ All GEMMs route through :class:`DotEngine`, the integration point for the
 paper's technique: the engine can execute matmuls through the SFC-scheduled
 Pallas kernel (TPU) or XLA dot (CPU/default).  The engine is *static*
 configuration -- it never enters pytrees.
+
+A layer scan hands its body :func:`layer_params`: each weight matrix as
+a :class:`LayerView` (the stacked array and the layer index), which the
+engine's kernels read in place; a Pallas call needs its operand as a
+buffer of its own, so a plain slice would be copied out on every
+iteration.
 """
 from __future__ import annotations
 
@@ -14,10 +20,47 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-__all__ = ["DotEngine", "rms_norm", "layer_norm", "rope", "apply_rope",
-           "swiglu_mlp", "init_linear", "init_rms", "Param"]
+__all__ = ["DotEngine", "LayerView", "layer_params", "weight", "rms_norm",
+           "layer_norm", "rope", "apply_rope", "swiglu_mlp", "init_linear",
+           "init_rms", "Param"]
 
 Param = Any  # pytree of arrays
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class LayerView:
+    """Layer ``index`` (an int32 scalar, traced in a scan) of ``stack``,
+    an array stacked on a leading layer axis, left unsliced: the SFC
+    kernels read the layer's blocks in place.  ``shape`` is the
+    layer's."""
+    stack: Any
+    index: Any
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+
+def weight(w):
+    """The plain array of ``w``: its layer's slice where it is a
+    :class:`LayerView` (for XLA ops, which fuse the slice)."""
+    return w.stack[w.index] if isinstance(w, LayerView) else w
+
+
+def layer_params(stack, i):
+    """Layer ``i`` of a pytree stacked on a leading layer axis: each
+    leaf of two or more dims per layer (the weights the GEMMs read) as
+    a :class:`LayerView`, each vector (norm gains, biases) its plain
+    slice.  A matrix an XLA op reads (the router, a convolution) goes
+    through :func:`weight`."""
+    return jax.tree.map(
+        lambda w: LayerView(w, i) if w.ndim >= 3 else w[i], stack)
+
+
+def _unview(w):
+    """(stack, layer): a view's stacked array and index, or (w, None)."""
+    return (w.stack, w.index) if isinstance(w, LayerView) else (w, None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,8 +114,10 @@ class DotEngine:
         post-matmul HBM round trips; on the XLA path the identical math
         runs as (library-fusable) elementwise ops.  ``out_dtype`` folds
         a dtype cast into the same single write (the vocab head's
-        f32-logits cast)."""
+        f32-logits cast).  ``w`` may be a :class:`LayerView`, which the
+        kernel reads in place."""
         if self.schedule == "xla":
+            w = weight(w)
             if bias is None and activation == "none" and residual is None:
                 out = jnp.einsum("...d,df->...f", x, w)
                 return out.astype(out_dtype) if out_dtype else out
@@ -91,11 +136,12 @@ class DotEngine:
         res2 = residual.reshape(-1, w.shape[-1]) \
             if residual is not None else None
         bm, bn, bk = self.block or (None, None, None)
+        stack, layer = _unview(w)
         out = sfc_matmul(
-            x2, w, schedule=self.schedule, bm=bm, bn=bn, bk=bk,
+            x2, stack, schedule=self.schedule, bm=bm, bn=bn, bk=bk,
             use_prefetch=self.use_prefetch, interpret=self.interpret,
             objective=self.objective, comm=self.comm, out_dtype=out_dtype,
-            bias=bias, activation=activation, residual=res2,
+            bias=bias, activation=activation, residual=res2, layer=layer,
         )
         return out.reshape(*lead, w.shape[-1])
 
@@ -135,12 +181,14 @@ class DotEngine:
         is the grouped SFC kernel (``sfc_gmm_pallas``), whose blocks
         are derived from the shape (``block`` does not apply); under
         "xla" it is ``lax.ragged_dot``; "auto" raises (the tuner has no
-        grouped GEMM)."""
+        grouped GEMM).  ``w`` may be a :class:`LayerView`, read in
+        place."""
         from repro.kernels.ops import sfc_gmm
 
-        return sfc_gmm(x, w, group_tiles, schedule=self.schedule,
+        stack, layer = _unview(w)
+        return sfc_gmm(x, stack, group_tiles, schedule=self.schedule,
                        interpret=self.interpret, activation=activation,
-                       out_dtype=out_dtype)
+                       out_dtype=out_dtype, layer=layer)
 
 
 def init_linear(key, d_in: int, d_out: int, dtype=jnp.float32, scale=None):

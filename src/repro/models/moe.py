@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .layers import DotEngine, init_linear, init_swiglu, swiglu_mlp
+from .layers import DotEngine, init_linear, init_swiglu, swiglu_mlp, weight
 
 __all__ = ["init_moe", "moe_dropless", "moe_dense", "moe_capacity", "moe_ep",
            "moe_ffn"]
@@ -86,6 +86,7 @@ def init_moe(key, cfg, dtype=jnp.float32, model_axis_size: int | None = None):
 
 def _router(xf, params, cfg):
     """xf: (T, d) -> (weights (T,k), idx (T,k), aux_loss)."""
+    params = dict(params, router=weight(params["router"]))
     if cfg.moe_router == "sigmoid":
         return _sigmoid_router(xf, params, cfg)
     e_real = cfg.moe_experts
@@ -327,6 +328,8 @@ def moe_ffn(x, params, cfg, engine: DotEngine, mesh=None, impl="auto",
             f"{cfg.moe_first_held}..+{cfg.held_experts} of "
             f"{cfg.moe_experts})")
     idx = None
+    if impl != "dropless":  # these read the experts' weights by XLA ops
+        params = {k: weight(v) for k, v in params.items()}
     if impl == "dense":
         y, aux = moe_dense(x, params, cfg, engine)
     elif impl == "capacity":

@@ -16,7 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .layers import DotEngine, init_linear, init_rms, rms_norm
+from .layers import DotEngine, init_linear, init_rms, rms_norm, weight
 
 __all__ = ["init_ssm", "ssd_forward", "ssm_decode", "ssm_state_shape"]
 
@@ -82,7 +82,7 @@ def ssd_forward(x, p, cfg, engine: DotEngine, chunk: int = 128):
 
     proj = engine.dot(x, p["in_proj"])
     z, xbc, dt = _split_proj(proj, cfg)
-    xbc = _causal_conv(xbc, p["conv_w"])
+    xbc = _causal_conv(xbc, weight(p["conv_w"]))
     xs, bs, cs = jnp.split(xbc, [d_inner, d_inner + n], axis=-1)
     xs = xs.reshape(b, s, h, ph)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)

@@ -3,6 +3,10 @@
 Params are a pytree with all per-layer tensors stacked on a leading
 ``n_layers`` axis, consumed by ``jax.lax.scan`` -- compile time is
 depth-independent (essential for 60-layer dry-runs on 512 devices).
+The full-sequence forward scans over the layer index with the stacks
+closed over, and hands each GEMM its weight as a
+:class:`~repro.models.layers.LayerView`, which the SFC kernels read in
+place; the serve scans (prefill, decode) still scan over the stacks.
 """
 from __future__ import annotations
 
@@ -15,8 +19,8 @@ from . import attention as attn_mod
 from . import moe as moe_mod
 from . import ssm as ssm_mod
 from .config import ArchConfig
-from .layers import DotEngine, init_linear, init_rms, init_swiglu, rms_norm, \
-    rope, swiglu_mlp
+from .layers import DotEngine, init_linear, init_rms, init_swiglu, \
+    layer_params, rms_norm, rope, swiglu_mlp
 
 __all__ = ["init_model", "forward", "loss_fn", "init_decode_state",
            "decode_step", "prefill_kv", "prefill_kv_chunk",
@@ -208,9 +212,10 @@ def forward(params, cfg: ArchConfig, batch, engine: DotEngine | None = None,
         else:
             cos = sin = None
 
-    def layer_body(routes):
-        def body(x, lp):
-            return _layer_fwd(x, lp, cfg, engine, cos, sin, mesh, routes)
+    def scan_layers(stack, routes):
+        def body(x, i):
+            return _layer_fwd(x, layer_params(stack, i), cfg, engine, cos,
+                              sin, mesh, routes)
 
         if cfg.remat:
             if cfg.remat_policy == "dots":
@@ -218,14 +223,16 @@ def forward(params, cfg: ArchConfig, batch, engine: DotEngine | None = None,
                 # cuts backward recompute flops and activation traffic
                 policy = \
                     jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                return jax.checkpoint(body, policy=policy)
-            return jax.checkpoint(body)
-        return body
+                body = jax.checkpoint(body, policy=policy)
+            else:
+                body = jax.checkpoint(body)
+        n = jax.tree.leaves(stack)[0].shape[0]
+        return jax.lax.scan(body, x, jnp.arange(n))
 
     with jax.named_scope("layers"):
         if "dense_layers" in params:
-            x, _ = jax.lax.scan(layer_body(False), x, params["dense_layers"])
-        x, auxs = jax.lax.scan(layer_body(routes), x, params["layers"])
+            x, _ = scan_layers(params["dense_layers"], False)
+        x, auxs = scan_layers(params["layers"], routes)
     logits = _head(params, cfg, x, engine)
     logits = constrain(logits, "dp", None, "model")
     if routes:

@@ -22,7 +22,9 @@ show on the chip.  These tests compile for one chip of a *described*
 The topology is described inside a fixture, never at import, so that
 only the test worker that runs this file loads the TPU library.
 """
+import dataclasses
 import os
+import re
 
 import pytest
 
@@ -231,3 +233,85 @@ def test_mla_projections_compile(one_chip, role):
     hlo = jax.jit(lambda a, b: sfc_matmul(a, b, force_pallas=True)).lower(
         spec(MOON_ROWS, k), spec(k, n)).compile().as_text()
     assert CUSTOM_CALL in hlo and "%sfc_matmul_pallas" in hlo
+
+
+# ------------------------------------------- layer weights read in place --
+def _hlo_instructions(hlo: str) -> dict:
+    """{computation: {instruction: (opcode, dims, operand names)}} of a
+    compiled HLO text; dims () for a tuple."""
+    comps: dict = {}
+    cur = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and not line[0].isspace():
+            cur = comps.setdefault(head.group(1), {})
+            continue
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\((.*)",
+                     line)
+        if m and cur is not None:
+            shape = re.match(r"\w+\[([\d,]*)\]", m.group(2))
+            dims = tuple(int(d) for d in shape.group(1).split(",") if d) \
+                if shape else ()
+            cur[m.group(1)] = (m.group(3), dims, re.findall(
+                r"%([\w.\-]+)", m.group(4).split(")", 1)[0]))
+    return comps
+
+
+# the cells' configurations at their full depth (the compile costs what
+# two layers would: one scan body), rows a call, and the custom calls of
+# the two kernels a forward holds: 7 GEMMs a scan body (Moonlight's
+# expert body 7 more on sfc_matmul, 3 grouped) and the head
+IN_PLACE_CELLS = {
+    "qwen3": (CFG, 1, 8, 0),
+    "moonlight": (dataclasses.replace(MOON, moe_held=MOON_HELD), 2, 15, 3),
+}
+
+
+@pytest.mark.parametrize("name", sorted(IN_PLACE_CELLS))
+def test_layer_scan_reads_weights_in_place(one_chip, monkeypatch, name):
+    """The forward through the Morton engine, compiled for the chip:
+    each SFC kernel in a layer loop reads its weight's whole stack as
+    the loop holds it (no copy of the stack, no slice of the layer),
+    and no dynamic slice of a layer's weight is left anywhere.  Kernels
+    outside the loops (the head, Moonlight's one dense layer, its loop
+    of one removed) run once a call and are not held to it."""
+    from repro.kernels import ops
+    from repro.models import DotEngine, forward, init_model
+
+    cfg, rows, n_matmul, n_gmm = IN_PLACE_CELLS[name]
+    monkeypatch.setattr(ops, "default_backend_is_tpu", lambda: True)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: init_model(cfg, k), jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((rows, 2048), jnp.int32,
+                                  sharding=one_chip)
+    engine = DotEngine("morton")
+    hlo = jax.jit(lambda p, t: forward(p, cfg, {"tokens": t}, engine)[0]) \
+        .lower(params, tokens).compile().as_text()
+    comps = _hlo_instructions(hlo)
+    stacks = {s.shape for s in jax.tree.leaves(params["layers"])
+              if s.ndim >= 3}
+    found = {k: [(c, n) for c, ins in comps.items() for n, (op, _, _)
+                 in ins.items() if op == "custom-call"
+                 and n.startswith(k + ".")]
+             for k in ("sfc_matmul_pallas", "sfc_gmm_pallas")}
+    assert (len(found["sfc_matmul_pallas"]),
+            len(found["sfc_gmm_pallas"])) == (n_matmul, n_gmm)
+    bodies = set(re.findall(r"body=%([\w.\-]+)", hlo))
+    in_loops = [(c, n) for calls in found.values() for c, n in calls
+                if c in bodies]
+    assert len(in_loops) == 7 + 3 * (n_gmm > 0)
+    layer_dims = set()       # the layer shapes of the kernels' weights
+    for c, n in in_loops:
+        ins = comps[c]
+        weights = [o for o in ins[n][2] if ins.get(o, ("", ()))[1] in stacks]
+        assert len(weights) == 1, (n, ins[n])
+        assert ins[weights[0]][0] in ("get-tuple-element", "parameter"), (
+            n, weights[0], ins[weights[0]])
+        layer_dims.add(ins[weights[0]][1][1:])
+    slices = [(n, dims) for ins in comps.values()
+              for n, (op, dims, _) in ins.items()
+              if (op == "dynamic-slice" or n.startswith("dynamic-slice"))
+              and dims[-2:] and (dims in layer_dims
+                                 or dims[1:] in layer_dims)]
+    assert not slices, slices

@@ -199,3 +199,42 @@ def test_peano_kernel_matches_ref():
                      interpret=True, force_pallas=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(matmul_ref(a, b)),
                                rtol=1e-5, atol=1e-5)
+
+
+# an epilogue per case of the stacked-weight test, and one with the
+# walk decoded in the index map (no walk table in the scalar prefetch)
+STACKED = {
+    "none": {},
+    "silu": {"activation": "silu"},
+    "residual": {"residual": True},
+    "f32_out": {"out_dtype": jnp.float32},
+    "closed_form_walk": {"use_prefetch": False, "schedule": "rowmajor"},
+}
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("case", sorted(STACKED))
+def test_stacked_weight_reads_its_layer_in_place(case, layer):
+    """The kernel on a (L, K, N) stack and a traced layer index equals
+    the kernel on that layer's slice, bit for bit; so do the wrapper's
+    kernel path and its XLA fallback.  K 200 over blocks of 128 takes
+    two k steps, the last masked; N 300 a cropped last block."""
+    kw = dict(STACKED[case])
+    res = _rand((48, 300), jnp.bfloat16, 7) if kw.pop("residual", False) \
+        else None
+    a = _rand((48, 200), jnp.bfloat16, 5)
+    w = _rand((5, 200, 300), jnp.bfloat16, 6)
+    blocks = dict(bm=16, bn=128, bk=128)
+
+    def kernel(b, i=None):
+        return sfc_matmul_pallas(a, b, interpret=True, residual=res,
+                                 layer=i, **blocks, **kw)
+
+    got = jax.jit(kernel)(w, jnp.int32(layer))
+    assert np.array_equal(np.asarray(got), np.asarray(kernel(w[layer])))
+    ep = {k: v for k, v in kw.items() if k in ("activation", "out_dtype")}
+    for path in (dict(interpret=True, **blocks), {}):   # kernel, fallback
+        got = jax.jit(lambda b, i: sfc_matmul(a, b, layer=i, residual=res,
+                                              **ep, **path))(w, layer)
+        want = sfc_matmul(a, w[layer], residual=res, **ep, **path)
+        assert np.array_equal(np.asarray(got), np.asarray(want)), path

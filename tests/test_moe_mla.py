@@ -213,6 +213,30 @@ def test_grouped_kernel_matches_a_ragged_matmul(schedule, bn, bk):
         _plain_grouped(a, b, tiles), rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_grouped_stacked_weight_reads_its_layer_in_place(layer):
+    """The grouped kernel on a (L, G, K, N) stack and a traced layer
+    index equals the kernel on that layer's (G, K, N) slice, bit for
+    bit, with groups left empty; so do the wrapper's kernel path and its
+    XLA fallback (``lax.ragged_dot`` on the slice)."""
+    tiles = jnp.array([1, 0, 2, 0], jnp.int32)
+    a = _rand(0, 5 * GROUP_ROWS, 200)
+    w = _rand(1, 3, 4, 200, 300)
+    live = int(tiles.sum()) * GROUP_ROWS
+
+    def kernel(b, i=None):
+        return sfc_gmm_pallas(a, b, tiles, bn=128, bk=128, interpret=True,
+                              activation="silu", layer=i)
+
+    got = jax.jit(kernel)(w, jnp.int32(layer))
+    np.testing.assert_array_equal(got[:live], kernel(w[layer])[:live])
+    for path in ({"interpret": True}, {}):      # the kernel, the fallback
+        got = jax.jit(lambda b, i: sfc_gmm(a, b, tiles, layer=i, **path))(
+            w, layer)
+        want = sfc_gmm(a, w[layer], tiles, **path)
+        np.testing.assert_array_equal(got[:live], want[:live])
+
+
 @pytest.mark.parametrize("live_tiles", [0, 3, 8])
 def test_grouped_walk_repeats_the_last_live_step(live_tiles):
     """The walk visits every live (row tile, column tile) once, in the
@@ -361,17 +385,18 @@ def test_paths_that_need_a_latent_cache_refuse_mla(what):
 
 
 # the sha256 of each dense SMOKE config's forward as StableHLO, without
-# debug information, as the program built it before the expert and
-# latent-attention paths came in: those paths leave it as it was
+# debug information, as the program builds it with the layer scan over
+# the layer index (each GEMM reading its weight in place from the
+# stack): the expert and latent-attention paths leave it as it is
 DENSE_PROGRAMS = {
     "qwen3_1_7b": {
-        "xla": "edaddeee9a5bbb759f185d910ee4b261357d58ff477fa2e3ba346e94f8487d2e",
+        "xla": "9fc6e842f1aa12e44a8e8edea945805071251e967b750677aaf16e282193755b",
         "morton":
-            "a8e0a92566b76dfe7194d437785502089a7a65c0f9abed5c8f7c24b5ea1c17c7"},
+            "63ead8fc2f4fb16d917cdca541533901860aff74dff704486e4dd76eb16106ff"},
     "glm4_9b": {
-        "xla": "74f644fef1b96d66b4b37933d67563ee082e7b1129ea9712e3ecb488f095d1cf",
+        "xla": "88d6c38b2a4c24f0da142ce50e68610877e2af23e3fd014f22eb23931fa11479",
         "morton":
-            "ce713c967ec93e5b941ea68a10b0554cbd471a5d92ce2404700e7402a35cf001"},
+            "56d8945ba5c330ee06d529f20599af4bc35f30bb98f3efef5368776bd75682b5"},
 }
 
 

@@ -220,3 +220,31 @@ def test_engine_default_derives_blocks():
     np.testing.assert_allclose(np.asarray(eng.dot(x, w)),
                                np.asarray(jnp.einsum("bsd,df->bsf", x, w)),
                                rtol=1e-4, atol=1e-4)
+
+
+# GEMMs a forward's layer scans read in place: a dense layer's q, k, v,
+# o, gate, up, down; an expert layer's MLA q, kv_a, kv_b, o, shared
+# gate, up, down and expert gate, up, down, beside Moonlight's one
+# leading dense layer (MLA and a SwiGLU)
+IN_PLACE = {"qwen3_1_7b": 7, "moonlight_16b_a3b": 7 + 10}
+
+
+@pytest.mark.parametrize("schedule", ["morton", "xla"])
+@pytest.mark.parametrize("arch", sorted(IN_PLACE))
+def test_forward_counts_each_weight_read_in_place(arch, schedule):
+    """One traced forward raises ``sfc.weights_in_place`` by the GEMMs
+    of its scan bodies on the kernels, k and v apart though they share
+    a shape; the XLA engine reads none in place."""
+    from repro.configs import get_smoke_config
+    from repro.models import DotEngine, forward, init_model
+
+    cfg = get_smoke_config(arch)
+    params = jax.eval_shape(lambda k: init_model(cfg, k), jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    engine = DotEngine(schedule, interpret=True)
+    count = default_registry().counter("sfc.weights_in_place")
+    before = count.value
+    jax.jit(lambda p, t: forward(p, cfg, {"tokens": t}, engine)).lower(
+        params, tokens)
+    want = IN_PLACE[arch] if schedule == "morton" else 0
+    assert count.value - before == want
