@@ -17,6 +17,7 @@ from repro.core.curves import hilbert_encode_py, morton_encode_py
 from repro.core.energy import energy_joules
 from repro.kernels.ops import sfc_matmul
 from repro.kernels.ref import matmul_ref
+from repro.models.layers import DotEngine
 from repro.power import EnergyMeter, detect_backend
 
 print("=" * 64)
@@ -65,7 +66,8 @@ print("5. Energy telemetry: meter a region, tune for energy-delay product")
 backend = detect_backend()  # RAPL > NVML > analytic model
 with EnergyMeter("quickstart-gemm", backend=backend,
                  flops=2.0 * 128 ** 3) as em:
-    sfc_matmul(a, b, schedule="auto", objective="edp").block_until_ready()
+    DotEngine(schedule="auto", objective="edp").dot(a, b) \
+        .block_until_ready()
 r = em.reading
 print(f"  backend={r.backend}  {r.seconds*1e3:.2f} ms  "
       f"{r.joules:.4f} J  EDP={r.edp:.3e} J*s")

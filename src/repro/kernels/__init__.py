@@ -3,21 +3,16 @@
 # variant (sfc_matmul_cached.py), paged decode attention over a
 # block-table-gathered KV pool (paged_attention.py), jit wrappers
 # (ops.py), oracles (ref.py).
-from .ops import sfc_matmul, sfc_matmul_batched  # noqa: F401
+from .ops import sfc_matmul  # noqa: F401
 from .paged_attention import (  # noqa: F401
     paged_decode_attention,
     paged_decode_attention_pallas,
 )
 from .ref import (  # noqa: F401
     apply_epilogue_ref,
-    matmul_batched_fused_ref,
-    matmul_batched_ref,
     matmul_fused_ref,
     matmul_ref,
     paged_decode_attention_ref,
 )
-from .sfc_matmul import (  # noqa: F401
-    sfc_matmul_batched_pallas,
-    sfc_matmul_pallas,
-)
+from .sfc_matmul import sfc_matmul_pallas  # noqa: F401
 from .sfc_matmul_cached import sfc_matmul_cached  # noqa: F401

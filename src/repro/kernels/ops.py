@@ -1,45 +1,40 @@
 """Public jit'd wrappers around the SFC matmul kernels.
 
-``sfc_matmul`` is the framework-wide GEMM entry point: every model matmul
-can be routed through it (see ``repro.models.layers.DotEngine``).  On
-non-TPU backends it falls back to XLA dot by default (the Pallas kernel is
-TPU-targeted; ``interpret=True`` runs it on CPU for tests).
+Two entry points: ``sfc_matmul``, the framework-wide GEMM (every model
+matmul can be routed through it, see ``repro.models.layers.DotEngine``),
+and ``sfc_gmm``, the grouped GEMM of a routed expert layer.  On non-TPU
+backends they fall back to XLA by default (the Pallas kernels are
+TPU-targeted; ``interpret=True`` runs them on CPU for tests).
 
-Both entry points carry the **fused epilogue** (DESIGN.md §9): optional
-``bias=`` / ``activation=`` / ``residual=`` are applied to the kernel's
-f32 accumulator inside the last-k flush -- one cast, one HBM write, no
-post-matmul elementwise passes.  The XLA fallback reproduces the exact
-same math (``repro.kernels.ref.matmul_fused_ref``), so callers never
-branch on backend.
+``sfc_matmul`` carries the **fused epilogue** (DESIGN.md §9; ``sfc_gmm``
+its activation alone): optional ``bias=`` / ``activation=`` /
+``residual=`` are applied to the kernel's f32 accumulator inside the
+last-k flush -- one cast, one HBM write, no post-matmul elementwise
+passes.  The XLA fallback reproduces the exact same math
+(``repro.kernels.ref.matmul_fused_ref``), so callers never branch on
+backend.
 
-``schedule="auto"`` consults the autotuner (``repro.tune``, DESIGN.md §6):
-the (shape-bucket, dtype, backend, epilogue) winner comes from the
-on-disk cache when present, otherwise from the analytic cost model (plus
-wall-time adjudication on real TPU hardware).  The epilogue is part of
-the tuning key because fusion changes the traffic the candidate
-generates -- and therefore which block sizes win.  Resolution uses only
-static shape / dtype information, so it is safe at trace time.
+Both take a concrete schedule and refuse ``schedule="auto"``: the
+autotuner (the ``tune`` package, DESIGN.md §6) sits above this layer,
+and ``DotEngine`` resolves ``"auto"`` to a winner's schedule, blocks,
+prefetch and supertile factor (``tune.resolve``) before it calls
+``sfc_matmul``.  The tuner imports this module to time its candidates;
+nothing here imports the tuner.
 
-``sfc_matmul_batched`` is the einsum-style ``bij,bjk->bik`` entry: any
-number of leading batch dims, executed by a 3-D-grid Pallas kernel with
-the SFC schedule on the (i, j) tile plane (or by ``vmap`` over the 2-D
-kernel with ``via_vmap=True``).
-
-``sfc_gmm`` is the grouped GEMM of a routed expert layer: rows sorted
-by expert, each expert's rows padded to whole tiles of
-:data:`~repro.kernels.sfc_matmul.GROUP_ROWS`, each times its expert's
-weight, in one kernel (``sfc_gmm_pallas``) whose grid is sized for the
-static bound on rows.  Each GEMM traced onto it adds its grid steps and
-its row bound to the ``sfc_gmm.grid_steps`` / ``sfc_gmm.row_bound``
-counters.
+``sfc_gmm``: rows sorted by expert, each expert's rows padded to whole
+tiles of :data:`~repro.kernels.sfc_matmul.GROUP_ROWS`, each times its
+expert's weight, in one kernel (``sfc_gmm_pallas``) whose grid is sized
+for the static bound on rows.  Each GEMM traced onto it adds its grid
+steps and its row bound to the ``sfc_gmm.grid_steps`` /
+``sfc_gmm.row_bound`` counters.
 
 ``use_prefetch`` defaults to ``True`` across the whole stack (kernels,
 wrappers, engine): the scalar-prefetch schedule table works on any grid
 and amortises index cost to zero.  ``False`` (the paper-faithful
 in-``index_map`` decode) is an explicit opt-in everywhere.
 
-Blocks: a block the caller names (``bm``/``bn``/``bk``, all three, or
-the tuner's under ``schedule="auto"``) is run as named; a block left
+Blocks: a block the caller names (``bm``/``bn``/``bk``, all three, as
+the engine does with a tuner's winner) is run as named; a block left
 ``None`` is derived from the GEMM's shape, dtypes and epilogue
 (:func:`repro.kernels.sfc_matmul.sfc_blocks`).  Either way the operands
 go to the kernel as they are: a last block may overhang M, N or K, and
@@ -69,13 +64,11 @@ import jax.numpy as jnp
 
 from repro.obs.metrics import default_registry
 
-from .ref import apply_epilogue_ref, matmul_batched_fused_ref, \
-    matmul_fused_ref
+from .ref import apply_epilogue_ref, matmul_fused_ref
 from .sfc_matmul import GROUP_ROWS, grid_step_counts, sfc_blocks, \
-    sfc_gmm_pallas, sfc_matmul_batched_pallas, sfc_matmul_pallas
+    sfc_gmm_pallas, sfc_matmul_pallas
 
-__all__ = ["sfc_matmul", "sfc_matmul_batched", "sfc_gmm",
-           "default_backend_is_tpu"]
+__all__ = ["sfc_matmul", "sfc_gmm", "default_backend_is_tpu"]
 
 
 def default_backend_is_tpu() -> bool:
@@ -102,16 +95,26 @@ def _resolve_blocks(m, n, k, a_dtype, out_dtype, bm, bn, bk, *,
     return bm, bn, bk
 
 
-def _count_grid(schedule: str, m, n, k, bm, bn, bk, g, batch=1) -> None:
+def _count_grid(schedule: str, m, n, k, bm, bn, bk, g) -> None:
     """Add one traced kernel GEMM to the counters (called from the
     jitted wrappers' bodies, which run only while tracing)."""
     reg = default_registry()
     if not reg.enabled:
         return
     steps, elided = grid_step_counts(schedule, -(-m // bm), -(-n // bn),
-                                     -(-k // bk), g, batch)
+                                     -(-k // bk), g)
     reg.counter("sfc.grid_steps").inc(steps)
     reg.counter("sfc.copies_elided").inc(elided)
+
+
+def _refuse_auto(schedule: str) -> None:
+    """The kernels run a named schedule; ``"auto"`` is the engine's to
+    resolve (without this check it would fall to XLA's dot off a TPU)."""
+    if schedule == "auto":
+        raise ValueError(
+            'schedule="auto" is resolved by DotEngine (repro.models.layers) '
+            "through tune.resolve; the kernel wrappers take a concrete "
+            "schedule")
 
 
 def _count_in_place(layer, schedule: str, interpret, force_pallas: bool
@@ -122,36 +125,6 @@ def _count_in_place(layer, schedule: str, interpret, force_pallas: bool
     if reg.enabled and layer is not None and _uses_kernel(
             schedule, interpret, force_pallas):
         reg.counter("sfc.weights_in_place").inc()
-
-
-def _resolve_auto(m: int, n: int, k: int, dtype, batched: bool = False,
-                  objective: str = "time", has_bias: bool = False,
-                  activation: str = "none", has_residual: bool = False,
-                  comm=None):
-    """Map schedule="auto" to a concrete (schedule, blocks, prefetch, g).
-
-    The epilogue shape (bias / activation / residual presence) keys the
-    tuner: a fused epilogue removes whole HBM passes from the traffic
-    model, which moves the block-size optimum (DESIGN.md §9).  ``comm``
-    (a :class:`repro.tune.CommSpec` or None) adds the mesh's collective
-    term to the scoring and keys the winner under the mesh keyspace
-    (DESIGN.md §15).
-
-    The winner's DVFS dimension (``TuneConfig.f_scale``) is stripped
-    here: it parameterises the tuner's scoring and the launch layer's
-    energy accounting (``repro.tune.resolved_f_scale``), never the
-    kernel launch -- userspace cannot set the device clock.
-
-    Imported lazily: the tuner depends on this module for measurement."""
-    from repro.tune import resolve_config
-    from repro.tune.cost import EpilogueSpec
-
-    ep = EpilogueSpec(bias=has_bias, activation=activation,
-                      residual=has_residual)
-    cfg = resolve_config(int(m), int(n), int(k), jnp.dtype(dtype).name,
-                         batched=batched, objective=objective,
-                         epilogue=None if ep.is_noop else ep, comm=comm)
-    return cfg.schedule, cfg.bm, cfg.bn, cfg.bk, cfg.use_prefetch, cfg.g
 
 
 @functools.partial(
@@ -211,8 +184,6 @@ def sfc_matmul(
     interpret: bool | None = None,
     force_pallas: bool = False,
     g: int = 0,
-    objective: str = "time",
-    comm=None,
     bias=None,
     activation: str = "none",
     residual=None,
@@ -228,10 +199,8 @@ def sfc_matmul(
       ``residual`` (M, N) form the fused epilogue: applied to the f32
       accumulator in the kernel's flush step, they cost zero extra HBM
       output traffic (DESIGN.md §9);
-    * ``schedule="auto"`` resolves (schedule, block sizes, prefetch)
-      through the autotuner's cache/cost model for this (shape bucket,
-      epilogue), adjudicated under ``objective`` ("time", "energy" or
-      "edp" -- DESIGN.md §8; ignored for explicit schedules);
+    * ``schedule="auto"`` raises ``ValueError``: ``DotEngine`` resolves
+      it through the tuner and calls this with the winner;
     * ``schedule="xla"`` or a non-TPU backend (unless ``force_pallas``)
       uses the native XLA dot -- the "tuned library" baseline (ATLAS
       analogue in the paper's comparison) -- with the same epilogue math;
@@ -243,12 +212,7 @@ def sfc_matmul(
       GEMM's weight its layer ``layer``, which the kernel reads in place
       (the index in its scalar prefetch) and the XLA path slices.
     """
-    if schedule == "auto":
-        schedule, bm, bn, bk, use_prefetch, g = _resolve_auto(
-            a.shape[0], b.shape[-1], a.shape[1], a.dtype,
-            objective=objective, has_bias=bias is not None,
-            activation=activation, has_residual=residual is not None,
-            comm=comm)
+    _refuse_auto(schedule)
     bm, bn, bk = _resolve_blocks(
         a.shape[0], b.shape[-1], a.shape[1], a.dtype, out_dtype, bm, bn, bk,
         has_bias=bias is not None, has_residual=residual is not None)
@@ -258,121 +222,6 @@ def sfc_matmul(
         out_dtype=out_dtype, use_prefetch=use_prefetch, interpret=interpret,
         force_pallas=force_pallas, g=g,
         bias=bias, activation=activation, residual=residual, layer=layer)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("schedule", "bm", "bn", "bk", "out_dtype",
-                     "use_prefetch", "interpret", "force_pallas",
-                     "via_vmap", "g", "activation"),
-)
-def _sfc_matmul_batched(
-    a,
-    b,
-    *,
-    schedule: str,
-    bm: int,
-    bn: int,
-    bk: int,
-    out_dtype,
-    use_prefetch: bool,
-    interpret: bool | None,
-    force_pallas: bool,
-    via_vmap: bool,
-    g: int,
-    bias=None,
-    activation: str = "none",
-    residual=None,
-):
-    out_dtype = out_dtype or a.dtype
-
-    if not _uses_kernel(schedule, interpret, force_pallas):
-        return matmul_batched_fused_ref(
-            a, b, bias=bias, activation=activation, residual=residual,
-            out_dtype=out_dtype)
-
-    # flatten leading dims only on the kernel path: the XLA fallback above
-    # consumes the original arrays (no dead reshapes on the fallback)
-    lead = a.shape[:-2]
-    m, k = a.shape[-2:]
-    n = b.shape[-1]
-    a3 = a.reshape((-1, m, k))
-    b3 = b.reshape((-1, k, n))
-    res3 = residual.reshape((-1, m, n)) if residual is not None else None
-    _count_grid(schedule, m, n, k, bm, bn, bk, g, batch=a3.shape[0])
-
-    if via_vmap:
-        out = jax.vmap(
-            lambda x, y, r: sfc_matmul_pallas(
-                x, y, schedule=schedule, bm=bm, bn=bn, bk=bk,
-                out_dtype=out_dtype, use_prefetch=use_prefetch,
-                interpret=bool(interpret), g=g,
-                bias=bias, activation=activation, residual=r),
-            in_axes=(0, 0, 0 if res3 is not None else None),
-        )(a3, b3, res3)
-    else:
-        out = sfc_matmul_batched_pallas(
-            a3, b3, schedule=schedule, bm=bm, bn=bn, bk=bk,
-            out_dtype=out_dtype, use_prefetch=use_prefetch,
-            interpret=bool(interpret), g=g,
-            bias=bias, activation=activation, residual=res3)
-    return out.reshape(lead + (m, n))
-
-
-def sfc_matmul_batched(
-    a,
-    b,
-    *,
-    schedule: str = "morton",
-    bm: int | None = None,
-    bn: int | None = None,
-    bk: int | None = None,
-    out_dtype=None,
-    use_prefetch: bool = True,
-    interpret: bool | None = None,
-    force_pallas: bool = False,
-    via_vmap: bool = False,
-    g: int = 0,
-    objective: str = "time",
-    comm=None,
-    bias=None,
-    activation: str = "none",
-    residual=None,
-):
-    """Einsum ``bij,bjk->bik`` with SFC tile traversal per batch element.
-
-    ``a``: (..., M, K) and ``b``: (..., K, N) with identical leading
-    dims; leading dims are flattened into one batch axis for the 3-D-grid
-    kernel and restored on return.  ``bias`` (N,) is shared across batch
-    elements; ``residual`` matches the (..., M, N) output -- both fused
-    into the kernel flush (DESIGN.md §9).  Blocks as in
-    :func:`sfc_matmul`, derived from the per-element GEMM's shape where
-    left ``None``.  ``schedule="auto"`` consults
-    the autotuner (keyed on the per-element GEMM shape + epilogue,
-    adjudicated under ``objective``).  ``via_vmap=True`` runs the 2-D
-    kernel under ``jax.vmap`` instead of the 3-D grid -- the two must
-    agree (tested), and vmap is the fallback for callers that are
-    themselves inside a ``vmap``.
-    """
-    assert a.shape[:-2] == b.shape[:-2], (a.shape, b.shape)
-    assert a.shape[-1] == b.shape[-2], (a.shape, b.shape)
-    if residual is not None:
-        assert residual.shape == a.shape[:-1] + (b.shape[-1],), (
-            residual.shape, a.shape, b.shape)
-    if schedule == "auto":
-        schedule, bm, bn, bk, use_prefetch, g = _resolve_auto(
-            a.shape[-2], b.shape[-1], a.shape[-1], a.dtype, batched=True,
-            objective=objective, has_bias=bias is not None,
-            activation=activation, has_residual=residual is not None,
-            comm=comm)
-    bm, bn, bk = _resolve_blocks(
-        a.shape[-2], b.shape[-1], a.shape[-1], a.dtype, out_dtype, bm, bn,
-        bk, has_bias=bias is not None, has_residual=residual is not None)
-    return _sfc_matmul_batched(
-        a, b, schedule=schedule, bm=bm, bn=bn, bk=bk,
-        out_dtype=out_dtype, use_prefetch=use_prefetch, interpret=interpret,
-        force_pallas=force_pallas, via_vmap=via_vmap, g=g,
-        bias=bias, activation=activation, residual=residual)
 
 
 def gmm_ref(a, b, group_tiles, *, activation: str = "none", out_dtype=None):
@@ -396,9 +245,9 @@ def sfc_gmm(a, b, group_tiles, *, schedule: str = "morton",
     K, N) whose layer ``layer`` the kernel reads in place.  Off the
     kernel path (the XLA baseline, or a CPU without
     ``interpret``/``force_pallas``) the same math runs as
-    ``lax.ragged_dot``, on the layer's slice."""
-    if schedule == "auto":
-        raise ValueError("the tuner has no grouped GEMM: name a schedule")
+    ``lax.ragged_dot``, on the layer's slice.  The tuner has no grouped
+    GEMM, so ``schedule="auto"`` raises as in :func:`sfc_matmul`."""
+    _refuse_auto(schedule)
     _count_in_place(layer, schedule, interpret, force_pallas)
     return _sfc_gmm(a, b, group_tiles, layer, schedule=schedule,
                     out_dtype=out_dtype, interpret=interpret,

@@ -7,9 +7,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["matmul_ref", "matmul_batched_ref", "matmul_blocked_ref",
-           "ACTIVATIONS", "apply_activation", "apply_epilogue_ref",
-           "matmul_fused_ref", "matmul_batched_fused_ref",
+__all__ = ["matmul_ref", "matmul_blocked_ref", "ACTIVATIONS",
+           "apply_activation", "apply_epilogue_ref", "matmul_fused_ref",
            "paged_decode_attention_ref"]
 
 # epilogue activations the fused kernels support (DESIGN.md §9)
@@ -65,24 +64,6 @@ def matmul_fused_ref(a, b, bias=None, activation: str = "none",
     and the XLA fallback executed on non-TPU backends."""
     out_dtype = out_dtype or a.dtype
     acc = jnp.dot(a, b, preferred_element_type=jnp.float32)
-    return apply_epilogue_ref(acc, bias, activation, residual, out_dtype)
-
-
-def matmul_batched_ref(a, b, out_dtype=None):
-    """f32-accumulated batched matmul (``bij,bjk->bik`` over any leading
-    dims), the semantics ``sfc_matmul_batched`` must match."""
-    out_dtype = out_dtype or a.dtype
-    return jnp.matmul(
-        a, b, preferred_element_type=jnp.float32
-    ).astype(out_dtype)
-
-
-def matmul_batched_fused_ref(a, b, bias=None, activation: str = "none",
-                             residual=None, out_dtype=None):
-    """Batched ``matmul_fused_ref``; bias (N,) broadcasts over all leading
-    dims, residual matches the (..., M, N) output shape."""
-    out_dtype = out_dtype or a.dtype
-    acc = jnp.matmul(a, b, preferred_element_type=jnp.float32)
     return apply_epilogue_ref(acc, bias, activation, residual, out_dtype)
 
 

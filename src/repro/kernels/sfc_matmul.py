@@ -45,8 +45,8 @@ K inside the kernel before its product, so the overhang adds nothing.
 Each call raises the compiler's scoped VMEM limit to its double-buffered
 working set (:func:`sfc_vmem_bytes`) where that exceeds the default.
 
-Both the 2-D kernel and the grouped one take a weight stacked on a
-leading layer axis with a ``layer`` index: the index rides the scalar
+Both kernels, the dense one and the grouped one, take a weight stacked
+on a leading layer axis with a ``layer`` index: the index rides the scalar
 prefetch and B's index map puts it first, so a layer scan's GEMM reads
 its layer's blocks in place.  A slice ``w[i]`` handed to a Pallas call
 is a buffer of its own, which XLA writes out on every iteration; the
@@ -79,9 +79,8 @@ from repro.core.schedule import grid_schedule, is_pow2, \
     schedule_extra_kwargs
 from repro.kernels.ref import ACTIVATIONS, apply_activation
 
-__all__ = ["sfc_matmul_pallas", "sfc_matmul_batched_pallas", "sfc_gmm_pallas",
-           "decode_step", "sfc_blocks", "sfc_vmem_bytes", "grid_step_counts",
-           "GROUP_ROWS"]
+__all__ = ["sfc_matmul_pallas", "sfc_gmm_pallas", "decode_step", "sfc_blocks",
+           "sfc_vmem_bytes", "grid_step_counts", "GROUP_ROWS"]
 
 # the compiler's default scoped VMEM limit on a v5e; a kernel whose
 # working set is larger sets ``vmem_limit_bytes`` itself
@@ -200,41 +199,40 @@ def sfc_blocks(m: int, n: int, k: int, in_bytes: int, out_bytes: int, *,
         kt += 1
 
 
-def grid_step_counts(schedule: str, mt: int, nt: int, kt: int, g: int = 0,
-                     batch: int = 1) -> tuple[int, int]:
+def grid_step_counts(schedule: str, mt: int, nt: int, kt: int, g: int = 0
+                     ) -> tuple[int, int]:
     """(grid steps, steps that skip a copy) of one kernel call.
 
     A step skips a copy where its A or its B block index equals the
     previous step's (Pallas then keeps the resident block).  With k
-    innermost that needs kt = 1; a new batch element changes both."""
-    steps = batch * mt * nt * kt
+    innermost that needs kt = 1."""
+    steps = mt * nt * kt
     if kt > 1:
         return steps, 0
     order = grid_schedule(schedule, mt, nt,
                           **schedule_extra_kwargs(schedule, g))
     same = (order[1:] == order[:-1]).any(axis=1)
-    return steps, batch * int(same.sum())
+    return steps, int(same.sum())
 
 
-def _fused_flush(acc, bias_ref, res_ref, activation: str, out_dtype, ld):
+def _fused_flush(acc, bias_ref, res_ref, activation: str, out_dtype):
     """The epilogue applied to the f32 accumulator at the last k step:
     out = act(acc + bias) + residual, then a single cast.  Bias blocks
     are (1, bn) VMEM tiles broadcast over the (bm, bn) accumulator."""
     if bias_ref is not None:
-        acc = acc + ld(bias_ref).astype(jnp.float32)
+        acc = acc + bias_ref[...].astype(jnp.float32)
     acc = apply_activation(acc, activation)
     if res_ref is not None:
-        acc = acc + ld(res_ref).astype(jnp.float32)
+        acc = acc + res_ref[...].astype(jnp.float32)
     return acc.astype(out_dtype)
 
 
 def _mm_kernel(a_ref, b_ref, *rest, kt: int, k_tail: int, out_dtype,
-               batched: bool, activation: str = "none",
-               has_bias: bool = False, has_residual: bool = False, k=None):
-    """One grid step.  ``batched`` kernels see (1, ...) blocks and the k
-    index on grid dim 2 (or as ``k``, where the caller read it);
-    ``k_tail`` is the width of the last k block that lies inside K (0:
-    it lies inside whole)."""
+               activation: str = "none", has_bias: bool = False,
+               has_residual: bool = False, k=None):
+    """One grid step.  The k index is grid dim 1 (or ``k``, where the
+    caller read it); ``k_tail`` is the width of the last k block that
+    lies inside K (0: it lies inside whole)."""
     # rest: [bias_ref], [residual_ref], o_ref, [acc_ref] (inputs before
     # outputs before scratch -- pallas_call calling convention)
     rest = list(rest)
@@ -243,18 +241,12 @@ def _mm_kernel(a_ref, b_ref, *rest, kt: int, k_tail: int, out_dtype,
     bias_ref = rest[0] if has_bias else None
     res_ref = rest[-1] if has_residual else None
 
-    def ld(ref):
-        return ref[0] if batched else ref[...]
-
     def flush(acc):
-        out = _fused_flush(acc, bias_ref, res_ref, activation, out_dtype, ld)
-        if batched:
-            o_ref[0] = out
-        else:
-            o_ref[...] = out
+        o_ref[...] = _fused_flush(acc, bias_ref, res_ref, activation,
+                                  out_dtype)
 
     def product(masked: bool):
-        a, b = ld(a_ref), ld(b_ref)
+        a, b = a_ref[...], b_ref[...]
         if masked:
             # the block overhangs K: what lies past it is undefined
             a = jnp.where(lax.broadcasted_iota(jnp.int32, a.shape, 1)
@@ -267,7 +259,7 @@ def _mm_kernel(a_ref, b_ref, *rest, kt: int, k_tail: int, out_dtype,
         flush(product(bool(k_tail)))
         return
     if k is None:
-        k = pl.program_id(2 if batched else 1)
+        k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _zero():
@@ -315,122 +307,9 @@ def _check_epilogue(bias, residual, activation, n, out_shape):
         assert residual.shape == out_shape, (residual.shape, out_shape)
 
 
-def _epilogue_operands(bias, residual, bias_shape, bias_spec, res_spec):
-    """The (in_specs, operands) tail for the optional epilogue inputs.
-
-    Shared by both kernels and both index strategies; the (bias,
-    residual) order here must match the kernels' positional ``rest``
-    parsing."""
-    specs, ops = [], []
-    if bias is not None:
-        specs.append(bias_spec)
-        ops.append(bias.reshape(bias_shape))
-    if residual is not None:
-        specs.append(res_spec)
-        ops.append(residual)
-    return specs, ops
-
-
 def _layer_operand(layer):
     """A layer index as the (1,) int32 scalar-prefetch operand."""
     return jnp.reshape(layer, (1,)).astype(jnp.int32)
-
-
-def _sfc_call(a, b, bias, residual, layer, *, batched: bool, schedule: str,
-              bm: int, bn: int, bk: int, out_dtype, use_prefetch: bool,
-              interpret: bool, g: int, activation: str):
-    """The pallas_call of both kernels: grid (T, kt), or (batch, T, kt)
-    with the curve on every batch element's (i, j) tile plane.  Where
-    ``layer`` is given (2-D kernel only), ``b`` is a stack (L, K, N) and
-    its layer ``layer`` is read in place: the index rides the scalar
-    prefetch and B's index map puts it first."""
-    m, k = a.shape[-2:]
-    n = b.shape[-1]
-    stacked = layer is not None
-    assert not (batched and stacked)
-    assert b.shape[-2] == k and a.shape[:-2] == b.shape[:b.ndim - 2
-                                                        - stacked], (
-        a.shape, b.shape)
-    lead = a.shape[:-2]
-    _check_epilogue(bias, residual, activation, n, lead + (m, n))
-    mt, nt, kt = -(-m // bm), -(-n // bn), -(-k // bk)
-    out_dtype = out_dtype or a.dtype
-    kern_kw = dict(kt=kt, k_tail=k % bk, out_dtype=out_dtype,
-                   batched=batched, activation=activation,
-                   has_bias=bias is not None,
-                   has_residual=residual is not None)
-    out_shape = jax.ShapeDtypeStruct(lead + (m, n), out_dtype)
-    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if kt > 1 else []
-    need = sfc_vmem_bytes(bm, bn, bk, a.dtype.itemsize,
-                          jnp.dtype(out_dtype).itemsize,
-                          bias=bias is not None,
-                          residual=residual is not None, kt=kt,
-                          k_tail=k % bk)
-    grid = (lead[0], mt * nt, kt) if batched else (mt * nt, kt)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",) * len(grid),
-        vmem_limit_bytes=_vmem_limit(need))
-    one = (1,) if batched else ()
-
-    def spec(shape, f):
-        """A block of ``shape`` (per batch element) at the 2-D block
-        index ``f(t, kk, *sched) -> (r, c)``."""
-        if batched:
-            return pl.BlockSpec(one + shape,
-                                lambda bb_, t, kk, *s: (bb_, *f(t, kk, *s)))
-        return pl.BlockSpec(shape, f)
-
-    # the scalar-prefetch operands: the walk, then the layer index
-    prefetch = []
-    if use_prefetch:
-        prefetch.append(_flat_schedule(schedule, mt, nt, g))
-
-        def tile(t, sched_ref, *_):
-            return sched_ref[2 * t], sched_ref[2 * t + 1]
-    else:
-        def tile(t, *_):
-            return decode_step(t, schedule, mt, nt)
-    if stacked:
-        prefetch.append(_layer_operand(layer))
-
-    def a_map(t, kk, *s):
-        return tile(t, *s)[0], kk
-
-    def b_map(t, kk, *s):
-        return kk, tile(t, *s)[1]
-
-    def o_map(t, kk, *s):
-        return tile(t, *s)
-
-    def bias_map(t, kk, *s):
-        return 0, tile(t, *s)[1]
-
-    bias_spec = pl.BlockSpec(
-        (1, 1, bn), lambda bb_, t, kk, *s: (0, *bias_map(t, kk, *s))) \
-        if batched else pl.BlockSpec((1, bn), bias_map)
-    ep_specs, ep_ops = _epilogue_operands(
-        bias, residual, one + (1, n), bias_spec, spec((bm, bn), o_map))
-    b_spec = pl.BlockSpec(
-        (None, bk, bn), lambda t, kk, *s: (s[-1][0], *b_map(t, kk, *s))) \
-        if stacked else spec((bk, bn), b_map)
-    in_specs = [spec((bm, bk), a_map), b_spec, *ep_specs]
-    out_specs = spec((bm, bn), o_map)
-    if not prefetch:
-        return pl.pallas_call(
-            functools.partial(_mm_kernel, **kern_kw),
-            grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape, scratch_shapes=scratch,
-            compiler_params=params, interpret=interpret,
-        )(a, b, *ep_ops)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
-        out_specs=out_specs, scratch_shapes=scratch)
-    return pl.pallas_call(
-        functools.partial(_mm_kernel_prefetch, n_prefetch=len(prefetch),
-                          **kern_kw),
-        grid_spec=grid_spec, out_shape=out_shape,
-        compiler_params=params, interpret=interpret,
-    )(*prefetch, a, b, *ep_ops)
 
 
 _STATIC = ("schedule", "bm", "bn", "bk", "out_dtype", "use_prefetch",
@@ -457,60 +336,95 @@ def sfc_matmul_pallas(
 ):
     """C = act(A @ B + bias) + residual with SFC-ordered tile traversal.
 
-    Blocks need not divide the shapes (see the module docstring; the
-    wrapper :func:`repro.kernels.ops.sfc_matmul` derives a block when
-    none is named).  ``g`` is the
-    supertile factor (``schedule="supertile"`` only; 0 means the
-    schedule's default).  ``bias`` is (N,), ``residual`` is (M, N); both
-    optional -- the epilogue runs on the f32 accumulator inside the
-    last-k flush, costing zero extra HBM output traffic (DESIGN.md §9).
-    ``layer`` (an int32 scalar, traced or not): ``b`` is then a stack
-    (L, K, N) of which layer ``layer`` is the operand, read in place --
-    a layer scan hands the kernel its stacked weight, not a copy of the
-    slice.  The index must lie in [0, L): nothing checks it.
+    Grid (T, kt): the curve's T output tiles, K innermost.  Blocks need
+    not divide the shapes (see the module docstring; the wrapper
+    :func:`repro.kernels.ops.sfc_matmul` derives a block when none is
+    named).  ``g`` is the supertile factor (``schedule="supertile"``
+    only; 0 means the schedule's default).  ``bias`` is (N,),
+    ``residual`` is (M, N); both optional -- the epilogue runs on the
+    f32 accumulator inside the last-k flush, costing zero extra HBM
+    output traffic (DESIGN.md §9).  ``layer`` (an int32 scalar, traced
+    or not): ``b`` is then a stack (L, K, N) of which layer ``layer`` is
+    the operand, read in place -- the index rides the scalar prefetch
+    and B's index map puts it first, so a layer scan hands the kernel
+    its stacked weight, not a copy of the slice.  The index must lie in
+    [0, L): nothing checks it.
     """
-    assert a.ndim == 2 and b.ndim == (2 if layer is None else 3), (
-        a.shape, b.shape)
-    return _sfc_call(a, b, bias, residual, layer, batched=False,
-                     schedule=schedule, bm=bm, bn=bn, bk=bk,
-                     out_dtype=out_dtype, use_prefetch=use_prefetch,
-                     interpret=interpret, g=g, activation=activation)
+    stacked = layer is not None
+    assert a.ndim == 2 and b.ndim == 2 + stacked, (a.shape, b.shape)
+    (m, k), n = a.shape, b.shape[-1]
+    assert b.shape[-2] == k, (a.shape, b.shape)
+    _check_epilogue(bias, residual, activation, n, (m, n))
+    mt, nt, kt = -(-m // bm), -(-n // bn), -(-k // bk)
+    out_dtype = out_dtype or a.dtype
+    kern_kw = dict(kt=kt, k_tail=k % bk, out_dtype=out_dtype,
+                   activation=activation, has_bias=bias is not None,
+                   has_residual=residual is not None)
+    out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)] if kt > 1 else []
+    need = sfc_vmem_bytes(bm, bn, bk, a.dtype.itemsize,
+                          jnp.dtype(out_dtype).itemsize,
+                          bias=bias is not None,
+                          residual=residual is not None, kt=kt,
+                          k_tail=k % bk)
+    grid = (mt * nt, kt)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=_vmem_limit(need))
 
+    # the scalar-prefetch operands: the walk, then the layer index
+    prefetch = []
+    if use_prefetch:
+        prefetch.append(_flat_schedule(schedule, mt, nt, g))
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
-def sfc_matmul_batched_pallas(
-    a,
-    b,
-    *,
-    schedule: str = "morton",
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
-    out_dtype=None,
-    use_prefetch: bool = True,
-    interpret: bool = False,
-    g: int = 0,
-    bias=None,
-    activation: str = "none",
-    residual=None,
-):
-    """C[b] = act(A[b] @ B[b] + bias) + residual[b], SFC tile traversal.
+        def tile(t, sched_ref, *_):
+            return sched_ref[2 * t], sched_ref[2 * t + 1]
+    else:
+        def tile(t, *_):
+            return decode_step(t, schedule, mt, nt)
+    if stacked:
+        prefetch.append(_layer_operand(layer))
 
-    Grid is (batch, T, kt) with the curve applied to the (i, j) output
-    tile plane -- the batch dim is outermost, so each batch element
-    replays the full SFC sweep and inherits its locality (consecutive
-    tile steps within one batch element keep a resident block exactly as
-    in the 2-D kernel; the k-accumulator carries across the innermost
-    dim only).  ``bias`` is (N,), shared across batch elements;
-    ``residual`` matches the (batch, M, N) output.  See
-    :func:`repro.kernels.ops.sfc_matmul_batched` for batching of
-    arbitrary leading dims.
-    """
-    assert a.ndim == 3 and b.ndim == 3, (a.shape, b.shape)
-    return _sfc_call(a, b, bias, residual, None, batched=True,
-                     schedule=schedule, bm=bm, bn=bn, bk=bk,
-                     out_dtype=out_dtype, use_prefetch=use_prefetch,
-                     interpret=interpret, g=g, activation=activation)
+    def a_map(t, kk, *s):
+        return tile(t, *s)[0], kk
+
+    def b_map(t, kk, *s):
+        return kk, tile(t, *s)[1]
+
+    def o_map(t, kk, *s):
+        return tile(t, *s)
+
+    def bias_map(t, kk, *s):
+        return 0, tile(t, *s)[1]
+
+    b_spec = pl.BlockSpec(
+        (None, bk, bn), lambda t, kk, *s: (s[-1][0], *b_map(t, kk, *s))) \
+        if stacked else pl.BlockSpec((bk, bn), b_map)
+    # the epilogue operands, in the order the kernel's ``rest`` parses
+    in_specs, operands = [pl.BlockSpec((bm, bk), a_map), b_spec], [a, b]
+    if bias is not None:
+        in_specs.append(pl.BlockSpec((1, bn), bias_map))
+        operands.append(bias.reshape((1, n)))
+    if residual is not None:
+        in_specs.append(pl.BlockSpec((bm, bn), o_map))
+        operands.append(residual)
+    out_specs = pl.BlockSpec((bm, bn), o_map)
+    if not prefetch:
+        return pl.pallas_call(
+            functools.partial(_mm_kernel, **kern_kw),
+            grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=params, interpret=interpret,
+        )(*operands)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch)
+    return pl.pallas_call(
+        functools.partial(_mm_kernel_prefetch, n_prefetch=len(prefetch),
+                          **kern_kw),
+        grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=params, interpret=interpret,
+    )(*prefetch, *operands)
 
 
 def _gmm_kernel(steps_ref, groups_ref, live_ref, *args, stacked: bool,
@@ -606,7 +520,7 @@ def sfc_gmm_pallas(a, b, group_tiles, *, schedule: str = "morton",
         else [])
     return pl.pallas_call(
         functools.partial(_gmm_kernel, stacked=stacked, kt=kt,
-                          k_tail=k % bk, out_dtype=out_dtype, batched=False,
+                          k_tail=k % bk, out_dtype=out_dtype,
                           activation=activation),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, n), out_dtype),

@@ -69,11 +69,13 @@ class DotEngine:
 
     schedule: "xla" (native dot), an SFC schedule name executed by the
     Pallas kernel ("morton", "hilbert", "rowmajor", ...), or "auto" --
-    the autotuner policy: every GEMM's (schedule, block sizes, prefetch)
-    is resolved per shape bucket through ``repro.tune`` (cached winners
-    on disk, analytic cost model otherwise; DESIGN.md §6).  "auto" may
-    resolve to the XLA baseline where the model predicts the library
-    wins -- the engine stays the single integration point either way.
+    the autotuner policy: every GEMM's (schedule, block sizes, prefetch,
+    supertile factor) is resolved per shape bucket and epilogue through
+    ``repro.tune.resolve`` (cached winners on disk, analytic cost model
+    otherwise; DESIGN.md §6), here and nowhere below: the kernel
+    wrappers take a concrete schedule.  "auto" may resolve to the XLA
+    baseline where the model predicts the library wins -- the engine
+    stays the single integration point either way.
 
     objective: the tuner's adjudication metric under schedule="auto" --
     "time" (default), "energy" (joules), or "edp" (energy-delay
@@ -135,40 +137,36 @@ class DotEngine:
         x2 = x.reshape(-1, x.shape[-1])
         res2 = residual.reshape(-1, w.shape[-1]) \
             if residual is not None else None
-        bm, bn, bk = self.block or (None, None, None)
+        if self.schedule == "auto":
+            kw = self._tuned(x2, w.shape[-1], bias, activation, residual)
+        else:
+            bm, bn, bk = self.block or (None, None, None)
+            kw = dict(schedule=self.schedule, bm=bm, bn=bn, bk=bk,
+                      use_prefetch=self.use_prefetch)
         stack, layer = _unview(w)
         out = sfc_matmul(
-            x2, stack, schedule=self.schedule, bm=bm, bn=bn, bk=bk,
-            use_prefetch=self.use_prefetch, interpret=self.interpret,
-            objective=self.objective, comm=self.comm, out_dtype=out_dtype,
-            bias=bias, activation=activation, residual=res2, layer=layer,
-        )
+            x2, stack, **kw, interpret=self.interpret, out_dtype=out_dtype,
+            bias=bias, activation=activation, residual=res2, layer=layer)
         return out.reshape(*lead, w.shape[-1])
 
-    def dot_batched(self, x, w, *, bias=None, activation: str = "none",
-                    residual=None, out_dtype=None):
-        """Per-batch-element GEMM: x (..., B, M, K) @ w (..., B, K, N).
+    def _tuned(self, x2, n: int, bias, activation: str, residual) -> dict:
+        """The tuner's winner for the GEMM x2 (M, K) @ (K, n), as
+        ``sfc_matmul`` keywords.  Its key holds the GEMM's epilogue (a
+        fused one moves the block optimum, DESIGN.md §9), ``comm`` and
+        ``objective``.  The winner's DVFS point (``f_scale``) never
+        reaches the kernel, as userspace cannot set the device clock;
+        the launch layer reads it through ``repro.tune.resolved_f_scale``.
+        Shapes are static, so this is safe at trace time."""
+        from repro.tune import EpilogueSpec, GemmSpec, resolve
 
-        Routed through the 3-D-grid batched SFC kernel (or XLA matmul)
-        under the same schedule policy -- and the same fused epilogue --
-        as :meth:`dot`."""
-        if self.schedule == "xla":
-            if bias is None and activation == "none" and residual is None:
-                out = jnp.matmul(x, w)
-                return out.astype(out_dtype) if out_dtype else out
-            from repro.kernels.ref import matmul_batched_fused_ref
-            return matmul_batched_fused_ref(
-                x, w, bias=bias, activation=activation, residual=residual,
-                out_dtype=out_dtype or jnp.result_type(x, w))
-        from repro.kernels.ops import sfc_matmul_batched
-
-        bm, bn, bk = self.block or (None, None, None)
-        return sfc_matmul_batched(
-            x, w, schedule=self.schedule, bm=bm, bn=bn, bk=bk,
-            use_prefetch=self.use_prefetch, interpret=self.interpret,
-            objective=self.objective, comm=self.comm, out_dtype=out_dtype,
-            bias=bias, activation=activation, residual=residual,
-        )
+        ep = EpilogueSpec(bias=bias is not None, activation=activation,
+                          residual=residual is not None)
+        (m, k), dtype = x2.shape, jnp.dtype(x2.dtype).name
+        cfg = resolve(GemmSpec(m, n, k, dtype,
+                               epilogue=None if ep.is_noop else ep,
+                               comm=self.comm), objective=self.objective)
+        return dict(schedule=cfg.schedule, bm=cfg.bm, bn=cfg.bn, bk=cfg.bk,
+                    use_prefetch=cfg.use_prefetch, g=cfg.g)
 
     def dot_grouped(self, x, w, group_tiles, *, activation: str = "none",
                     out_dtype=None):
@@ -180,9 +178,9 @@ class DotEngine:
         groups' tiles are undefined.  Under an SFC schedule on a TPU it
         is the grouped SFC kernel (``sfc_gmm_pallas``), whose blocks
         are derived from the shape (``block`` does not apply); under
-        "xla" it is ``lax.ragged_dot``; "auto" raises (the tuner has no
-        grouped GEMM).  ``w`` may be a :class:`LayerView`, read in
-        place."""
+        "xla" it is ``lax.ragged_dot``; "auto" raises ``ValueError``
+        (the tuner has no grouped GEMM).  ``w`` may be a
+        :class:`LayerView`, read in place."""
         from repro.kernels.ops import sfc_gmm
 
         stack, layer = _unview(w)

@@ -1,6 +1,7 @@
 # Autotuning for the SFC GEMM path (DESIGN.md §6): analytic pre-filter
 # over the LRU traffic simulator + index-cost model, measured top-k, and
-# an on-disk winner cache consulted by sfc_matmul(schedule="auto").
+# an on-disk winner cache consulted by DotEngine(schedule="auto"), which
+# hands each GEMM's winner to the kernel wrappers (repro.kernels.ops).
 # Winners are adjudicated under a pluggable objective -- wall time,
 # joules, or energy-delay product (DESIGN.md §8).
 from .autotune import (  # noqa: F401

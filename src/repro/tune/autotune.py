@@ -16,8 +16,10 @@ specialised to the repo's GEMM stack:
 4. **persist** the winner in the on-disk JSON cache
    (:mod:`repro.tune.cache`) so later processes pay zero search cost.
 
-``resolve_config`` is the hot-path entry used by
-``repro.kernels.ops.sfc_matmul(schedule="auto")``.
+``resolve`` (over ``resolve_config``) is the hot-path entry used by
+``repro.models.layers.DotEngine(schedule="auto")``, which hands the
+winner to ``repro.kernels.ops.sfc_matmul``: the dependency runs one way,
+engine -> tuner -> kernels.
 """
 from __future__ import annotations
 
@@ -192,18 +194,14 @@ def measure_config(
     reps: int = 5,
     warmup: int = 2,
     seed: int = 0,
-    batched: bool = False,
     epilogue: EpilogueSpec | None = None,
 ) -> float:
     """Median wall seconds of one GEMM under ``cfg`` on this backend.
 
-    ``batched=True`` times the 3-D-grid batched kernel (small batch of 2)
-    and reports the per-element time, so bmm/ winners are adjudicated on
-    the kernel that will actually execute them.  ``epilogue`` attaches
-    the bias/activation/residual the caller will run: Pallas candidates
-    execute it fused in the flush, the ``xla`` candidate pays the real
-    dot-then-elementwise composition -- the measurement adjudicates the
-    same pipeline the model scored.
+    ``epilogue`` attaches the bias/activation/residual the caller will
+    run: Pallas candidates execute it fused in the flush, the ``xla``
+    candidate pays the real dot-then-elementwise composition -- the
+    measurement adjudicates the same pipeline the model scored.
 
     Runs eagerly even when called while a jitted caller is being traced
     (``schedule="auto"`` resolves at trace time): under the caller's
@@ -215,14 +213,14 @@ def measure_config(
     with jax.core.eval_context():
         return _measure(cfg, m, n, k, dtype, interpret=interpret,
                         reps=reps, warmup=warmup, seed=seed,
-                        batched=batched, epilogue=epilogue)
+                        epilogue=epilogue)
 
 
 def _measure(cfg, m, n, k, dtype, *, interpret, reps, warmup, seed,
-             batched, epilogue) -> float:
+             epilogue) -> float:
     import jax.numpy as jnp
 
-    from repro.kernels.ops import sfc_matmul, sfc_matmul_batched
+    from repro.kernels.ops import sfc_matmul
 
     rng = np.random.default_rng(seed)
     kw = dict(schedule=cfg.schedule, bm=cfg.bm, bn=cfg.bn, bk=cfg.bk,
@@ -232,16 +230,6 @@ def _measure(cfg, m, n, k, dtype, *, interpret, reps, warmup, seed,
         kw["activation"] = epilogue.activation
         if epilogue.bias:
             kw["bias"] = jnp.asarray(rng.standard_normal((n,)), dtype=dtype)
-    if batched:
-        bsz = 2
-        a = jnp.asarray(rng.standard_normal((bsz, m, k)), dtype=dtype)
-        b = jnp.asarray(rng.standard_normal((bsz, k, n)), dtype=dtype)
-        if epilogue is not None and epilogue.residual:
-            kw["residual"] = jnp.asarray(
-                rng.standard_normal((bsz, m, n)), dtype=dtype)
-        t = _timeit(lambda a, b: sfc_matmul_batched(a, b, **kw), a, b,
-                    reps=reps, warmup=warmup)
-        return t / bsz
     a = jnp.asarray(rng.standard_normal((m, k)), dtype=dtype)
     b = jnp.asarray(rng.standard_normal((k, n)), dtype=dtype)
     if epilogue is not None and epilogue.residual:
@@ -273,7 +261,6 @@ def autotune(
     refresh: bool = False,
     capacity: int | None = None,
     candidates: list[TuneConfig] | None = None,
-    batched: bool = False,
     objective: str = "time",
     f_scales: tuple[float, ...] | None = None,
     epilogue: EpilogueSpec | None = None,
@@ -323,8 +310,7 @@ def autotune(
         cache = TuneCache()
     if epilogue is not None and epilogue.is_noop:
         epilogue = None
-    key = cache_key(m, n, k, dtype_name, backend, batched=batched,
-                    objective=objective,
+    key = cache_key(m, n, k, dtype_name, backend, objective=objective,
                     epilogue=epilogue.tag() if epilogue else None,
                     comm=comm.tag() if comm else None)
 
@@ -393,7 +379,7 @@ def autotune(
                 for hook in _PRECOMPILE_HOOKS:
                     hook(kc, m, n, k)
                 t_nom = measure_config(kc, m, n, k, dtype,
-                                       interpret=interpret, batched=batched,
+                                       interpret=interpret,
                                        epilogue=epilogue)
                 measured[repr(kc)] = t_nom
                 # model-calibration drift (DESIGN.md §12): the ratio of
@@ -535,7 +521,6 @@ def resolve_config(
     *,
     backend: str | None = None,
     cache: TuneCache | None = None,
-    batched: bool = False,
     objective: str = "time",
     epilogue: EpilogueSpec | None = None,
     comm: CommSpec | None = None,
@@ -543,12 +528,10 @@ def resolve_config(
     """Hot-path ``schedule="auto"`` resolution: cached winner or a fresh
     (analytic + measured-on-TPU) search.  Memoised in-process, so after
     first use per shape bucket it is a dict lookup; safe to call at
-    trace time (shapes are static).  ``batched`` keys the 3-D-grid
-    kernel's winners separately from the 2-D kernel's (different block
-    specs, different optimum); ``objective`` selects the adjudication
-    metric, ``epilogue`` the fused bias/activation/residual shape and
-    ``comm`` the mesh's collective term (DESIGN.md §15) -- all three key
-    the memo and the on-disk cache, so time-tuned, bare-GEMM or
+    trace time (shapes are static).  ``objective`` selects the
+    adjudication metric, ``epilogue`` the fused bias/activation/residual
+    shape and ``comm`` the mesh's collective term (DESIGN.md §15) -- all
+    three key the memo and the on-disk cache, so time-tuned, bare-GEMM or
     single-chip winners never leak into an energy/EDP, fused-epilogue
     or multi-chip policy."""
     import jax
@@ -558,15 +541,14 @@ def resolve_config(
     if epilogue is not None and epilogue.is_noop:
         epilogue = None
     path = cache.path if cache is not None else default_cache_path()
-    bucket = cache_key(m, n, k, dtype_name, bk_, batched=batched,
-                       objective=objective,
+    bucket = cache_key(m, n, k, dtype_name, bk_, objective=objective,
                        epilogue=epilogue.tag() if epilogue else None,
                        comm=comm.tag() if comm else None)
     cfg = _memoised_resolve(
         path, bucket,
         lambda: autotune(m, n, k, dtype, backend=backend, cache=cache,
-                         batched=batched, objective=objective,
-                         epilogue=epilogue, comm=comm).config)
+                         objective=objective, epilogue=epilogue,
+                         comm=comm).config)
     # per-call: validity depends on the exact shape, not the bucket
     return _validate_for_shape(cfg, m, n, k, _dtype_bytes(dtype))
 
@@ -579,7 +561,6 @@ def resolved_f_scale(
     *,
     backend: str | None = None,
     cache: TuneCache | None = None,
-    batched: bool = False,
     objective: str = "time",
     epilogue: EpilogueSpec | None = None,
     comm: CommSpec | None = None,
@@ -593,8 +574,8 @@ def resolved_f_scale(
     shares the memo/cache and is safe to call once at startup.
     """
     return resolve_config(m, n, k, dtype, backend=backend, cache=cache,
-                          batched=batched, objective=objective,
-                          epilogue=epilogue, comm=comm).f_scale
+                          objective=objective, epilogue=epilogue,
+                          comm=comm).f_scale
 
 
 # ------------------------------------------------------ decode attention ---
@@ -743,7 +724,7 @@ def resolved_attn_f_scale(
 @dataclass(frozen=True)
 class GemmSpec:
     """A GEMM tuning problem as a value: what :func:`resolve_config`
-    took as six positional/keyword arguments, packaged so call sites
+    takes as positional/keyword arguments, packaged so call sites
     build the spec once and hand it around (launch layer, benchmarks).
     ``epilogue`` is the fused bias/activation/residual the caller will
     attach (DESIGN.md §9); ``comm`` is the mesh's collective term
@@ -753,7 +734,6 @@ class GemmSpec:
     n: int
     k: int
     dtype: str = "float32"
-    batched: bool = False
     epilogue: EpilogueSpec | None = None
     comm: CommSpec | None = None
 
@@ -786,7 +766,7 @@ def resolve(
     """One tuning entrypoint for every problem kind (DESIGN.md §11).
 
     Dispatches on the spec's type: :class:`GemmSpec` routes through the
-    GEMM keyspace (``mm/`` / ``bmm/``), :class:`DecodeAttnSpec` through
+    GEMM keyspace (``mm/``), :class:`DecodeAttnSpec` through
     the attention keyspace (``attn=...``).  The legacy pairs
     (``resolve_config``/``resolve_attn_config`` and
     ``autotune``/``autotune_attn``) remain the implementation -- this
@@ -803,16 +783,15 @@ def resolve(
         if search:
             return autotune(spec.m, spec.n, spec.k, spec.dtype,
                             backend=backend, cache=cache,
-                            batched=spec.batched, objective=objective,
-                            epilogue=spec.epilogue, comm=spec.comm,
-                            **search_kw)
+                            objective=objective, epilogue=spec.epilogue,
+                            comm=spec.comm, **search_kw)
         if search_kw:
             raise TypeError(
                 f"search options {sorted(search_kw)} need search=True")
         return resolve_config(spec.m, spec.n, spec.k, spec.dtype,
                               backend=backend, cache=cache,
-                              batched=spec.batched, objective=objective,
-                              epilogue=spec.epilogue, comm=spec.comm)
+                              objective=objective, epilogue=spec.epilogue,
+                              comm=spec.comm)
     if isinstance(spec, DecodeAttnSpec):
         if search:
             return autotune_attn(spec.slots, spec.cache_len,

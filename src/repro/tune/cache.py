@@ -38,7 +38,7 @@ def shape_bucket(m: int, n: int, k: int) -> tuple[int, int, int]:
 
 
 def cache_key(m: int, n: int, k: int, dtype: str, backend: str,
-              batched: bool = False, objective: str = "time",
+              objective: str = "time",
               epilogue: str | None = None,
               attn: str | None = None,
               comm: str | None = None) -> str:
@@ -55,7 +55,7 @@ def cache_key(m: int, n: int, k: int, dtype: str, backend: str,
 
     ``attn`` (an :class:`repro.tune.cost.AttnSpec` tag such as
     ``paged-p8``) keys the decode-attention winners (DESIGN.md §10):
-    the kernel tag replaces the ``mm``/``bmm`` prefix with ``attn`` and
+    the kernel tag replaces the ``mm`` prefix with ``attn`` and
     the shape is (slots, kv_width, cache_len) -- a paged winner and a
     contiguous winner are different searches with different byte curves,
     and neither may leak into the GEMM keyspace.
@@ -68,7 +68,7 @@ def cache_key(m: int, n: int, k: int, dtype: str, backend: str,
     different curve.  Single-chip callers (``comm=None``) keep the
     historical unsuffixed key."""
     bm_, bn_, bk_ = shape_bucket(m, n, k)
-    tag = "attn" if attn else ("bmm" if batched else "mm")
+    tag = "attn" if attn else "mm"
     key = f"{tag}/{bm_}x{bn_}x{bk_}/{dtype}/{backend}"
     if objective != "time":
         key += f"/obj={objective}"
@@ -131,7 +131,7 @@ class TuneCache:
     def get(self, key: str) -> dict | None:
         hit = self._load().get(key)
         # per-keyspace hit/miss telemetry (DESIGN.md §12): the keyspace
-        # is the key's kernel-kind prefix (mm / bmm / attn), so one
+        # is the key's kernel-kind prefix (mm / attn), so one
         # snapshot shows which searches the on-disk cache is absorbing.
         # NB: in-process memo hits (_memoised_resolve) never reach here.
         keyspace = key.split("/", 1)[0]

@@ -1,6 +1,6 @@
 """Tests for the repro.tune autotuner: cost-model ranking, cache
-round-trip/corruption recovery, schedule="auto" equivalence, batched
-kernel equivalence (interpret mode)."""
+round-trip/corruption recovery, schedule="auto" equivalence through
+DotEngine (interpret mode)."""
 import json
 
 import numpy as np
@@ -8,8 +8,9 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels.ops import sfc_matmul, sfc_matmul_batched
-from repro.kernels.ref import matmul_batched_ref, matmul_ref
+from repro.kernels.ops import sfc_gmm, sfc_matmul
+from repro.kernels.ref import matmul_ref
+from repro.models.layers import DotEngine
 from repro.tune import (
     TuneConfig,
     autotune,
@@ -21,7 +22,7 @@ from repro.tune.cache import TuneCache, cache_key, shape_bucket
 
 @pytest.fixture
 def tune_cache(tmp_path, monkeypatch):
-    """Isolated on-disk cache; also steers sfc_matmul's auto resolution."""
+    """Isolated on-disk cache; also steers DotEngine's auto resolution."""
     path = str(tmp_path / "tune.json")
     monkeypatch.setenv("REPRO_TUNE_CACHE", path)
     return TuneCache(path)
@@ -208,21 +209,20 @@ def test_cached_closed_form_winner_revalidated_for_bucket_sibling(tune_cache):
     cfg = resolve_config(300, 300, 300, "float32")
     assert cfg.use_prefetch is True
     a = _rand((300, 300), jnp.float32, 30)
-    out = sfc_matmul(a, a, schedule="auto", interpret=True,
-                     force_pallas=True)
+    out = DotEngine(schedule="auto", interpret=True).dot(a, a)
     np.testing.assert_allclose(np.asarray(out), np.asarray(matmul_ref(a, a)),
                                rtol=1e-4, atol=1e-4)
 
 
 # --------------------------------------------------------- auto schedule ---
 def test_auto_schedule_bit_identical_to_morton(tune_cache):
-    """Acceptance: sfc_matmul(schedule="auto") is bit-identical to the
+    """Acceptance: DotEngine(schedule="auto") is bit-identical to the
     schedule="morton" reference path (both resolve to the same CPU
     execution; on TPU both run the Pallas kernel whose result is
     schedule-invariant, see test_kernels)."""
     a = _rand((300, 260), jnp.float32, 0)
     b = _rand((260, 190), jnp.float32, 1)
-    out_auto = sfc_matmul(a, b, schedule="auto")
+    out_auto = DotEngine(schedule="auto").dot(a, b)
     out_mo = sfc_matmul(a, b, schedule="morton")
     np.testing.assert_array_equal(np.asarray(out_auto), np.asarray(out_mo))
 
@@ -234,10 +234,12 @@ def test_auto_schedule_matches_ref_interpret(tune_cache):
     a = _rand((64, 64), jnp.float32, 2)
     b = _rand((64, 64), jnp.float32, 3)
     cfg = resolve_config(64, 64, 64, "float32")
-    kw = (dict(schedule="auto") if cfg.schedule == "xla"
-          else dict(schedule=cfg.schedule, bm=16, bn=16, bk=16,
-                    use_prefetch=cfg.use_prefetch, force_pallas=True))
-    out = sfc_matmul(a, b, interpret=True, **kw)
+    if cfg.schedule == "xla":
+        out = DotEngine(schedule="auto", interpret=True).dot(a, b)
+    else:
+        out = sfc_matmul(a, b, schedule=cfg.schedule, bm=16, bn=16, bk=16,
+                         use_prefetch=cfg.use_prefetch, interpret=True,
+                         force_pallas=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(matmul_ref(a, b)),
                                rtol=1e-5, atol=1e-5)
 
@@ -266,63 +268,6 @@ def test_measure_config_inside_a_trace(schedule):
     assert isinstance(seen[0], float) and seen[0] > 0
 
 
-def test_auto_batched(tune_cache):
-    a = _rand((3, 48, 40), jnp.float32, 4)
-    b = _rand((3, 40, 56), jnp.float32, 5)
-    out = sfc_matmul_batched(a, b, schedule="auto")
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(matmul_batched_ref(a, b)))
-
-
-# -------------------------------------------------------- batched kernel ---
-@pytest.mark.parametrize("schedule", ["rowmajor", "morton", "hilbert"])
-def test_batched_matches_loop(schedule):
-    """3-D-grid batched kernel == per-element 2-D GEMMs (interpret)."""
-    a = _rand((4, 48, 32), jnp.float32, 6)
-    b = _rand((4, 32, 48), jnp.float32, 7)
-    out = sfc_matmul_batched(a, b, schedule=schedule, bm=16, bn=16, bk=16,
-                             interpret=True, force_pallas=True)
-    loop = np.stack([
-        np.asarray(sfc_matmul(a[i], b[i], schedule=schedule, bm=16, bn=16,
-                              bk=16, interpret=True, force_pallas=True))
-        for i in range(a.shape[0])
-    ])
-    np.testing.assert_array_equal(np.asarray(out), loop)
-
-
-def test_batched_grid_equals_vmap():
-    a = _rand((2, 64, 64), jnp.float32, 8)
-    b = _rand((2, 64, 64), jnp.float32, 9)
-    kw = dict(schedule="morton", bm=16, bn=16, bk=16, interpret=True,
-              force_pallas=True)
-    out_grid = sfc_matmul_batched(a, b, **kw)
-    out_vmap = sfc_matmul_batched(a, b, via_vmap=True, **kw)
-    np.testing.assert_array_equal(np.asarray(out_grid), np.asarray(out_vmap))
-
-
-def test_batched_leading_dims_and_ragged():
-    a = _rand((2, 3, 50, 36), jnp.float32, 10)
-    b = _rand((2, 3, 36, 28), jnp.float32, 11)
-    out = sfc_matmul_batched(a, b, schedule="hilbert", bm=16, bn=16, bk=16,
-                             interpret=True, force_pallas=True)
-    assert out.shape == (2, 3, 50, 28)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(matmul_batched_ref(a, b)),
-        rtol=1e-5, atol=1e-5)
-
-
-def test_batched_closed_form_decode():
-    """use_prefetch=False on a square power-of-two (i, j) tile grid."""
-    a = _rand((2, 64, 32), jnp.float32, 12)
-    b = _rand((2, 32, 64), jnp.float32, 13)
-    out = sfc_matmul_batched(a, b, schedule="morton", bm=16, bn=16, bk=16,
-                             use_prefetch=False, interpret=True,
-                             force_pallas=True)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(matmul_batched_ref(a, b)),
-        rtol=1e-5, atol=1e-5)
-
-
 def test_supertile_g_reaches_kernel():
     """A tuned supertile factor must be executed, not silently replaced
     by the schedule default (g=2)."""
@@ -341,19 +286,7 @@ def test_supertile_g_reaches_kernel():
                               grid_schedule("supertile", 4, 4, g=4))
 
 
-def test_batched_auto_uses_separate_cache_bucket(tune_cache):
-    from repro.tune import resolve_config
-
-    resolve_config(256, 256, 256, "float32")
-    resolve_config(256, 256, 256, "float32", batched=True)
-    keys = sorted(tune_cache.keys())
-    assert any(k.startswith("mm/") for k in keys)
-    assert any(k.startswith("bmm/") for k in keys)
-
-
 def test_dot_engine_auto(tune_cache):
-    from repro.models.layers import DotEngine
-
     eng = DotEngine(schedule="auto")
     x = _rand((4, 32, 24), jnp.float32, 14)
     w = _rand((24, 16), jnp.float32, 15)
@@ -362,13 +295,16 @@ def test_dot_engine_auto(tune_cache):
         np.asarray(y), np.asarray(jnp.einsum("...d,df->...f", x, w)),
         rtol=1e-5, atol=1e-5)
 
-    xb = _rand((4, 32, 24), jnp.float32, 16)
-    wb = _rand((4, 24, 16), jnp.float32, 17)
-    eng2 = DotEngine(schedule="morton", block=(16, 16, 16), interpret=True)
-    yb = eng2.dot_batched(xb, wb)
-    np.testing.assert_allclose(
-        np.asarray(yb), np.asarray(jnp.matmul(xb, wb)),
-        rtol=1e-5, atol=1e-5)
+
+def test_kernel_wrappers_refuse_auto():
+    """"auto" is resolved by DotEngine alone: the wrappers refuse it
+    rather than fall to XLA's dot off a TPU."""
+    a = _rand((128, 24), jnp.float32, 18)
+    with pytest.raises(ValueError, match="DotEngine"):
+        sfc_matmul(a, _rand((24, 16), jnp.float32, 19), schedule="auto")
+    with pytest.raises(ValueError, match="DotEngine"):
+        sfc_gmm(a, _rand((2, 24, 16), jnp.float32, 20),
+                jnp.asarray([1, 0], jnp.int32), schedule="auto")
 
 
 def test_cache_put_survives_readonly_path(tmp_path, monkeypatch):

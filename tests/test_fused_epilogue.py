@@ -11,11 +11,10 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels.ops import sfc_matmul, sfc_matmul_batched
+from repro.kernels.ops import sfc_matmul
 from repro.kernels.ref import ACTIVATIONS, apply_activation, \
-    matmul_batched_fused_ref, matmul_fused_ref
-from repro.kernels.sfc_matmul import sfc_matmul_batched_pallas, \
-    sfc_matmul_pallas
+    matmul_fused_ref
+from repro.kernels.sfc_matmul import sfc_matmul_pallas
 from repro.tune.cost import EpilogueSpec, TuneConfig, \
     epilogue_extra_bytes, predict
 
@@ -50,19 +49,18 @@ def _unfused(a, b, bias, activation, residual, out_dtype):
 @given(
     schedule=st.sampled_from(SCHEDULES),
     use_prefetch=st.booleans(),
-    batched=st.booleans(),
     activation=st.sampled_from(ACTIVATIONS),
     has_bias=st.booleans(),
     has_residual=st.booleans(),
     seed=st.integers(min_value=0, max_value=2 ** 16),
 )
-def test_fused_matches_unfused_composition(schedule, use_prefetch, batched,
+def test_fused_matches_unfused_composition(schedule, use_prefetch,
                                            activation, has_bias,
                                            has_residual, seed):
     """Property (interpret mode): fused epilogue == dot->bias->act->res
-    within f32 tolerance, across schedules, prefetch modes, and the
-    batched kernel.  Grid kept square pow2 so the closed-form
-    (use_prefetch=False) decode exists for morton/hilbert."""
+    within f32 tolerance, across schedules and prefetch modes.  Grid
+    kept square pow2 so the closed-form (use_prefetch=False) decode
+    exists for morton/hilbert."""
     m = n = k = 32
     a = _rand((m, k), jnp.float32, seed)
     b = _rand((k, n), jnp.float32, seed + 1)
@@ -70,22 +68,9 @@ def test_fused_matches_unfused_composition(schedule, use_prefetch, batched,
     kw = dict(schedule=schedule, bm=16, bn=16, bk=16,
               use_prefetch=use_prefetch, interpret=True,
               bias=bias, activation=activation)
-    if batched:
-        a = _rand((2, m, k), jnp.float32, seed)
-        b = _rand((2, k, n), jnp.float32, seed + 1)
-        residual = _rand((2, m, n), jnp.float32, seed + 3) \
-            if has_residual else None
-        out = sfc_matmul_batched_pallas(a, b, residual=residual, **kw)
-        ref = jnp.stack([
-            _unfused(a[i], b[i], bias, activation,
-                     None if residual is None else residual[i],
-                     jnp.float32)
-            for i in range(2)])
-    else:
-        residual = _rand((m, n), jnp.float32, seed + 3) \
-            if has_residual else None
-        out = sfc_matmul_pallas(a, b, residual=residual, **kw)
-        ref = _unfused(a, b, bias, activation, residual, jnp.float32)
+    residual = _rand((m, n), jnp.float32, seed + 3) if has_residual else None
+    out = sfc_matmul_pallas(a, b, residual=residual, **kw)
+    ref = _unfused(a, b, bias, activation, residual, jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -126,33 +111,6 @@ def test_fused_dtype_cast_single_write(dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         **_tol(dtype))
-
-
-def test_batched_wrapper_fused_leading_dims():
-    a = _rand((2, 3, 20, 12), jnp.float32, 7)
-    b = _rand((2, 3, 12, 24), jnp.float32, 8)
-    bias = _rand((24,), jnp.float32, 9)
-    res = _rand((2, 3, 20, 24), jnp.float32, 10)
-    out = sfc_matmul_batched(a, b, schedule="hilbert", bm=16, bn=16, bk=16,
-                             interpret=True, force_pallas=True,
-                             bias=bias, activation="silu", residual=res)
-    ref = matmul_batched_fused_ref(a, b, bias=bias, activation="silu",
-                                   residual=res)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_batched_via_vmap_agrees_with_3d_grid():
-    a = _rand((3, 32, 32), jnp.float32, 11)
-    b = _rand((3, 32, 32), jnp.float32, 12)
-    bias = _rand((32,), jnp.float32, 13)
-    res = _rand((3, 32, 32), jnp.float32, 14)
-    kw = dict(schedule="morton", bm=16, bn=16, bk=16, interpret=True,
-              force_pallas=True, bias=bias, activation="gelu", residual=res)
-    np.testing.assert_allclose(
-        np.asarray(sfc_matmul_batched(a, b, via_vmap=True, **kw)),
-        np.asarray(sfc_matmul_batched(a, b, via_vmap=False, **kw)),
-        rtol=1e-6, atol=1e-6)
 
 
 def test_xla_fallback_reproduces_fused_math():
@@ -316,14 +274,16 @@ def test_resolve_config_epilogue_memo(tmp_path):
 
 
 def test_schedule_auto_fused_smoke(tmp_path, monkeypatch):
-    """schedule="auto" with an epilogue resolves and computes correctly
-    end to end (interpret-mode measurement off CPU)."""
+    """schedule="auto" with an epilogue resolves (in DotEngine) and
+    computes correctly end to end (interpret-mode measurement off CPU)."""
+    from repro.models.layers import DotEngine
+
     monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path / "auto.json"))
     monkeypatch.setenv("REPRO_TUNE_MEASURE", "0")
     a = _rand((64, 32), jnp.float32, 30)
     b = _rand((32, 48), jnp.float32, 31)
     bias = _rand((48,), jnp.float32, 32)
-    out = sfc_matmul(a, b, schedule="auto", bias=bias, activation="gelu")
+    out = DotEngine(schedule="auto").dot(a, b, bias=bias, activation="gelu")
     ref = matmul_fused_ref(a, b, bias=bias, activation="gelu")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)

@@ -209,6 +209,14 @@ STACKED = {
     "residual": {"residual": True},
     "f32_out": {"out_dtype": jnp.float32},
     "closed_form_walk": {"use_prefetch": False, "schedule": "rowmajor"},
+    "hilbert_walk": {"schedule": "hilbert"},
+    "supertile_walk": {"schedule": "supertile", "g": 2},
+    # the paper's in-index-map decodes need a square power-of-two grid:
+    # M 64 and N 512 give 4 x 4 tiles
+    "closed_form_morton": {"use_prefetch": False, "schedule": "morton",
+                           "m": 64, "n": 512},
+    "closed_form_hilbert": {"use_prefetch": False, "schedule": "hilbert",
+                            "m": 64, "n": 512},
 }
 
 
@@ -216,14 +224,16 @@ STACKED = {
 @pytest.mark.parametrize("case", sorted(STACKED))
 def test_stacked_weight_reads_its_layer_in_place(case, layer):
     """The kernel on a (L, K, N) stack and a traced layer index equals
-    the kernel on that layer's slice, bit for bit; so do the wrapper's
-    kernel path and its XLA fallback.  K 200 over blocks of 128 takes
-    two k steps, the last masked; N 300 a cropped last block."""
+    the kernel on that layer's slice, bit for bit, under each walk and
+    index strategy; so do the wrapper's kernel path and its XLA
+    fallback.  K 200 over blocks of 128 takes two k steps, the last
+    masked; N 300 (the default) a cropped last block."""
     kw = dict(STACKED[case])
-    res = _rand((48, 300), jnp.bfloat16, 7) if kw.pop("residual", False) \
+    m, n = kw.pop("m", 48), kw.pop("n", 300)
+    res = _rand((m, n), jnp.bfloat16, 7) if kw.pop("residual", False) \
         else None
-    a = _rand((48, 200), jnp.bfloat16, 5)
-    w = _rand((5, 200, 300), jnp.bfloat16, 6)
+    a = _rand((m, 200), jnp.bfloat16, 5)
+    w = _rand((5, 200, n), jnp.bfloat16, 6)
     blocks = dict(bm=16, bn=128, bk=128)
 
     def kernel(b, i=None):

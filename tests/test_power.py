@@ -402,12 +402,12 @@ def test_edp_objective_selects_different_winner(tune_cache):
 
 
 def test_sfc_matmul_auto_with_objective(tune_cache):
-    from repro.kernels.ops import sfc_matmul
+    from repro.models.layers import DotEngine
 
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.standard_normal((96, 80)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((80, 64)), jnp.float32)
-    out = np.asarray(sfc_matmul(a, b, schedule="auto", objective="edp"))
+    out = np.asarray(DotEngine(schedule="auto", objective="edp").dot(a, b))
     np.testing.assert_allclose(out, np.asarray(a @ b), rtol=1e-4, atol=1e-4)
     # the edp resolution landed in its own cache bucket
     assert any(k.endswith("/obj=edp") for k in tune_cache)

@@ -16,9 +16,8 @@ import jax.numpy as jnp
 
 from _gemms import cell_gemm_cases, forward_gemms
 from repro.core.schedule import grid_schedule, matmul_block_trace
-from repro.kernels.ops import _resolve_blocks, sfc_matmul, \
-    sfc_matmul_batched
-from repro.kernels.ref import matmul_batched_fused_ref, matmul_fused_ref
+from repro.kernels.ops import _resolve_blocks, sfc_matmul
+from repro.kernels.ref import matmul_fused_ref
 from repro.obs.metrics import default_registry
 
 # the module (the package's ``sfc_matmul`` attribute is the wrapper)
@@ -120,8 +119,6 @@ def test_grid_step_counts_match_the_block_trace(schedule, mt, nt, kt):
     elided = sum(1 for prev, cur in zip(steps, steps[1:])
                  if cur[0] == prev[0] or cur[1] == prev[1])
     assert K.grid_step_counts(schedule, mt, nt, kt) == (len(steps), elided)
-    assert K.grid_step_counts(schedule, mt, nt, kt, batch=3) == (
-        3 * len(steps), 3 * elided)
     if kt > 1:
         assert elided == 0
 
@@ -195,19 +192,6 @@ def test_derived_blocks_match_reference(case):
         assert blocks[2] > k
     if case == "bk_is_k":
         assert blocks[2] == k
-
-
-def test_derived_blocks_batched_match_reference():
-    a = _rand((2, 3, 24, 200), jnp.bfloat16, 5)
-    b = _rand((2, 3, 200, 2200), jnp.bfloat16, 6)
-    res = _rand((2, 3, 24, 2200), jnp.bfloat16, 7)
-    out = sfc_matmul_batched(a, b, interpret=True, residual=res,
-                             activation="relu")
-    ref = matmul_batched_fused_ref(a, b, residual=res, activation="relu",
-                                   out_dtype=jnp.bfloat16)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=2e-2, atol=2e-2)
 
 
 def test_engine_default_derives_blocks():
